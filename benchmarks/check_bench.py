@@ -5,7 +5,7 @@ may regress beyond the threshold against the previous run.
     PYTHONPATH=src python benchmarks/check_bench.py bench_ci.json \
         [--threshold 0.5] [--no-regress-gate]
 
-A kernel that stops lowering under ``REPRO_PALLAS_INTERPRET=1`` (or starts
+A kernel that stops lowering in the CPU interpreter (or starts
 returning NaN timings) would otherwise just drop out of the trajectory and
 the regression would go unnoticed until someone eyeballed the JSON —
 ``benchmarks/run.py`` only exits non-zero on ordering-claim FAILs, not on

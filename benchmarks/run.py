@@ -54,6 +54,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     selected = args.only.split(",") if args.only else list(TABLES)
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
+
     from benchmarks import (appendix_a_weight_vs_act, coverage, kernel_bench,
                             sensitivity_scan, serving, table1_amber,
                             table2_osparse, table3_generation)

@@ -101,7 +101,7 @@ def _aval_small(aval) -> bool:
 
 def _closed(j):
     """(jaxpr, consts) from a ClosedJaxpr or open Jaxpr param value."""
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
     if isinstance(j, jax_core.ClosedJaxpr):
         return j.jaxpr, list(j.consts)
     return j, []
@@ -129,7 +129,7 @@ def _eval_jaxpr(jaxpr, consts, invals, calls: List[ResolvedCall],
     through first-order primitives eagerly; higher-order primitives are
     recursed for ``pallas_call`` collection.  Returns outvar values
     (``UNKNOWN``-filled where resolution stopped)."""
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
 
     env: Dict[Any, Any] = {}
 
@@ -154,9 +154,9 @@ def _eval_jaxpr(jaxpr, consts, invals, calls: List[ResolvedCall],
 
         if name == "pallas_call":
             calls.append(ResolvedCall(eqn, vals, path))
-        elif name == "pjit":
+        elif name in ("pjit", "jit"):
             j, c = _closed(eqn.params["jaxpr"])
-            outs = _eval_jaxpr(j, c, vals, calls, path + "/pjit")
+            outs = _eval_jaxpr(j, c, vals, calls, path + "/" + name)
         elif name in ("custom_jvp_call", "custom_vjp_call"):
             j, c = _closed(eqn.params["call_jaxpr"])
             outs = _eval_jaxpr(j, c, vals, calls, path + "/" + name)
@@ -246,7 +246,10 @@ class GridEval:
 
 
 def _block_dims(block_shape) -> Tuple[int, ...]:
-    return tuple(int(d) if isinstance(d, int) else 1 for d in block_shape)
+    """Block extents: ints, or ``pl.Blocked``/``pl.Element`` wrappers
+    (their ``block_size``); squeezed dims count as 1 row."""
+    return tuple(int(d) if isinstance(d, int)
+                 else int(getattr(d, "block_size", 1)) for d in block_shape)
 
 
 def _scalar_dependent(index_map_jaxpr, n_grid: int) -> bool:
@@ -269,7 +272,7 @@ def eval_pallas_eqn(eqn, invals: Sequence[Any]):
     error string when the grid/scalars cannot be resolved statically."""
     import jax
     import numpy as np
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
     from jax._src import state
     try:
         from jax._src.state import discharge as state_discharge
@@ -322,7 +325,7 @@ def eval_pallas_eqn(eqn, invals: Sequence[Any]):
         nd = len(bdims)
         idx = np.stack([np.broadcast_to(np.asarray(o), (steps,))
                         for o in outs[:nd]], axis=-1).astype(np.int64)
-        arr = bm.array_shape_dtype
+        arr = bm.array_aval
         og = OperandGrid(
             role="out" if is_out else "in",
             idx=(bi - gm.num_inputs) if is_out else bi,

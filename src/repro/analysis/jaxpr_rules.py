@@ -5,9 +5,9 @@ abstractly with :func:`jax.make_jaxpr` (``jax.ShapeDtypeStruct`` operands
 where possible — nothing runs, no TPU needed):
 
   * ``jaxpr.projection-dispatch``  each ``sparse_linear`` mode (per-token
-    N:M, tile-consensus, Outstanding-sparse W8A8 prefill AND decode) is
-    exactly ONE fused ``pallas_call`` with kernels on, zero with kernels
-    off, and zero on the ``layer_flag`` fallback;
+    N:M, tile-consensus, Outstanding-sparse W8A8 prefill AND decode, and
+    a ``layer_flag`` scan-stacked projection) is exactly ONE fused
+    ``pallas_call`` with kernels on and zero with kernels off;
   * ``jaxpr.step-contracts``  for every fused step bucket of
     ``serve/executor.py`` (enumerated from ``STEP_BUCKETS``, never
     hand-listed): zero pool-shaped gathers/scatters outside kernels, no
@@ -107,19 +107,24 @@ def _projection_cases():
         # decode: prune=False statically — still ONE fused W8A8 GEMM
         ("w8a8-decode kernels-on", trace(qproj(on, "decode"), x, wq,
                                          w_scale, smooth, act_scale), 1),
-        # scan-stacked layer_flag models must stay on the jnp fallback
-        ("layer-flag fallback",
-         trace(lambda x, w: sparse_linear(
+        # scan-stacked layer_flag models: the flag picks the kernel or
+        # the dense projection with lax.cond — still ONE pallas_call
+        ("layer-flag kernel",
+         trace(lambda x, w, f: sparse_linear(
              x, {"w": w}, "down_proj", flag_on, "prefill",
-             layer_flag=jnp.array(True)), x, w), 0),
+             layer_flag=f), x, w, jax.ShapeDtypeStruct((), jnp.bool_)), 1),
+        ("layer-flag kernels-off",
+         trace(lambda x, w, f: sparse_linear(
+             x, {"w": w}, "down_proj", off, "prefill",
+             layer_flag=f), x, w, jax.ShapeDtypeStruct((), jnp.bool_)), 0),
     ]
 
 
 @rule("jaxpr.projection-dispatch", family="jaxpr")
 def rule_projection_dispatch(ctx: Context) -> List[Finding]:
     """One fused pallas_call per sparse projection (per-token,
-    tile-consensus, W8A8 prefill/decode); zero on the jnp oracle and
-    layer_flag paths."""
+    tile-consensus, W8A8 prefill/decode, layer_flag); zero on the jnp
+    oracle."""
     findings: List[Finding] = []
     for name, thunk, want in _projection_cases():
         got = count_pallas_calls(thunk())
@@ -165,8 +170,7 @@ def _step_fixture(ctx: Context):
     tab[0, :3], tab[1, :3] = [1, 2, 3], [4, 5, 6]
     cache["block_table"] = jnp.asarray(tab)
     cache["pos"] = jnp.asarray([10, 7], jnp.int32)
-    pool_shapes = {(rows, bs, cfg.n_kv_heads, cfg.head_dim),
-                   (rows * bs, cfg.n_kv_heads, cfg.head_dim)}
+    pool_shapes = {(rows, cfg.n_kv_heads, bs, cfg.head_dim)}
     args = (params, cache, jnp.asarray(0, jnp.int32),
             jnp.zeros((1, 8), jnp.int32), jnp.asarray(8, jnp.int32),
             {}, jnp.zeros((slots,), jnp.int32),

@@ -51,7 +51,7 @@ _MOVE_PRIMS = {"broadcast_in_dim", "reshape", "squeeze", "slice",
 def _all_eqns(jaxpr) -> List[Any]:
     """Flatten a kernel jaxpr including sub-jaxprs (``pl.when`` lowers
     to ``cond``; loops carry bodies in params)."""
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
 
     out = []
     stack = [jaxpr]

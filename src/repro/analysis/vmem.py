@@ -77,9 +77,8 @@ def _itemsize(dtype) -> int:
 
 
 def _block_numel(block_shape) -> int:
-    # squeezed/mapped dims may appear as non-ints; they contribute 1 row
-    return math.prod(int(d) if isinstance(d, int) else 1
-                     for d in block_shape)
+    from repro.analysis.grid_eval import _block_dims
+    return math.prod(_block_dims(block_shape))
 
 
 def _ref_space_and_bytes(aval) -> Tuple[str, int]:
@@ -104,7 +103,7 @@ def estimate_jaxpr(jaxpr) -> List[PallasFootprint]:
 
         block_bytes = 0
         for bm in gm.block_mappings:
-            arr = bm.array_shape_dtype
+            arr = bm.array_aval
             block_bytes += _block_numel(bm.block_shape) * _itemsize(arr.dtype)
 
         inner = eqn.params["jaxpr"]
@@ -220,7 +219,7 @@ def _zoo(cfg, chunk, decode_slots, max_seq, block_size):
     mb = max_seq // block_size
     nb = decode_slots * mb
     qp = S((decode_slots, chunk, hq, hd), jnp.float32)
-    pool = S((nb, block_size, hkv, hd), jnp.float32)
+    pool = S((nb, hkv, block_size, hd), jnp.float32)
     tab = S((decode_slots, mb), jnp.int32)
     vec = S((decode_slots,), jnp.int32)
     entries.append(("paged_attention", lambda: estimate_call(
@@ -279,8 +278,10 @@ def grid_zoo_entries(cfg) -> List[GridZooEntry]:
                                                paged_kv_scatter_pallas)
     from repro.serve.paged import device_pool_rows
 
-    d = cfg.d_model
-    n_out = max(cfg.d_ff, cfg.q_dim, cfg.moe_d_ff or 0)
+    # projections: two 128-lane blocks per axis (the wrappers pad smaller
+    # or unaligned blocks up to Mosaic's lane tile, and the formulas speak
+    # of the kernel's own dims)
+    d = n_out = 256
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     n, m = _nm_for(d)
 
@@ -290,7 +291,7 @@ def grid_zoo_entries(cfg) -> List[GridZooEntry]:
     bk8 = d // 2 if d % 2 == 0 else d                 # no %m constraint
 
     x = jnp.zeros((t, d), jnp.float32)
-    xd = jnp.zeros((2, d), jnp.float32)
+    xd = jnp.zeros((16, d), jnp.float32)             # 8-row blocks: I = 2
     w = jnp.zeros((d, n_out), jnp.float32)
     wq = jnp.zeros((d, n_out), jnp.int8)
     vec_d = jnp.ones((d,), jnp.float32)
@@ -325,9 +326,9 @@ def grid_zoo_entries(cfg) -> List[GridZooEntry]:
             "osparse_w8a8_decode",
             lambda x_, wq_, sm_, ws_, a_, b_: ops.osparse_matmul(
                 x_, wq_, sm_, None, ws_, n, m, act_scale=a_, bias=b_,
-                prune=False, block_t=1, block_o=bo, block_k=bk),
+                prune=False, block_t=8, block_o=bo, block_k=bk),
             (xd, wq, vec_d, vec_o, act, vec_o),
-            dict(mm, t=2, bt=1)),
+            dict(mm, t=16, bt=8)),
         GridZooEntry(
             "w8a8_matmul",
             lambda xq_, wq_, a_, ws_: ops.w8a8_matmul(
@@ -352,7 +353,7 @@ def grid_zoo_entries(cfg) -> List[GridZooEntry]:
     # its chunk); row 1 sits mid-sequence at pos 12/16.
     bs, mb, nb = 8, 8, 16
     rows = device_pool_rows(nb)
-    pool = jnp.zeros((rows, bs, hkv, hd), jnp.float32)
+    pool = jnp.zeros((rows, hkv, bs, hd), jnp.float32)
     atab = np.full((2, mb), -1, np.int32)
     atab[0, :4] = [1, 2, 3, 4]
     atab[1, :6] = [5, 6, 7, 8, 9, 10]

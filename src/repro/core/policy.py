@@ -52,12 +52,11 @@ class SparsityPolicy:
                      Pallas kernels (``repro.kernels.ops``): per-token mode
                      lowers to one ``nm_prune_matmul`` call, tile-consensus
                      to the k-blocked ``nm_spmm``, and the Outstanding-
-                     sparse W8A8 chain to ``osparse_matmul``.  The pure-jnp
-                     path stays the bit-exact oracle/fallback and is always
-                     used for scan-stacked ``layer_flag`` models (which
-                     need the mask-select form, not a fused GEMM).  The
-                     ``REPRO_PALLAS_INTERPRET`` env switch controls whether
-                     the kernels run interpreted (CPU) or compiled (TPU).
+                     sparse W8A8 chain to ``osparse_matmul``; scan-stacked
+                     ``layer_flag`` models choose kernel or dense per layer
+                     with ``lax.cond``.  The pure-jnp path stays the
+                     bit-exact oracle.  The platform decides whether the
+                     kernels compile (TPU) or run interpreted (CPU).
     """
 
     enabled: bool = True

@@ -24,9 +24,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "channel_norm_scale",
+    "kth_smallest",
+    "quantile",
     "robust_norm_scale",
     "precompute_scale",
     "score_activations",
@@ -55,6 +58,48 @@ def channel_norm_scale(w: jax.Array) -> jax.Array:
     return _min_normalize(norms)
 
 
+def _order_key(x: jax.Array) -> jax.Array:
+    """float32 → uint32 whose unsigned order is the float order."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
+
+
+def _from_order_key(key: jax.Array) -> jax.Array:
+    u = jnp.where(key >> 31 == 1, key & jnp.uint32(0x7FFFFFFF), ~key)
+    return jax.lax.bitcast_convert_type(u, jnp.float32)
+
+
+def kth_smallest(x: jax.Array, k: int) -> jax.Array:
+    """The ``k``-th smallest (0-based) element of a finite float32 array.
+
+    Builds the answer's 32-bit order key from the top bit down, one counting
+    pass over ``x`` per bit: the largest key with at most ``k`` elements
+    below it.  Streams the data 32 times instead of sorting it — a sort of
+    the 68 M weights of one Qwen2-7B MLP matrix is the slow part of
+    :func:`robust_norm_scale` on a TPU."""
+    key = _order_key(x.reshape(-1))
+
+    def bit(i, ans):
+        cand = ans | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(jnp.sum(key < cand) <= k, cand, ans)
+
+    return _from_order_key(jax.lax.fori_loop(0, 32, bit, jnp.uint32(0)))
+
+
+def quantile(x: jax.Array, q: float) -> jax.Array:
+    """``jnp.quantile(x, q)`` (linear interpolation) of a finite float32
+    array — the same value in the same compiled program — from two order
+    statistics instead of a sort."""
+    n = x.size
+    pos = np.float32(q) * (np.float32(n) - np.float32(1))
+    low, high = np.floor(pos), np.ceil(pos)
+    hw = pos - low
+    lw = np.float32(1) - hw
+    lo_v = kth_smallest(x, int(min(max(low, 0), n - 1)))
+    hi_v = kth_smallest(x, int(min(max(high, 0), n - 1)))
+    return lo_v * lw + hi_v * hw
+
+
 def robust_norm_scale(
     w: jax.Array, q_low: float = 0.005, q_high: float = 0.995
 ) -> jax.Array:
@@ -74,8 +119,8 @@ def robust_norm_scale(
       ``(d_in,)`` float32 scale.
     """
     wf = w.astype(jnp.float32)
-    lo = jnp.quantile(wf, q_low)
-    hi = jnp.quantile(wf, q_high)
+    lo = quantile(wf, q_low)
+    hi = quantile(wf, q_high)
     wc = jnp.clip(wf, lo, hi)
     mu = jnp.mean(wc)
     sd = jnp.sqrt(jnp.var(wc) + _EPS)

@@ -39,11 +39,6 @@ from typing import List, Optional
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                    # jax ≥ 0.4.35 moved it
-    from jax.experimental.shard_map import shard_map
-except ImportError:                     # pragma: no cover - drift shim
-    from jax.sharding import shard_map  # type: ignore
-
 __all__ = ["TPScope", "scope", "current", "degree", "column_parallel",
            "head_sharded_attention", "head_sharded_scatter",
            "replica_meshes"]
@@ -132,42 +127,43 @@ def column_parallel(fn, cols, out_axis: int = -1):
         return jax.lax.all_gather(y, ctx.axis, axis=out_axis % y.ndim,
                                   tiled=True)
 
-    return shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=P(), check_rep=False)(*cols)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(*cols)
 
 
 def head_sharded_attention(fn, q, k_pool, v_pool, rest):
     """Shard a paged-attention call over KV heads: ``q`` splits on its
-    Hq axis, the pools on their Hkv axis (both axis 2), the block table /
-    offsets / lengths in ``rest`` replicate, and the per-shard outputs
-    gather back along the head axis.  Per-head computation is exact, so
+    Hq axis (axis 2), the head-major pools on their Hkv axis (axis 1),
+    the block table / offsets / lengths in ``rest`` replicate, and the
+    per-shard outputs gather back along the head axis.  Per-head computation is exact, so
     the gathered result is bit-identical.  Returns None when no scope is
     active or the head counts do not divide."""
     ctx = current()
     if ctx is None:
         return None
     tp = ctx.size
-    hq, hkv = q.shape[2], k_pool.shape[2]
+    hq, hkv = q.shape[2], k_pool.shape[1]
     if hq % tp or hkv % tp or (hq // tp) % (hkv // tp):
         return None
-    hs = P(None, None, ctx.axis)
+    qs, ps = P(None, None, ctx.axis), P(None, ctx.axis)
 
     def body(q_, kp_, vp_, *rest_):
         with _suspended():
             y = fn(q_, kp_, vp_, *rest_)
         return jax.lax.all_gather(y, ctx.axis, axis=2, tiled=True)
 
-    in_specs = (hs, hs, hs) + tuple(P() for _ in rest)
-    return shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=P(), check_rep=False)(q, k_pool, v_pool,
-                                                     *rest)
+    in_specs = (qs, ps, ps) + tuple(P() for _ in rest)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(q, k_pool, v_pool,
+                                                         *rest)
 
 
 def head_sharded_scatter(fn, k_new, v_new, k_pool, v_pool, rest):
-    """Shard a paged KV scatter over KV heads: new rows and pools split
-    on their head axis (axis 2), table/pos/len replicate, and the
-    updated pools come back **gathered** (replicated) so the cache
-    pytree stays a plain replicated array between steps.  Returns None
+    """Shard a paged KV scatter over KV heads: new rows split on their
+    head axis (axis 2) and the head-major pools on theirs (axis 1),
+    table/pos/len replicate, and the updated pools come back **gathered**
+    (replicated) so the cache pytree stays a plain replicated array
+    between steps.  Returns None
     when no scope is active or Hkv does not divide."""
     ctx = current()
     if ctx is None:
@@ -175,17 +171,17 @@ def head_sharded_scatter(fn, k_new, v_new, k_pool, v_pool, rest):
     tp = ctx.size
     if k_new.shape[2] % tp:
         return None
-    hs = P(None, None, ctx.axis)
+    ns, ps = P(None, None, ctx.axis), P(None, ctx.axis)
 
     def body(kn_, vn_, kp_, vp_, *rest_):
         with _suspended():
             k2, v2 = fn(kn_, vn_, kp_, vp_, *rest_)
-        return (jax.lax.all_gather(k2, ctx.axis, axis=2, tiled=True),
-                jax.lax.all_gather(v2, ctx.axis, axis=2, tiled=True))
+        return (jax.lax.all_gather(k2, ctx.axis, axis=1, tiled=True),
+                jax.lax.all_gather(v2, ctx.axis, axis=1, tiled=True))
 
-    in_specs = (hs, hs, hs, hs) + tuple(P() for _ in rest)
-    return shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=(P(), P()), check_rep=False)(
+    in_specs = (ns, ns, ps, ps) + tuple(P() for _ in rest)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
+                         out_specs=(P(), P()), check_vma=False)(
                          k_new, v_new, k_pool, v_pool, *rest)
 
 

@@ -39,10 +39,10 @@ Dispatch order for model projections (``layers.linear.sparse_linear``):
      with static ``prune=False``, skipping selection in-kernel).  A
      projection bias rides the kernels' f32 dequant/accumulator epilogue
      instead of a separate HBM pass.  Scan-stacked ``layer_flag`` models
-     always fall back to the jnp mask-select form.
-  2. ``REPRO_PALLAS_INTERPRET`` env switch — ``1`` (default, CPU container)
-     runs the kernels through the Pallas interpreter; ``0`` compiles the
-     same BlockSpecs to Mosaic on a real TPU.
+     pick the kernel or the dense projection per layer with ``lax.cond``.
+  2. The platform (``kernels.platform``): Mosaic compiles the kernels on
+     a TPU backend; anywhere else (the CPU test hosts) the same BlockSpecs
+     run through the Pallas interpreter.
   3. The pure-jnp implementations in ``repro.core`` remain the bit-exact
      oracles (``kernels.ref`` wraps them per kernel for the test sweeps).
 
@@ -85,7 +85,7 @@ consecutive skips fetch nothing).
 Dispatch for the paged pool (``models/attention.paged_attention`` reads,
 ``models/attention.paged_kv_update`` writes) runs the same ladder as the
 projections: ``SparsityPolicy.use_pallas_kernels`` →
-``REPRO_PALLAS_INTERPRET`` (interpret vs Mosaic) → the jnp
+the platform (Mosaic vs interpreter) → the jnp
 gather-then-attend / flat-index-scatter oracles (the gather oracle is
 always used for windowed paged shapes and non-tile-divisible query
 counts).  Both directions carry chaos-harness sites
@@ -102,7 +102,7 @@ scalar-prefetched block tables:
   flash_attention       1.13 MiB     0 B   recurrentgemma_2b (1,10,8,8)
   nm_prune              2.00 MiB     0 B   llama31_8b        (1,8)
   nm_prune_matmul       2.76 MiB     0 B   llama31_8b        (1,56,8)
-  nm_spmm               8.77 MiB     0 B   llama31_8b        (1,56,2)
+  nm_spmm               2.75 MiB     0 B   llama31_8b        (1,56,8)
   osparse_matmul        2.01 MiB     0 B   llama31_8b        (1,56,16)
   osparse_w8a8_decode   0.32 MiB     0 B   llama31_8b        (1,56,8)
   paged_attention       0.69 MiB  8256 B   recurrentgemma_2b (8,10,2,256)

@@ -21,11 +21,14 @@ interpret mode).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import contract_precision, resolve_interpret
 
 __all__ = ["flash_attention_pallas"]
 
@@ -43,13 +46,14 @@ def softmax_init(m_ref, l_ref, acc_ref) -> None:
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
-def softmax_update(s, v, m_ref, l_ref, acc_ref) -> None:
+def softmax_update(s, v, m_ref, l_ref, acc_ref, precision=None) -> None:
     """Fold one KV block into the running (m, l, acc) scratch.
 
     ``s`` is the already-masked (bq, bk) score tile — invalid lanes hold
     ``_NEG``, which the ``s > _NEG / 2`` guard turns into exactly-zero
     probabilities (a fully-masked row would otherwise yield
-    exp(_NEG − _NEG) = 1 per lane).  ``v`` is the (bk, d) value tile.
+    exp(_NEG − _NEG) = 1 per lane).  ``v`` is the (bk, d) value tile;
+    ``precision`` that of the ``p @ v`` contraction.
     """
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -58,7 +62,7 @@ def softmax_update(s, v, m_ref, l_ref, acc_ref) -> None:
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
     acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p, v, preferred_element_type=jnp.float32, precision=precision)
     m_ref[...] = m_new
 
 
@@ -96,7 +100,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         k = k_ref[0, 0].astype(jnp.float32)               # (bk, d)
         v = v_ref[0, 0].astype(jnp.float32)
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq, bk)
+        prec = contract_precision(q_ref.dtype, k_ref.dtype)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                    precision=prec)                        # (bq, bk)
         q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         if causal:
@@ -104,7 +110,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if window > 0:
             s = jnp.where(k_pos > q_pos - window, s, _NEG)
 
-        softmax_update(s, v, m_ref, l_ref, acc_ref)
+        softmax_update(s, v, m_ref, l_ref, acc_ref, prec)
 
     @pl.when(ki == n_kv - 1)
     def _finalize():
@@ -121,7 +127,7 @@ def flash_attention_pallas(
     window: int = 0,              # >0 → sliding-window (SWA/local) band
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,       # CPU container default
+    interpret: Optional[bool] = None,  # None → chosen by platform
 ) -> jax.Array:
     b, h, t, d = q.shape
     hkv, s = k.shape[1], k.shape[2]
@@ -153,6 +159,6 @@ def flash_attention_pallas(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out

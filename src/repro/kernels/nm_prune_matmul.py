@@ -4,7 +4,7 @@ The naive per-token path (``prune_input`` then ``xp @ w``) materializes the
 masked activations: X streams HBM→VMEM for scoring/masking, the masked copy
 is written back to HBM, then the dense matmul reads it again — three full
 passes over a T×D tensor that exists only to be multiplied once.  This
-kernel fuses score → iterative top-N select → mask → GEMM into a single
+kernel fuses score → in-group rank select → mask → GEMM into a single
 ``pallas_call``: the masked copy lives only in registers and is never
 materialized in HBM.  The GEMM's own block streaming (each X block is
 re-fetched once per output block, as in any tiled matmul — dense included)
@@ -17,9 +17,9 @@ Extra HBM traffic vs the dense GEMM:   none          (fused, this kernel)
 The grid is (T/bt, N_out/bo, D/bk) with a float32 accumulator scratch; the
 per-token N:M selection is local to each contiguous group of M channels, so
 k-blocking (bk % m == 0) is exact — every k-step prunes its own groups and
-accumulates its partial product.  Selection is the same iterative
-first-occurrence argmax as ``nm_prune_pallas`` (lowest index wins on ties),
-so masks are bit-identical to the ``nm.apply_nm`` oracle.
+accumulates its partial product.  Selection is the shared in-group rank of
+``nm_prune_pallas`` (lowest index wins on ties), so masks are bit-identical
+to the ``nm.apply_nm`` oracle.
 """
 from __future__ import annotations
 
@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.nm_prune import _select_topn_mask
+from repro.kernels.nm_prune import nm_rank
+from repro.kernels.platform import contract_precision, resolve_interpret
 
 __all__ = ["nm_prune_matmul_pallas"]
 
@@ -47,18 +48,18 @@ def _kernel(x_ref, w_ref, scale_ref, bias_ref, o_ref, acc_ref, *, n: int,
     x = x_ref[...]                                     # (bt, bk)
     s = jnp.abs(x.astype(jnp.float32))
     if has_scale:
-        s = s * scale_ref[...].astype(jnp.float32)[None, :]
-    bt, bk = s.shape
-    keep = _select_topn_mask(s.reshape(bt, bk // m, m), n, m).reshape(bt, bk)
+        s = s * scale_ref[...].astype(jnp.float32)
+    keep = nm_rank(s, m) < n
     xp = jnp.where(keep, x.astype(jnp.float32), 0.0)
     acc_ref[...] += jnp.dot(xp, w_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=contract_precision(x.dtype, w_ref.dtype))
 
     @pl.when(k == k_steps - 1)
     def _finish():
         acc = acc_ref[...]
         if has_bias:  # bias-add folded into the epilogue (free: acc is hot)
-            acc = acc + bias_ref[...].astype(jnp.float32)[None, :]
+            acc = acc + bias_ref[...].astype(jnp.float32)
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -74,7 +75,7 @@ def nm_prune_matmul_pallas(
     block_t: int = 256,
     block_o: int = 256,
     block_k: int = 512,
-    interpret: bool = True,             # CPU container default; False on TPU
+    interpret: Optional[bool] = None,   # None → chosen by platform
 ) -> jax.Array:
     t, d = x.shape
     n_out = w.shape[-1]
@@ -99,11 +100,11 @@ def nm_prune_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bt, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bo), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk,), lambda i, j, k: (k,)),
-            pl.BlockSpec((bo,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bk), lambda i, j, k: (0, k)),
+            pl.BlockSpec((1, bo), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bt, bo), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, n_out), out_dtype),
         scratch_shapes=[pltpu.VMEM((bt, bo), jnp.float32)],
-        interpret=interpret,
-    )(x, w, scale, bias)
+        interpret=resolve_interpret(interpret),
+    )(x, w, scale.reshape(1, d), bias.reshape(1, n_out))

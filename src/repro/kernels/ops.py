@@ -1,8 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU deployment set ``REPRO_PALLAS_INTERPRET=0`` (or pass
-``interpret=False``) and the same BlockSpecs compile to Mosaic.
+``interpret=None`` (the default) lets the platform choose: Mosaic on a TPU
+backend, the Pallas interpreter elsewhere (``kernels.platform``).
 
 Wrappers handle leading-batch flattening and shape padding so callers can
 use them as drop-in linear ops: when no well-sized block evenly divides an
@@ -14,7 +13,7 @@ zero weight rows, and padded output columns are sliced away.
 """
 from __future__ import annotations
 
-import os
+import math
 from typing import Optional
 
 import jax
@@ -33,12 +32,7 @@ __all__ = [
     "nm_spmm",
     "osparse_matmul",
     "w8a8_matmul",
-    "default_interpret",
 ]
-
-
-def default_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def _flatten(x: jax.Array):
@@ -63,17 +57,31 @@ def _block_and_pad(total: int, target: int, multiple_of: int = 1):
     """Pick a block size ≤ target (multiple of ``multiple_of``) and the
     padded axis length it divides.
 
-    Prefers an exact divisor of ``total`` (zero padding, full occupancy);
+    An axis no longer than ``target`` is one full-extent block.  Otherwise
+    prefers an exact divisor of ``total`` (zero padding, full occupancy);
     when only degenerately small divisors exist (e.g. prime token counts)
     or none is a multiple of ``multiple_of``, falls back to a full-size
     block with zero padding up to the next block multiple.
     """
+    if total <= target:
+        return total, total
     div = _largest_divisor(total, target, multiple_of)
     lim = min(total, target)
     if div is not None and 2 * div >= lim:
         return div, total
     block = max(lim - lim % multiple_of, multiple_of)
     return block, total + (-total) % block
+
+
+def _rows(total: int, target: int):
+    """Block for a sublane (second-to-last) axis: Mosaic tiles 8 rows."""
+    return _block_and_pad(total, target, multiple_of=8)
+
+
+def _lanes(total: int, target: int, m: int = 1):
+    """Block for a lane (last) axis: Mosaic tiles 128 lanes; contraction
+    blocks also hold whole N:M groups of ``m`` channels."""
+    return _block_and_pad(total, target, multiple_of=math.lcm(128, m))
 
 
 def _pad_to(a: jax.Array, axis: int, new_size: int, value: float = 0.0):
@@ -99,12 +107,11 @@ def nm_prune(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused Amber prune over any (..., D) tensor."""
-    interpret = default_interpret() if interpret is None else interpret
     xf, lead = _flatten(x)
     t, d = xf.shape
     _check_groups(d, m)
-    bt, tp = _block_and_pad(t, block_t)
-    bd, dp = _block_and_pad(d, block_d, multiple_of=m)
+    bt, tp = _rows(t, block_t)
+    bd, dp = _lanes(d, block_d, m)
     xf = _pad_to(_pad_to(xf, 0, tp), 1, dp)
     if scale is not None:
         scale = _pad_to(scale, 0, dp)
@@ -129,7 +136,6 @@ def nm_prune_matmul(
 
     ``bias`` (``(N_out,)``) is folded into the kernel epilogue — the add
     happens on the hot f32 accumulator instead of a separate HBM pass."""
-    interpret = default_interpret() if interpret is None else interpret
     # tensor parallelism (distributed/tp.py): under an active TP scope the
     # call re-enters itself column-parallel — each device runs this same
     # wrapper on its N_out slice (scope suspended inside the shard body),
@@ -145,9 +151,9 @@ def nm_prune_matmul(
     t, d = xf.shape
     n_out = w.shape[-1]
     _check_groups(d, m)
-    bt, tp = _block_and_pad(t, block_t)
-    bo, op = _block_and_pad(n_out, block_o)
-    bk, dp = _block_and_pad(d, block_k, multiple_of=m)
+    bt, tp = _rows(t, block_t)
+    bo, op = _lanes(n_out, block_o)
+    bk, dp = _lanes(d, block_k, m)
     xf = _pad_to(_pad_to(xf, 0, tp), 1, dp)
     w = _pad_to(_pad_to(w, 0, dp), 1, op)
     if scale is not None:
@@ -167,7 +173,7 @@ def nm_spmm(
     m: int,
     tile: int = 256,
     block_o: int = 256,
-    block_k: int = 2048,
+    block_k: int = 512,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Tile-consensus compacted matmul over any (..., D) input.
@@ -178,7 +184,6 @@ def nm_spmm(
     multiple — exactly ``pruner.sparse_matmul``'s tiling, never a smaller
     divisor (which would change which tokens vote in each pool).
     """
-    interpret = default_interpret() if interpret is None else interpret
     # column-parallel TP: the consensus vote runs over the full (replicated)
     # activations/K axis on every device, so sharding N_out cannot change
     # which channels win — outputs stay bit-identical
@@ -194,8 +199,8 @@ def nm_spmm(
     _check_groups(d, m)
     bt = min(tile, t)
     tp = t + (-t) % bt
-    bo, op = _block_and_pad(n_out, block_o)
-    bk, dp = _block_and_pad(d, block_k, multiple_of=m)
+    bo, op = _lanes(n_out, block_o)
+    bk, dp = _lanes(d, block_k, m)
     xf = _pad_to(_pad_to(xf, 0, tp), 1, dp)
     w = _pad_to(_pad_to(w, 0, dp), 1, op)
     if scale is not None:
@@ -229,7 +234,6 @@ def osparse_matmul(
     dequant epilogue; ``prune=False`` skips the N:M selection statically,
     turning the same kernel into the decode-phase smoothed W8A8 GEMM.
     """
-    interpret = default_interpret() if interpret is None else interpret
     if not prune:
         n = m = 1  # no selection → no channel-group divisibility constraint
     # column-parallel TP: wq/w_scale/bias are N_out-aligned and shard;
@@ -246,9 +250,9 @@ def osparse_matmul(
     t, d = xf.shape
     n_out = wq.shape[-1]
     _check_groups(d, m)
-    bt, tp = _block_and_pad(t, block_t)
-    bo, op = _block_and_pad(n_out, block_o)
-    bk, dp = _block_and_pad(d, block_k, multiple_of=m)
+    bt, tp = _rows(t, block_t)
+    bo, op = _lanes(n_out, block_o)
+    bk, dp = _lanes(d, block_k, m)
     xf = _pad_to(_pad_to(xf, 0, tp), 1, dp)
     wq = _pad_to(_pad_to(wq, 0, dp), 1, op)
     smooth = _pad_to(smooth, 0, dp, value=1.0)  # padded channels: 0/1 = 0
@@ -274,7 +278,6 @@ def w8a8_matmul(
     block_k: int = 512,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    interpret = default_interpret() if interpret is None else interpret
     y = tp_mod.column_parallel(
         lambda wq_, ws_: w8a8_matmul(xq, wq_, x_scale, ws_, block_t=block_t,
                                      block_o=block_o, block_k=block_k,
@@ -285,9 +288,9 @@ def w8a8_matmul(
     xf, lead = _flatten(xq)
     t, d = xf.shape
     n_out = wq.shape[-1]
-    bt, tp = _block_and_pad(t, block_t)
-    bo, op = _block_and_pad(n_out, block_o)
-    bk, dp = _block_and_pad(d, block_k)
+    bt, tp = _rows(t, block_t)
+    bo, op = _lanes(n_out, block_o)
+    bk, dp = _lanes(d, block_k)
     xf = _pad_to(_pad_to(xf, 0, tp), 1, dp)
     wq = _pad_to(_pad_to(wq, 0, dp), 1, op)
     w_scale = _pad_to(w_scale, 0, op)

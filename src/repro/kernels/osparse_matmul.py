@@ -27,7 +27,8 @@ Two quantization modes (matching ``repro.core.quant``):
 
 Scoring uses the Amber channel scale on the *smoothed* activations, exactly
 as ``layers.linear._quantized`` does; selection is the shared iterative
-first-occurrence argmax, so masks match ``nm.apply_nm`` bit-for-bit.
+in-group rank (``nm_prune.nm_rank``), so masks match ``nm.apply_nm``
+bit-for-bit.
 """
 from __future__ import annotations
 
@@ -39,7 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.nm_prune import _select_topn_mask
+from repro.kernels.nm_prune import nm_rank
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["osparse_matmul_pallas"]
 
@@ -52,15 +54,13 @@ def _pruned_smoothed(x, smooth, amber, *, n, m, has_amber, prune=True):
     ``prune=False`` (static) skips scoring/selection entirely — the same
     kernel then runs the plain smoothed W8A8 chain, which is what the
     decode phase uses (the policy gates pruning to prefill)."""
-    xs = x.astype(jnp.float32) / smooth.astype(jnp.float32)[None, :]
+    xs = x.astype(jnp.float32) / smooth.astype(jnp.float32)
     if not prune:
         return xs
     s = jnp.abs(xs)
     if has_amber:
-        s = s * amber.astype(jnp.float32)[None, :]
-    bt, bk = s.shape
-    keep = _select_topn_mask(s.reshape(bt, bk // m, m), n, m).reshape(bt, bk)
-    return jnp.where(keep, xs, 0.0)
+        s = s * amber.astype(jnp.float32)
+    return jnp.where(nm_rank(s, m) < n, xs, 0.0)
 
 
 def _quantize(xp, scale):
@@ -79,7 +79,7 @@ def _kernel(x_ref, wq_ref, smooth_ref, amber_ref, ws_ref, as_ref, bias_ref,
 
     def epilogue(o):  # (bt, bo) f32 dequantized — fold the bias-add in
         if has_bias:
-            o = o + bias_ref[...].astype(jnp.float32)[None, :]
+            o = o + bias_ref[...].astype(jnp.float32)
         return o
 
     if per_token:
@@ -116,13 +116,13 @@ def _kernel(x_ref, wq_ref, smooth_ref, amber_ref, ws_ref, as_ref, bias_ref,
             scale = jnp.maximum(amax_ref[...], _EPS) / 127.0
             w_scale = ws_ref[...].astype(jnp.float32)
             o_ref[...] = epilogue(acc_ref[...].astype(jnp.float32) * scale
-                                  * w_scale[None, :]).astype(o_ref.dtype)
+                                  * w_scale).astype(o_ref.dtype)
     else:
         @pl.when(k == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        act_scale = as_ref[0]
+        act_scale = as_ref[0, 0]
         acc_ref[...] += jax.lax.dot_general(
             _quantize(xp(), act_scale), wq_ref[...],
             (((1,), (0,)), ((), ())),
@@ -133,7 +133,7 @@ def _kernel(x_ref, wq_ref, smooth_ref, amber_ref, ws_ref, as_ref, bias_ref,
         def _finish():
             w_scale = ws_ref[...].astype(jnp.float32)
             o_ref[...] = epilogue(acc_ref[...].astype(jnp.float32) * act_scale
-                                  * w_scale[None, :]).astype(o_ref.dtype)
+                                  * w_scale).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "m", "prune", "per_token",
@@ -154,7 +154,7 @@ def osparse_matmul_pallas(
     block_t: int = 256,
     block_o: int = 256,
     block_k: int = 512,
-    interpret: bool = True,             # CPU container default; False on TPU
+    interpret: Optional[bool] = None,   # None → chosen by platform
 ) -> jax.Array:
     t, d = x.shape
     n_out = wq.shape[-1]
@@ -180,7 +180,8 @@ def osparse_matmul_pallas(
     # per-token mode runs the k axis twice: absmax sweep, then GEMM sweep
     k_grid = (2 * k_steps) if per_token else k_steps
     x_block = lambda i, j, k: (i, k % k_steps)
-    d_block = lambda i, j, k: (k % k_steps,)
+    d_block = lambda i, j, k: (0, k % k_steps)
+    o_block = lambda i, j, k: (0, j)
 
     return pl.pallas_call(
         functools.partial(_kernel, n=n, m=m, has_amber=has_amber,
@@ -190,11 +191,12 @@ def osparse_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bt, bk), x_block),
             pl.BlockSpec((bk, bo), lambda i, j, k: (k % k_steps, j)),
-            pl.BlockSpec((bk,), d_block),
-            pl.BlockSpec((bk,), d_block),
-            pl.BlockSpec((bo,), lambda i, j, k: (j,)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
-            pl.BlockSpec((bo,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bk), d_block),
+            pl.BlockSpec((1, bk), d_block),
+            pl.BlockSpec((1, bo), o_block),
+            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bo), o_block),
         ],
         out_specs=pl.BlockSpec((bt, bo), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, n_out), jnp.float32),
@@ -202,6 +204,8 @@ def osparse_matmul_pallas(
             pltpu.VMEM((bt, bo), jnp.int32),
             pltpu.VMEM((bt, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(x, wq, smooth, amber, w_scale,
-      jnp.asarray(act_scale, jnp.float32).reshape(1), bias)
+        interpret=resolve_interpret(interpret),
+    )(x, wq, smooth.reshape(1, d), amber.reshape(1, d),
+      w_scale.reshape(1, n_out),
+      jnp.asarray(act_scale, jnp.float32).reshape(1, 1),
+      bias.reshape(1, n_out))

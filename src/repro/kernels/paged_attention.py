@@ -16,10 +16,13 @@ that are unallocated (``-1``) or entirely outside the row's valid
 skipped before their matmuls issue — decode attention is O(pos) per row,
 not O(max_blocks * block_size).
 
-Layout: q ``(B, Tq, Hq, hd)``; pools ``(num_blocks, block_size, Hkv, hd)``
-shared by every row (GQA is resolved in the index map: query head ``h``
-reads KV head ``h // (Hq // Hkv)`` — the pool is never head-repeated in
-HBM).  Grid ``(B, Hq, Tq/bq, max_blocks)`` with the block axis innermost;
+Layout: q ``(B, Tq, Hq, hd)`` (viewed head-major inside the wrapper);
+pools are head-major ``(num_blocks, Hkv, block_size, hd)`` shared by every
+row, so one KV head of one physical block is a ``(block_size, hd)`` tile —
+the last two block dims Mosaic tiles on.  GQA is resolved in the index
+map: query head ``h`` reads KV head ``h // (Hq // Hkv)`` — the pool is
+never head-repeated in HBM.  Grid ``(B, Hq, Tq/bq, max_blocks)`` with the
+block axis innermost;
 scratch (m, l, acc) carries the online-softmax state across blocks;
 finalization divides on the last block.  Masking is by **absolute**
 positions: query row r sits at ``q_offset[b] + qi*bq + r`` and block
@@ -27,12 +30,14 @@ positions: query row r sits at ``q_offset[b] + qi*bq + r`` and block
 cache offset (scalar ``q_offset`` broadcast per row) and vector-position
 decode (per-row ``q_offset``) lower to the same kernel.
 
-MXU alignment: bq and block_size should be multiples of the hardware tile
-on real TPUs (any value in interpret mode — decode runs bq=1).
+Mosaic tiling: ``block_size`` must be a multiple of the sublane tile of
+the pool dtype (16 for bf16) and ``hd`` a multiple of 128; ``bq`` is 128
+or the whole query count (decode runs bq=1).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import (_NEG, softmax_finalize,
                                            softmax_init, softmax_update)
+from repro.kernels.platform import contract_precision, resolve_interpret
 
 __all__ = ["paged_attention_pallas", "paged_kv_scatter_pallas",
            "paged_kernel_covers"]
@@ -99,11 +105,13 @@ def _kernel(tab_ref, qoff_ref, kvlen_ref,      # scalar prefetch
 
     @pl.when(visible)
     def _accumulate():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale            # (bq, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                    # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq, bs)
+        prec = contract_precision(q_ref.dtype, k_ref.dtype)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                    precision=prec)                        # (bq, bs)
         q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 0)
         k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
         valid = k_pos < kvl
@@ -118,29 +126,29 @@ def _kernel(tab_ref, qoff_ref, kvlen_ref,      # scalar prefetch
         col = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
         v = jnp.where(k_lo + col < kvl, v, 0.0)
 
-        softmax_update(s, v, m_ref, l_ref, acc_ref)
+        softmax_update(s, v, m_ref, l_ref, acc_ref, prec)
 
     @pl.when(ki == mb - 1)
     def _finalize():
-        o_ref[0, :, 0, :] = softmax_finalize(l_ref, acc_ref, o_ref.dtype)
+        o_ref[0, 0] = softmax_finalize(l_ref, acc_ref, o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "interpret"))
 def paged_attention_pallas(
     q: jax.Array,             # (B, Tq, Hq, hd)
-    k_pool: jax.Array,        # (num_blocks, block_size, Hkv, hd)
-    v_pool: jax.Array,        # (num_blocks, block_size, Hkv, hd)
+    k_pool: jax.Array,        # (num_blocks, Hkv, block_size, hd)
+    v_pool: jax.Array,        # (num_blocks, Hkv, block_size, hd)
     block_table: jax.Array,   # (B, max_blocks) int32, -1 = unallocated
     q_offset: jax.Array,      # (B,) int32 absolute position of q[:, 0]
     kv_len: jax.Array,        # (B,) int32 valid KV rows per table row
     causal: bool = True,
     window: int = 0,          # >0 → sliding-window band by absolute pos
     block_q: int = 128,
-    interpret: bool = True,   # CPU container default
+    interpret: Optional[bool] = None,  # None → chosen by platform
 ) -> jax.Array:
     b, t, hq, hd = q.shape
-    nb, bs, hkv = k_pool.shape[:3]
+    nb, hkv, bs = k_pool.shape[:3]
     mb = block_table.shape[1]
     g = hq // hkv
     bq = min(block_q, t)
@@ -163,19 +171,20 @@ def paged_attention_pallas(
         pb, vis = _block_visible(tab_ref, qoff_ref, kvlen_ref, bi, qi, ki,
                                  bq=bq, bs=bs, causal=causal, window=window)
         pb = jnp.where(vis, pb, tab_ref[bi, 0])
-        return (jnp.maximum(pb, 0), 0, h // g, 0)
+        return (jnp.maximum(pb, 0), h // g, 0, 0)
+
+    def q_index(bi, h, qi, ki, *_):
+        return (bi, h, qi, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, hq, t // bq, mb),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd),
-                         lambda bi, h, qi, ki, *_: (bi, qi, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd), k_index),
-            pl.BlockSpec((1, bs, 1, hd), k_index),
+            pl.BlockSpec((1, 1, bq, hd), q_index),
+            pl.BlockSpec((1, 1, bs, hd), k_index),
+            pl.BlockSpec((1, 1, bs, hd), k_index),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda bi, h, qi, ki, *_: (bi, qi, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, bq, hd), q_index),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
@@ -186,10 +195,10 @@ def paged_attention_pallas(
         functools.partial(_kernel, mb=mb, bq=bq, bs=bs, causal=causal,
                           window=window, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, hq, hd), q.dtype),
-        interpret=interpret,
-    )(tab, qoff, kvl, q, k_pool, v_pool)
-    return out
+        out_shape=jax.ShapeDtypeStruct((b, hq, t, hd), q.dtype),
+        interpret=resolve_interpret(interpret),
+    )(tab, qoff, kvl, q.transpose(0, 2, 1, 3), k_pool, v_pool)
+    return out.transpose(0, 2, 1, 3)
 
 
 def _scatter_visible(tab_ref, pos_ref, len_ref, bi, ci, *, bs: int, mb: int):
@@ -212,7 +221,7 @@ def _scatter_visible(tab_ref, pos_ref, len_ref, bi, ci, *, bs: int, mb: int):
 
 def _scatter_kernel(tab_ref, pos_ref, len_ref,          # scalar prefetch
                     kn_ref, vn_ref, kin_ref, vin_ref, ko_ref, vo_ref, *,
-                    bs: int, mb: int, t: int):
+                    bs: int, mb: int, t: int, hkv: int):
     bi = pl.program_id(0)
     ci = pl.program_id(1)
     p0 = pos_ref[bi]
@@ -236,16 +245,19 @@ def _scatter_kernel(tab_ref, pos_ref, len_ref,          # scalar prefetch
         row = jax.lax.broadcasted_iota(jnp.int32, (bs, t), 0)
         tok = jax.lax.broadcasted_iota(jnp.int32, (bs, t), 1)
         sel = ((lo + row == p0 + tok) & (tok < cl)).astype(jnp.float32)
-        wr = (lo + row[:, 0] >= p0) & (lo + row[:, 0] < p0 + cl)  # (bs,)
-        wr = wr[:, None, None]
+        col = lo + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+        wr = (col >= p0) & (col < p0 + cl)                        # (bs, 1)
 
+        # one-hot row selection: exact only at full precision for f32 rows
+        prec = contract_precision(kn_ref.dtype)
         for new_ref, cur_ref, out_ref in ((kn_ref, kin_ref, ko_ref),
                                           (vn_ref, vin_ref, vo_ref)):
-            new = new_ref[0].reshape(t, -1).astype(jnp.float32)
-            rows = jnp.dot(sel, new, preferred_element_type=jnp.float32)
-            cur = cur_ref[0]
-            rows = rows.reshape(cur.shape).astype(cur.dtype)
-            out_ref[0] = jnp.where(wr, rows, cur)
+            for h in range(hkv):        # head-major: one (bs, hd) tile each
+                new = new_ref[0, h].astype(jnp.float32)           # (t, hd)
+                rows = jnp.dot(sel, new, preferred_element_type=jnp.float32,
+                               precision=prec)
+                cur = cur_ref[0, h]
+                out_ref[0, h] = jnp.where(wr, rows.astype(cur.dtype), cur)
 
 
 # VMEM cap for the resident chunk tile of one scatter call, per K/V leaf.
@@ -263,12 +275,12 @@ _MAX_CHUNK_TILE_BYTES = 2 * 1024 * 1024
 def paged_kv_scatter_pallas(
     k_new: jax.Array,         # (B, T, Hkv, hd) chunk K (decode: T == 1)
     v_new: jax.Array,         # (B, T, Hkv, hd)
-    k_pool: jax.Array,        # (num_blocks, block_size, Hkv, hd)
-    v_pool: jax.Array,        # (num_blocks, block_size, Hkv, hd)
+    k_pool: jax.Array,        # (num_blocks, Hkv, block_size, hd)
+    v_pool: jax.Array,        # (num_blocks, Hkv, block_size, hd)
     block_table: jax.Array,   # (B, max_blocks) int32, -1 = unallocated
     pos: jax.Array,           # (B,) int32 absolute position of k_new[:, 0]
     chunk_len: jax.Array,     # (B,) int32 valid rows of k_new per row
-    interpret: bool = True,   # CPU container default
+    interpret: Optional[bool] = None,  # None → chosen by platform
 ) -> tuple[jax.Array, jax.Array]:
     """Write chunk K/V rows into the shared pool through the block table,
     entirely in-kernel: the grid walks the logical blocks the chunk spans,
@@ -295,8 +307,12 @@ def paged_kv_scatter_pallas(
     aliased pools, so untouched blocks pass through unchanged.
     """
     b, t = k_new.shape[:2]
-    nb, bs, hkv, hd = k_pool.shape
+    nb, hkv, bs, hd = k_pool.shape
     assert v_new.shape == k_new.shape and v_pool.shape == k_pool.shape
+    interpret = resolve_interpret(interpret)
+    # head-major chunk rows, matching the pool: (B, Hkv, T, hd)
+    k_new = k_new.transpose(0, 2, 1, 3)
+    v_new = v_new.transpose(0, 2, 1, 3)
 
     row_bytes = hkv * hd * k_new.dtype.itemsize
     ts = max(1, min(t, _MAX_CHUNK_TILE_BYTES // row_bytes))
@@ -306,7 +322,7 @@ def paged_kv_scatter_pallas(
         for off in range(0, t, ts):
             sl = slice(off, min(off + ts, t))
             k_pool, v_pool = _scatter_call(
-                k_new[:, sl], v_new[:, sl], k_pool, v_pool, block_table,
+                k_new[:, :, sl], v_new[:, :, sl], k_pool, v_pool, block_table,
                 posv + off, jnp.clip(cl - off, 0, sl.stop - off), interpret)
         return k_pool, v_pool
     return _scatter_call(k_new, v_new, k_pool, v_pool, block_table,
@@ -315,9 +331,10 @@ def paged_kv_scatter_pallas(
 
 def _scatter_call(k_new, v_new, k_pool, v_pool, block_table, pos,
                   chunk_len, interpret):
-    """One bounded-tile scatter ``pallas_call`` (see the public wrapper)."""
-    b, t = k_new.shape[:2]
-    nb, bs, hkv, hd = k_pool.shape
+    """One bounded-tile scatter ``pallas_call`` (see the public wrapper);
+    ``k_new``/``v_new`` arrive head-major ``(B, Hkv, T, hd)``."""
+    b, hkv, t = k_new.shape[:3]
+    nb, _, bs, hd = k_pool.shape
     mb = block_table.shape[1]
     # an unaligned T-row chunk spans at most this many logical blocks
     n_lb = min((t - 1) // bs + 2, t)
@@ -350,18 +367,18 @@ def _scatter_call(k_new, v_new, k_pool, v_pool, block_table, pos,
         num_scalar_prefetch=3,
         grid=(b, n_lb),
         in_specs=[
-            pl.BlockSpec((1, t, hkv, hd), new_index),   # k_new
-            pl.BlockSpec((1, t, hkv, hd), new_index),   # v_new
-            pl.BlockSpec((1, bs, hkv, hd), pool_index),  # k_pool (in)
-            pl.BlockSpec((1, bs, hkv, hd), pool_index),  # v_pool (in)
+            pl.BlockSpec((1, hkv, t, hd), new_index),   # k_new
+            pl.BlockSpec((1, hkv, t, hd), new_index),   # v_new
+            pl.BlockSpec((1, hkv, bs, hd), pool_index),  # k_pool (in)
+            pl.BlockSpec((1, hkv, bs, hd), pool_index),  # v_pool (in)
         ],
         out_specs=[
-            pl.BlockSpec((1, bs, hkv, hd), pool_index),  # k_pool (out)
-            pl.BlockSpec((1, bs, hkv, hd), pool_index),  # v_pool (out)
+            pl.BlockSpec((1, hkv, bs, hd), pool_index),  # k_pool (out)
+            pl.BlockSpec((1, hkv, bs, hd), pool_index),  # v_pool (out)
         ],
     )
     return pl.pallas_call(
-        functools.partial(_scatter_kernel, bs=bs, mb=mb, t=t),
+        functools.partial(_scatter_kernel, bs=bs, mb=mb, t=t, hkv=hkv),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],

@@ -13,11 +13,14 @@ effective HBM bandwidth (the reason W8A8 helps decode).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["w8a8_matmul_pallas"]
 
@@ -37,10 +40,10 @@ def _kernel(x_ref, w_ref, ws_ref, xs_ref, o_ref, acc_ref, *, k_steps: int):
 
     @pl.when(k == k_steps - 1)
     def _finish():
-        x_scale = xs_ref[0]
+        x_scale = xs_ref[0, 0]
         w_scale = ws_ref[...].astype(jnp.float32)
         o_ref[...] = (
-            acc_ref[...].astype(jnp.float32) * x_scale * w_scale[None, :]
+            acc_ref[...].astype(jnp.float32) * x_scale * w_scale
         ).astype(o_ref.dtype)
 
 
@@ -54,7 +57,7 @@ def w8a8_matmul_pallas(
     block_t: int = 256,
     block_o: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,   # None → chosen by platform
 ) -> jax.Array:
     t, d = xq.shape
     n_out = wq.shape[-1]
@@ -68,11 +71,12 @@ def w8a8_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bt, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bo), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bo,), lambda i, j, k: (j,)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
+            pl.BlockSpec((1, bo), lambda i, j, k: (0, j)),
+            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bt, bo), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, n_out), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, bo), jnp.int32)],
-        interpret=interpret,
-    )(xq, wq, w_scale, x_scale.reshape(1))
+        interpret=resolve_interpret(interpret),
+    )(xq, wq, w_scale.reshape(1, n_out), x_scale.reshape(1, 1))

@@ -11,16 +11,11 @@ import math
 import jax
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_mesh_auto",
-           "make_serving_mesh", "abstract_mesh"]
+           "make_serving_mesh"]
 
 
 def make_mesh_auto(shape, axes):
-    """``jax.make_mesh`` with Auto axis types across the jax API drift.
-
-    Newer jax grew an ``axis_types`` kwarg (and ``jax.sharding.AxisType``)
-    for the explicit-sharding mode; Auto is both the new default and the
-    only behaviour older versions have, so falling back to the bare call
-    is semantically identical.
+    """``jax.make_mesh`` with Auto axis types.
 
     Raises ValueError up front when the mesh asks for more devices than
     the backend exposes — ``jax.make_mesh``'s own error talks about array
@@ -35,24 +30,8 @@ def make_mesh_auto(shape, axes):
             f"backend has {have}; lower dp/tp or export "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={want} "
             "BEFORE the first jax call to fake host devices")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
-
-
-def abstract_mesh(shape, axes):
-    """``jax.sharding.AbstractMesh`` across the positional-signature drift:
-    newer jax takes ``(shape, axis_names)``, 0.4.x takes one tuple of
-    ``(name, size)`` pairs.  Validates partition specs without devices."""
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,7 +27,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--pallas-kernels", action="store_true",
                     help="route sparse projections through the fused Pallas "
-                         "kernels (REPRO_PALLAS_INTERPRET=0 on real TPUs)")
+                         "kernels (Mosaic on a TPU, interpreted elsewhere)")
     ap.add_argument("--trace", action="store_true",
                     help="continuous-batching driver: Poisson request "
                          "arrivals, mixed prompt lengths, per-request "
@@ -80,6 +80,9 @@ def main(argv=None):
 
     import jax
     import jax.numpy as jnp
+
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     from repro.configs.base import get_config, get_smoke_config
     from repro.core.policy import DENSE, paper_policy

@@ -6,16 +6,18 @@ Dispatch order per call (all static except the per-layer skip flag):
                    *smoothed* activations, paper §Outstanding-sparse)
   2. prunable?    (policy says this module is pruned in this phase)
   3. mode:        per-token N:M mask (paper-faithful) or tile-consensus
-                   compacted matmul (TPU-native, DESIGN.md §2)
+                   compacted matmul (TPU-native)
   4. backend:     ``policy.use_pallas_kernels`` lowers the pruned matmul /
                    Outstanding-sparse chain to one fused pallas_call
                    (``repro.kernels.ops``); the jnp forms below remain the
-                   bit-exact oracle and the ``layer_flag`` fallback
+                   bit-exact oracle
 
 ``layer_flag`` supports ``lax.scan``-stacked layers: the per-layer q/gate
-skip list becomes a boolean vector scanned alongside the weights, selecting
-pruned vs dense input with a ``jnp.where`` (element-wise; leaves matmul
-FLOPs untouched in per-token mode).
+skip list becomes a boolean vector scanned alongside the weights.  With
+kernels on, a flagged projection is a ``lax.cond`` between the pruned
+kernel and the dense projection, so only the chosen branch runs; the jnp
+oracle selects pruned vs dense *input* with a ``jnp.where`` (element-wise;
+leaves matmul FLOPs untouched in per-token mode).
 """
 from __future__ import annotations
 
@@ -63,12 +65,11 @@ def _quantized(x: jax.Array, p: Dict[str, jax.Array], prune: bool,
     copies in HBM) — for BOTH phases: the decode-phase call sets
     ``prune=False`` statically, which skips the N:M selection in-kernel and
     runs the plain smoothed W8A8 GEMM, and the bias-add rides the dequant
-    epilogue.  ``layer_flag`` models keep the jnp mask-select form — the
-    flag picks pruned vs dense *input*, which the fused GEMM cannot
-    express without computing both.
+    epilogue.  (Flagged layers reach here with ``layer_flag=None``: the
+    kernel path resolves the flag with ``lax.cond`` in ``sparse_linear``.)
     """
     per_token = bool(p.get("per_token", False))
-    if layer_flag is None and policy.use_pallas_kernels:
+    if policy.use_pallas_kernels:
         from repro.kernels import ops
 
         y = ops.osparse_matmul(
@@ -114,29 +115,36 @@ def sparse_linear(
       layer_flag: traced bool (scan-stacked models) — True ⇒ prune here.
     """
     prune = policy.active(phase) and policy.should_prune(module, layer_idx)
+    if prune and layer_flag is not None and policy.use_pallas_kernels:
+        # scan-stacked skip list with kernels on: the flag picks the pruned
+        # kernel or the dense projection at run time — both branches are
+        # compiled, only the chosen one runs
+        return jax.lax.cond(layer_flag,
+                            lambda x_: _project(x_, p, True, policy),
+                            lambda x_: _project(x_, p, False, policy), x)
+    return _project(x, p, prune, policy, layer_flag if prune else None)
 
+
+def _project(x: jax.Array, p: Dict[str, jax.Array], prune: bool,
+             policy: SparsityPolicy, layer_flag=None) -> jax.Array:
     if "wq" in p:  # Outstanding-sparse / plain W8A8
-        return _quantized(x, p, prune, policy, layer_flag if prune else None)
+        return _quantized(x, p, prune, policy, layer_flag)
 
     if not prune:
         return dense_linear(x, p)
 
     scale = p.get(SCALE_KEY)
-    use_fused = policy.use_pallas_kernels and layer_flag is None
-    if policy.tile_consensus:
-        pol = policy if use_fused else policy.with_(use_pallas_kernels=False)
-        y = pruner.sparse_matmul(x, p["w"], scale, pol, bias=p.get("b"))
-        if layer_flag is not None:
-            # compacted inputs can't be element-wise selected against the
-            # dense ones, so flagged layers pick between the two outputs
-            y = jnp.where(layer_flag, y, dense_linear(x, p))
-    elif use_fused:
-        # fused prune+GEMM path (one pallas_call under the policy flag,
-        # bias-add folded into the kernel epilogue)
+    if policy.tile_consensus or policy.use_pallas_kernels:
+        # with kernels on: one fused pallas_call, bias-add folded into the
+        # kernel epilogue
         y = pruner.sparse_matmul(x, p["w"], scale, policy, bias=p.get("b"))
+        if layer_flag is not None:
+            # jnp tile-consensus: compacted inputs can't be element-wise
+            # selected against the dense ones, so pick between the outputs
+            y = jnp.where(layer_flag, y, dense_linear(x, p))
     else:
-        # mask-select form: scan-stacked models pick pruned vs dense input
-        # with a traced per-layer flag, so the mask must be materialized
+        # jnp oracle, mask-select form: scan-stacked models pick pruned vs
+        # dense input with the traced per-layer flag
         xp = pruner.prune_input(x, scale, policy)
         if layer_flag is not None:
             xp = jnp.where(layer_flag, xp, x)

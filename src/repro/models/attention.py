@@ -206,7 +206,6 @@ def attention(
             and kv_positions is None and from_zero and T % bqk == 0
             and (window is None or causal)):
         from repro.kernels.flash_attention import flash_attention_pallas
-        from repro.kernels.ops import default_interpret
 
         # KV stays in grouped (B, Hkv, S, hd) layout — the kernel's index
         # map resolves each query head to its KV head, so GQA is never
@@ -217,8 +216,7 @@ def attention(
         o = flash_attention_pallas(qh, kh, vh, causal=causal,
                                    window=0 if window is None
                                    else min(window, S),
-                                   block_q=bqk, block_k=bqk,
-                                   interpret=default_interpret())
+                                   block_q=bqk, block_k=bqk)
         return o.transpose(0, 2, 1, 3).astype(q.dtype)
 
     qg = (q * hd**-0.5).reshape(B, T, Hkv, G, hd)
@@ -243,27 +241,28 @@ def attention(
 def gather_kv_blocks(pool: jax.Array, block_table: jax.Array) -> jax.Array:
     """Contiguous logical KV view gathered from a pooled cache.
 
-    ``pool`` is ``(num_blocks, block_size, Hkv, hd)`` shared by every slot;
-    ``block_table`` is ``(B, max_blocks)`` int32 mapping each row's logical
-    block index to its physical block (``-1`` = unallocated).  Returns
-    ``(B, max_blocks * block_size, Hkv, hd)``.  Unallocated entries clip to
-    block 0 for the gather and their rows are then **zeroed**: those
+    ``pool`` is head-major ``(num_blocks, Hkv, block_size, hd)`` shared by
+    every slot; ``block_table`` is ``(B, max_blocks)`` int32 mapping each
+    row's logical block index to its physical block (``-1`` =
+    unallocated).  Returns ``(B, max_blocks * block_size, Hkv, hd)``.
+    Unallocated entries clip to block 0 for the gather and their rows are
+    then **zeroed**: those
     logical positions are ≥ the row's ``pos`` and callers fence them with
     ``kv_len``, but the softmax fence multiplies by probability 0 — which
     is only a fence for *finite* garbage (0·NaN = NaN), so whatever block 0
     happens to hold must never reach the contraction
     (``tests/test_paged_kv.py`` poisons it to pin this).
     """
-    nb, bs = pool.shape[:2]
+    nb, hkv, bs, hd = pool.shape
     idx = jnp.clip(block_table, 0, nb - 1)
-    g = jnp.take(pool, idx, axis=0)            # (B, max_blocks, bs, Hkv, hd)
+    g = jnp.take(pool, idx, axis=0)            # (B, max_blocks, Hkv, bs, hd)
     g = jnp.where((block_table >= 0)[:, :, None, None, None], g, 0)
     b, mb = block_table.shape
-    return g.reshape(b, mb * bs, *pool.shape[2:])
+    return g.transpose(0, 1, 3, 2, 4).reshape(b, mb * bs, hkv, hd)
 
 
 def paged_kv_update(
-    k_pool: jax.Array,             # (num_blocks, block_size, Hkv, hd)
+    k_pool: jax.Array,             # (num_blocks, Hkv, block_size, hd)
     v_pool: jax.Array,
     k_new: jax.Array,              # (B, T, Hkv, hd) — decode: T == 1
     v_new: jax.Array,
@@ -279,7 +278,7 @@ def paged_kv_update(
     Same dispatch ladder as :func:`paged_attention`: ``use_kernel`` routes
     to :func:`repro.kernels.paged_attention.paged_kv_scatter_pallas`
     (pools aliased in-place, nothing pool-shaped touched outside the
-    ``pallas_call``); the jnp flat-index scatter below is the bit-exact
+    ``pallas_call``); the jnp scatter below is the bit-exact
     oracle and the fallback.  Rows landing on an unallocated (-1) or
     out-of-range block are dropped — the same fence either way.
 
@@ -287,7 +286,7 @@ def paged_kv_update(
     and slot-batched decode (``T == 1``, vector ``pos``) are the same op.
     """
     b, t = k_new.shape[:2]
-    nb, bs = k_pool.shape[:2]
+    nb, bs = k_pool.shape[0], k_pool.shape[2]
     mb = block_table.shape[1]
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     cl = (jnp.full((b,), t, jnp.int32) if chunk_len is None else
@@ -303,14 +302,11 @@ def paged_kv_update(
             raise KernelFault(
                 "injected paged KV scatter kernel compile failure")
         if kind != "fallback":
-            from repro.kernels.ops import default_interpret
             from repro.kernels.paged_attention import paged_kv_scatter_pallas
-
-            interp = default_interpret() if interpret is None else interpret
 
             def _scatter(kn_, vn_, kp_, vp_, bt_, pos_, cl_):
                 return paged_kv_scatter_pallas(kn_, vn_, kp_, vp_, bt_,
-                                               pos_, cl_, interpret=interp)
+                                               pos_, cl_, interpret=interpret)
 
             # tensor parallelism (distributed/tp.py): under an active TP
             # scope the scatter shards over KV heads; the pools come back
@@ -323,25 +319,25 @@ def paged_kv_update(
                 return out
             return paged_kv_scatter_pallas(k_new, v_new, k_pool, v_pool,
                                            block_table, posv, cl,
-                                           interpret=interp)
-    # jnp oracle: flat-index scatter over the (nb*bs, ...) pool view
+                                           interpret=interpret)
+    # jnp oracle: scatter each row to (physical block, in-block row)
     i = jnp.arange(t)
     wpos = posv[:, None] + i[None, :]                       # (B, T) abs pos
     blk = block_table[jnp.arange(b)[:, None],
                       jnp.clip(wpos // bs, 0, mb - 1)]
-    flat = jnp.where((i[None, :] < cl[:, None]) & (blk >= 0)
-                     & (wpos // bs < mb),
-                     blk * bs + wpos % bs, nb * bs)         # OOB → dropped
-    fk = k_pool.reshape(nb * bs, *k_pool.shape[2:]).at[flat.reshape(-1)].set(
+    blk = jnp.where((i[None, :] < cl[:, None]) & (blk >= 0)
+                    & (wpos // bs < mb), blk, nb).reshape(-1)  # OOB → dropped
+    off = (wpos % bs).reshape(-1)
+    fk = k_pool.at[blk, :, off].set(
         k_new.reshape(b * t, *k_new.shape[2:]), mode="drop")
-    fv = v_pool.reshape(nb * bs, *v_pool.shape[2:]).at[flat.reshape(-1)].set(
+    fv = v_pool.at[blk, :, off].set(
         v_new.reshape(b * t, *v_new.shape[2:]), mode="drop")
-    return fk.reshape(k_pool.shape), fv.reshape(v_pool.shape)
+    return fk, fv
 
 
 def paged_attention(
     q: jax.Array,                  # (B, T, Hq, hd)
-    k_pool: jax.Array,             # (num_blocks, block_size, Hkv, hd)
+    k_pool: jax.Array,             # (num_blocks, Hkv, block_size, hd)
     v_pool: jax.Array,
     block_table: jax.Array,        # (B, max_blocks) int32, -1 = unallocated
     *,
@@ -362,8 +358,8 @@ def paged_attention(
          routes the call onto :func:`repro.kernels.paged_attention
          .paged_attention_pallas`, which walks the block table in-kernel
          and never materializes the gathered logical view;
-      2. ``REPRO_PALLAS_INTERPRET`` — ``1`` (CPU container default) runs
-         the kernel interpreted, ``0`` compiles it to Mosaic on a TPU;
+      2. the platform (``kernels.platform``) — Mosaic on a TPU, the
+         Pallas interpreter elsewhere;
       3. the jnp gather-then-attend path below stays the bit-exact oracle
          and the fallback for shapes the kernel does not cover (sliding
          windows over paged pools, non-tile-divisible query counts).
@@ -389,9 +385,6 @@ def paged_attention(
             raise KernelFault(
                 "injected paged-attention kernel compile failure")
         if kind != "fallback":
-            from repro.kernels.ops import default_interpret
-
-            interp = default_interpret() if interpret is None else interpret
             qo = jnp.broadcast_to(
                 jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
             kvl = jnp.broadcast_to(
@@ -401,7 +394,7 @@ def paged_attention(
                 return paged_attention_pallas(q_, kp_, vp_, bt_, qo_, kvl_,
                                               causal=causal,
                                               block_q=min(128, T),
-                                              interpret=interp)
+                                              interpret=interpret)
 
             # tensor parallelism (distributed/tp.py): under an active TP
             # scope the kernel shards over KV heads (heads are independent
@@ -414,7 +407,7 @@ def paged_attention(
             return paged_attention_pallas(q, k_pool, v_pool, block_table,
                                           qo, kvl, causal=causal,
                                           block_q=min(128, T),
-                                          interpret=interp)
+                                          interpret=interpret)
     k = gather_kv_blocks(k_pool, block_table)
     v = gather_kv_blocks(v_pool, block_table)
     return attention(q, k, v, causal=causal, window=window,

@@ -175,7 +175,6 @@ def _moe_shard_map(
     tensor core would see the operand.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     dp = data_axes_tuple(mesh)
     wg, wu, wd = (p["experts"]["gate_proj"]["w"], p["experts"]["up_proj"]["w"],
@@ -229,7 +228,7 @@ def _moe_shard_map(
         return y.reshape(b, t, d).astype(xb.dtype)
 
     dp_entry = dp if len(dp) > 1 else dp[0]
-    y = shard_map(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -240,7 +239,7 @@ def _moe_shard_map(
             P(None, "model", None),           # down (E, F/model, D)
         ),
         out_specs=P(dp_entry, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, router, wg, wu, wd)
 
     if "shared" in p:

@@ -256,15 +256,16 @@ def _attn_block_apply(
         o = attention(q, k, v, causal=True, window=window, q_offset=0,
                       chunk=cfg.attn_chunk, impl=cfg.attn_impl)
     elif block_table is not None:
-        # paged cache: K/V live in a pooled (num_blocks, block_size, Hkv,
-        # hd) array shared by every slot; logical row p of a slot maps to
-        # physical row (table[p // bs], p % bs).  Writes scatter through
-        # the table (unallocated / pad rows drop) and reads fence stale or
-        # unallocated positions with kv_len; both dispatch through the
+        # paged cache: K/V live in a head-major pooled (num_blocks, Hkv,
+        # block_size, hd) array shared by every slot; logical row p of a
+        # slot maps to physical block table[p // bs], in-block row p % bs.
+        # Writes scatter through the table (unallocated / pad rows drop)
+        # and reads fence stale or unallocated positions with kv_len; both
+        # dispatch through the
         # kernel ladder in models/attention — with kernels on, neither
         # direction touches a pool-shaped array outside a pallas_call.
         assert window is None, "paged KV does not cover sliding-window rings"
-        bs_ = cache["k"].shape[1]
+        bs_ = cache["k"].shape[2]
         mb = block_table.shape[1]
         # same policy flag that routes projections onto the fused kernels
         # sends the KV scatter AND the attention through the in-kernel

@@ -15,9 +15,10 @@ parallelism layout: ``dp`` replicas (host-level — each an independent
 Scheduler+Executor with its own slot/block pool, load-balanced by the
 :class:`~repro.serve.router.Router`) by ``tp`` tensor-parallel shards per
 replica (device-level — column-parallel projections and head-sharded
-paged attention, see ``distributed/tp.py``).  ``dp*tp > 1`` builds a
-``(data, model)`` mesh via ``launch.mesh.make_serving_mesh``, which
-validates the device count up front.
+paged attention, see ``distributed/tp.py``).  ``tp > 1``, or ``dp > 1``
+on a backend with a device per replica, builds a ``(data, model)`` mesh
+via ``launch.mesh.make_serving_mesh``: replica *i* holds its params and
+cache on mesh row *i*.
 
 The legacy entry points survive as thin adapters over this stack:
 ``ContinuousServingEngine`` is exactly a dp=1 router replica and
@@ -37,6 +38,11 @@ from repro.serve.metrics import MetricsSnapshot
 from repro.serve.router import Router
 
 __all__ = ["EngineConfig", "Engine"]
+
+
+def _device_count() -> int:
+    import jax
+    return len(jax.devices())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,11 +72,14 @@ class Engine:
         """Build the full serving stack for ``cfg``'s layout.
 
         ``mesh`` overrides the auto-built one (useful in tests that fake
-        host devices); otherwise ``tp > 1`` builds a ``(dp, tp)`` mesh —
-        and raises a clear ValueError when the backend lacks the devices.
-        ``tp == 1`` never touches jax device state (pure host dp).
+        host devices); otherwise a ``(dp, tp)`` mesh is built when
+        ``tp > 1`` — raising a clear ValueError when the backend lacks the
+        devices — or when ``dp > 1`` and the backend has a device per
+        replica, so each replica's params and cache sit on its own chip.
+        With fewer devices than ``dp`` (and ``tp == 1``) the replicas
+        share the default device.
         """
-        if mesh is None and cfg.tp > 1:
+        if mesh is None and (cfg.tp > 1 or 1 < cfg.dp <= _device_count()):
             from repro.launch.mesh import make_serving_mesh
             mesh = make_serving_mesh(cfg.dp, cfg.tp)
         router = Router(model, policy, cfg.serving, dp=cfg.dp, mesh=mesh,

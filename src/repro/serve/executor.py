@@ -21,6 +21,7 @@ bit-identical results — see ``distributed/tp.py``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -77,8 +78,10 @@ class Executor:
     May mutate: ``self.cache``, its own dispatch/degradation counters,
     ``trace_counts``.  May NOT touch: requests, slots bookkeeping, the
     block pool (scheduler territory).  ``mesh`` (a 1-axis TP mesh, see
-    ``distributed/tp.replica_meshes``) shards the kernels; ``mesh=None``
-    is the single-device executor."""
+    ``distributed/tp.replica_meshes``) holds this executor's params and
+    cache (replicated) and shards the kernels over its devices — a
+    one-device mesh pins a dp replica to its own chip; ``mesh=None`` is
+    the single-device executor on the default device."""
 
     def __init__(self, model, policy: SparsityPolicy, cfg,
                  mesh=None, tp_axis: str = "model"):
@@ -126,7 +129,16 @@ class Executor:
                 f"{cfg.chunk_size} (see kernels.paged_attention"
                 ".paged_kernel_covers); use a power-of-two chunk_size or "
                 "drop use_pallas_kernels")
+        # a TP mesh returns every output replicated over it — the layout
+        # the cache and params go in with, so a step never retraces on a
+        # changed input sharding
+        jit = jax.jit
+        if mesh is not None and mesh.size > 1:
+            from jax.sharding import NamedSharding, PartitionSpec
+            jit = functools.partial(jax.jit, out_shardings=NamedSharding(
+                mesh, PartitionSpec()))
         self.cache = None               # built lazily per params
+        self._placed = None             # (params, params on self.mesh)
         self.trace_counts: Dict[str, int] = {}
         self.dispatches = 0       # compiled-program launches (incl. oracle)
         self.degraded_iterations = 0  # iterations re-run on the jnp oracle
@@ -173,7 +185,7 @@ class Executor:
                 return jnp.where(active, nxt, tokens), new_cache, ok
             return decode_fn
 
-        self._prefill_jit = jax.jit(make_prefill_fn(policy, "prefill"))
+        self._prefill_jit = jit(make_prefill_fn(policy, "prefill"))
         # preemption replay re-ingests tokens the request already EMITTED;
         # their KV was originally written by the dense decode step, so the
         # replay must also run dense or sparse-prefill outputs would drift
@@ -181,20 +193,20 @@ class Executor:
         # boundary (see Scheduler.next_chunk); this program only ever
         # traces (and the "prefill_replay" key only appears) if a
         # preemption happens under a non-dense policy.
-        self._prefill_replay_jit = jax.jit(
+        self._prefill_replay_jit = jit(
             make_prefill_fn(dense, "prefill_replay"))
-        self._decode_jit = jax.jit(make_decode_fn(dense, "decode"))
+        self._decode_jit = jit(make_decode_fn(dense, "decode"))
         # graceful-degradation ladder: bit-exact jnp oracle twins of every
         # phase program (kernel dispatch forced off).  jax.jit is lazy, so
         # none of these trace — and no "*_oracle" trace-count key appears —
         # unless an iteration actually degrades.
         opolicy = policy.with_(use_pallas_kernels=False) \
             if policy.use_pallas_kernels else policy
-        self._prefill_oracle_jit = jax.jit(
+        self._prefill_oracle_jit = jit(
             make_prefill_fn(opolicy, "prefill_oracle"))
-        self._prefill_replay_oracle_jit = jax.jit(
+        self._prefill_replay_oracle_jit = jit(
             make_prefill_fn(DENSE, "prefill_replay_oracle"))
-        self._decode_oracle_jit = jax.jit(
+        self._decode_oracle_jit = jit(
             make_decode_fn(DENSE, "decode_oracle"))
 
         # ---- one-dispatch iterations: a single hybrid step program per
@@ -259,8 +271,8 @@ class Executor:
             self._step_raw[key] = make_step_fn(pf, dense, name, hp, hd)
             self._step_oracle_raw[key] = make_step_fn(
                 opf, DENSE, name + "_oracle", hp, hd)
-            self._step_jits[key] = jax.jit(self._step_raw[key])
-            self._step_oracle_jits[key] = jax.jit(self._step_oracle_raw[key])
+            self._step_jits[key] = jit(self._step_raw[key])
+            self._step_oracle_jits[key] = jit(self._step_oracle_raw[key])
 
     # ------------------------------------------------------------- sampling
     def _sample(self, logits, key):
@@ -273,16 +285,34 @@ class Executor:
         return int(self._sample(logits, key))
 
     # ------------------------------------------------------------ the cache
+    def _on_mesh(self, tree):
+        """``tree`` replicated over this executor's mesh — the one device
+        of a dp replica, or its TP submesh.  Without a mesh, as given."""
+        if self.mesh is None:
+            return tree
+        if self.mesh.size == 1:   # the sharding a one-device jit returns
+            return jax.device_put(tree, self.mesh.devices.flat[0])
+        from jax.sharding import NamedSharding, PartitionSpec
+        return jax.device_put(tree, NamedSharding(self.mesh, PartitionSpec()))
+
+    def place(self, params):
+        """``params`` on this executor's devices, copied once per params
+        object (a dp replica keeps its own copy on its own device)."""
+        if self._placed is None or self._placed[0] is not params:
+            self._placed = (params, self._on_mesh(params))
+        return self._placed[1]
+
     def init_cache(self, num_blocks: Optional[int] = None) -> None:
         if self.cache is not None:
             return
         if self.paged:
-            self.cache = init_paged_cache(
+            cache = init_paged_cache(
                 self.model, self.cfg.num_slots, self.cfg.max_seq,
                 self.cfg.block_size, num_blocks, self._spec)
         else:
-            self.cache = slot_ops.init_slot_cache(
+            cache = slot_ops.init_slot_cache(
                 self.model, self.cfg.num_slots, self.cfg.max_seq)
+        self.cache = self._on_mesh(cache)
 
     def drop_cache(self) -> None:
         """Forget device state (restore path: the crash that motivated a
@@ -297,7 +327,7 @@ class Executor:
             self.cache = slot_ops.reset_slot(self.cache, slot, self._spec,
                                              pos=pos)
         if plan.table is not None:
-            self.cache["block_table"] = jnp.asarray(plan.table)
+            self.cache["block_table"] = self._on_mesh(jnp.asarray(plan.table))
 
     # ----------------------------------------------------------- dispatch
     def _tp_scope(self):
@@ -347,7 +377,7 @@ class Executor:
             toks = jnp.zeros((self.cfg.num_slots,), jnp.int32)
             act = jnp.zeros((self.cfg.num_slots,), bool)
         bucket = plan.bucket
-        args = (params, self.cache, slot, ptoks, pclen, ex, toks, act,
+        args = (self.place(params), self.cache, slot, ptoks, pclen, ex, toks, act,
                 pkey, dkey)
         ptok, nxt, new_cache, _ = self._run_ladder(
             self._step_jits[bucket], self._step_oracle_jits[bucket],
@@ -367,7 +397,8 @@ class Executor:
         fn = self._prefill_replay_jit if pw.replay else self._prefill_jit
         ofn = (self._prefill_replay_oracle_jit if pw.replay
                else self._prefill_oracle_jit)
-        args = (params, self.cache, jnp.asarray(pw.req.slot, jnp.int32),
+        args = (self.place(params), self.cache,
+                jnp.asarray(pw.req.slot, jnp.int32),
                 jnp.asarray(pw.tokens), jnp.asarray(pw.chunk_len, jnp.int32),
                 extras)
         logits, new_cache, _ = self._run_ladder(fn, ofn, args, fault)
@@ -378,7 +409,7 @@ class Executor:
         """Legacy two-program split, phase 2: one slot-batched decode
         step; returns the (num_slots,) next-token array."""
         dw = plan.decode
-        args = (params, self.cache, jnp.asarray(dw.toks),
+        args = (self.place(params), self.cache, jnp.asarray(dw.toks),
                 jnp.asarray(dw.active), key)
         nxt, new_cache, _ = self._run_ladder(
             self._decode_jit, self._decode_oracle_jit, args, fault)

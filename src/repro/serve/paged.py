@@ -6,8 +6,10 @@ prompts — memory, not compute, then caps concurrency.  Paged allocation
 replaces the slab with a **global pool** of fixed-size KV blocks shared by
 every slot:
 
-  * each attention layer's cache leaf becomes a pooled
-    ``(num_blocks, block_size, n_kv_heads, head_dim)`` array;
+  * each attention layer's cache leaf becomes a head-major pooled
+    ``(num_blocks, n_kv_heads, block_size, head_dim)`` array (one KV head
+    of one block is a ``(block_size, head_dim)`` tile, the shape the paged
+    Pallas kernels move);
   * each slot holds a **block table** — a ``(max_blocks_per_slot,)`` int32
     row mapping logical block index (``position // block_size``) to a
     physical block id, ``-1`` = unallocated;
@@ -455,8 +457,9 @@ def init_paged_cache(model, num_slots: int, max_seq: int, block_size: int,
     """Slot cache with paged attention leaves.
 
     ``spec`` is the bool pytree from ``model.paged_kv_spec()``: leaves
-    marked True swap their ``(..., num_slots, max_seq, ...)`` axes for
-    pooled ``(..., device_pool_rows(num_blocks), block_size, ...)`` —
+    marked True swap their ``(..., num_slots, max_seq, Hkv, hd)`` axes for
+    head-major pooled ``(..., device_pool_rows(num_blocks), Hkv,
+    block_size, hd)`` —
     ``num_blocks`` allocatable blocks plus the trailing sentinel row the
     scatter kernel parks invisible grid steps on (never referenced by any
     block table); everything else keeps the slot axis.  Adds the per-slot
@@ -482,9 +485,9 @@ def init_paged_cache(model, num_slots: int, max_seq: int, block_size: int,
 
         def pool_leaf(a, paged, ax=ax):
             if paged:
+                hkv, hd = a.shape[ax + 2:]
                 shape = (a.shape[:ax]
-                         + (device_pool_rows(num_blocks), block_size)
-                         + a.shape[ax + 2:])
+                         + (device_pool_rows(num_blocks), hkv, block_size, hd))
                 return jnp.zeros(shape, a.dtype)
             return jnp.zeros(a.shape, a.dtype)
 

@@ -7,10 +7,11 @@ The :class:`Router` owns ``dp`` independent
 a full Scheduler+Executor pair with its own slot set, block pool, and
 prefix index — and load-balances admissions across them.  dp replication
 is **host-level**: no collective spans the data axis in serving (replicas
-never exchange activations), so dp replicas work on a single device, and
-a ``(dp, tp)`` mesh (``launch.mesh.make_serving_mesh``) additionally
-gives each replica its own TP submesh
-(:func:`repro.distributed.tp.replica_meshes`) to shard its kernels over.
+never exchange activations).  Without a mesh the replicas share the
+default device; a ``(dp, tp)`` mesh (``launch.mesh.make_serving_mesh``)
+gives each replica its own row (:func:`repro.distributed.tp
+.replica_meshes`), which holds that replica's params and cache and, for
+``tp > 1``, shards its kernels.
 
 Routing is least-loaded with **prefix affinity**: requests opening with
 the same leading KV block are pinned to the same replica, so the

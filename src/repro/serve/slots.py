@@ -16,10 +16,11 @@ Paged mode (see ``serve/paged.py``) adds two twists, driven by the
 optional ``spec`` argument — a bool pytree mirroring the cache subtrees
 in which True marks a **pooled** attention K/V leaf:
 
-  * pooled leaves have NO slot axis (they are ``(num_blocks, block_size,
-    ...)`` shared by every slot), so slicing passes them through whole and
-    writing takes the updated pool verbatim — the model's block-table
-    scatter already confined the writes to the slot's own blocks;
+  * pooled leaves have NO slot axis (they are ``(num_blocks, Hkv,
+    block_size, hd)`` shared by every slot), so slicing passes them
+    through whole and writing takes the updated pool verbatim — the
+    model's block-table scatter already confined the writes to the
+    slot's own blocks;
   * ``block_table`` rides in the cache as a ``(num_slots, max_blocks)``
     int32 leaf; slicing extracts the slot's row (kept 2-D so prefill and
     batched decode share the model-side gather code).

@@ -6,7 +6,7 @@ which assert the rule fires."""
 import jax
 import jax.numpy as jnp
 
-POOL_SHAPE = (64, 16, 2, 8)          # (num_blocks, block_size, Hkv, hd)
+POOL_SHAPE = (64, 2, 16, 8)          # (num_blocks, Hkv, block_size, hd)
 
 
 def gathering_step(pool, idx):

@@ -318,7 +318,7 @@ def test_grid_eval_sentinel_exemption():
     from repro.serve.paged import device_pool_rows
 
     bs, mb, nb, hkv, hd, t = 8, 8, 16, 2, 16, 16
-    pool = jnp.zeros((device_pool_rows(nb), bs, hkv, hd), jnp.float32)
+    pool = jnp.zeros((device_pool_rows(nb), hkv, bs, hd), jnp.float32)
     tab = np.full((2, mb), -1, np.int32)
     tab[0, :2] = [1, 2]
     tab[1, 1:4] = [5, 6, 7]

@@ -252,11 +252,10 @@ def test_nonfinite_output_degrades_to_oracle(tiny, site, calls):
 
 @pytest.mark.parametrize("site", ["kernel.projection",
                                   "kernel.paged_attention"])
-def test_kernel_compile_failure_degrades_to_oracle(tiny, site, monkeypatch):
+def test_kernel_compile_failure_degrades_to_oracle(tiny, site):
     """A simulated Mosaic lowering failure aborts the trace; the engine
     re-runs the iteration on the kernels-off oracle jit and the request
     stream completes token-identically (kernel ≡ oracle math)."""
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     cfg, model, params = tiny
     if site == "kernel.projection":
         policy = paper_policy(8, 16, cfg.qgate_skip_layers,
@@ -289,11 +288,10 @@ def test_kernel_compile_failure_degrades_to_oracle(tiny, site, monkeypatch):
         _drained(eng)
 
 
-def test_kernel_fallback_is_silent(tiny, monkeypatch):
+def test_kernel_fallback_is_silent(tiny):
     """The "fallback" kind routes a dispatch onto the jnp oracle branch
     WITHOUT an exception: same tokens, no degradation recorded (it is the
     ladder's ordinary uncovered-shape path, not a failure)."""
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     cfg, model, params = tiny
     policy = DENSE.with_(use_pallas_kernels=True)
     prompts = _prompts(cfg, [10, 15], seed0=450)
@@ -465,14 +463,12 @@ def test_watchdog_breaks_admission_livelock(tiny):
 
 @pytest.mark.parametrize("attn_kernel", [False, True],
                          ids=["gather-oracle", "pallas-kernel"])
-def test_preemption_storm_cancel_interleaving(tiny, attn_kernel,
-                                              monkeypatch):
+def test_preemption_storm_cancel_interleaving(tiny, attn_kernel):
     """Undersized pool → sustained preemption churn, plus a cancel landing
     mid-prefill: survivors stay token-identical on both the jnp gather
     oracle and the Pallas block-walk kernel, and the cancelled request's
     unwind never leaves a writable shared block (per-iteration audit +
     post-drain reclaim check)."""
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     cfg, model, params = tiny
     policy = DENSE.with_(use_pallas_kernels=True) if attn_kernel else DENSE
     lens, arrivals, max_new = [16, 18, 14, 15], [0, 1, 2, 3], 8

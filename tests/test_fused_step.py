@@ -182,10 +182,8 @@ def test_step_program_pool_ops_stay_in_kernel(tiny):
     mb = max_blocks_per_slot(MAX_SEQ, bs)
     nb = slots * mb
     rows = device_pool_rows(nb)
-    # the pooled-KV leaves (+1 sentinel row), 4D and as the flat row view
-    # the host-side scatter used to write through
-    pool_shapes = {(rows, bs, cfg.n_kv_heads, cfg.head_dim),
-                   (rows * bs, cfg.n_kv_heads, cfg.head_dim)}
+    # the head-major pooled-KV leaves (+1 sentinel row)
+    pool_shapes = {(rows, cfg.n_kv_heads, bs, cfg.head_dim)}
 
     def jaxpr_for(kernels):
         pol = DENSE.with_(use_pallas_kernels=kernels)

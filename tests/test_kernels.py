@@ -132,8 +132,8 @@ def _paged_inputs(rng, nb=10, bs=8, mb=6, B=3, Hkv=2, hd=16,
     """Pool + permuted per-row block tables with -1 tails (physical block 0
     left unreferenced so the fencing tests can poison it)."""
     k1, k2 = jax.random.split(rng)
-    kp = jax.random.normal(k1, (nb, bs, Hkv, hd), dtype=dtype)
-    vp = jax.random.normal(k2, (nb, bs, Hkv, hd), dtype=dtype)
+    kp = jax.random.normal(k1, (nb, Hkv, bs, hd), dtype=dtype)
+    vp = jax.random.normal(k2, (nb, Hkv, bs, hd), dtype=dtype)
     tab = np.full((B, mb), -1, np.int32)
     perm = np.random.default_rng(7).permutation(np.arange(1, nb))
     tab[0, :3] = perm[:3]

@@ -126,12 +126,11 @@ def test_block_pool_alloc_validates_before_mutating():
 
 @pytest.mark.parametrize("attn_kernel", [False, True],
                          ids=["gather-oracle", "pallas-kernel"])
-def test_half_pool_token_identical_one_trace(tiny, attn_kernel, monkeypatch):
+def test_half_pool_token_identical_one_trace(tiny, attn_kernel):
     """Acceptance: pool at 50% of num_slots*max_seq, staggered greedy
     outputs token-identical to the one-shot engine, one compile per shape
     bucket — on the jnp gather oracle AND (ISSUE-4) on the Pallas
-    block-table-walk kernel under REPRO_PALLAS_INTERPRET=1."""
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    block-table-walk kernel (interpreted on the CPU)."""
     cfg, model, params = tiny
     policy = DENSE.with_(use_pallas_kernels=True) if attn_kernel else DENSE
     slots, bs = 3, 8
@@ -312,8 +311,8 @@ def test_unallocated_block_fence_survives_poison():
     from repro.models.attention import paged_attention
     rng = np.random.default_rng(3)
     nb, bs, mb, B, Hq, Hkv, hd = 12, 8, 6, 3, 4, 2, 16
-    kp = np.asarray(rng.normal(size=(nb, bs, Hkv, hd)), np.float32)
-    vp = np.asarray(rng.normal(size=(nb, bs, Hkv, hd)), np.float32)
+    kp = np.asarray(rng.normal(size=(nb, Hkv, bs, hd)), np.float32)
+    vp = np.asarray(rng.normal(size=(nb, Hkv, bs, hd)), np.float32)
     # disjoint per-row prefixes over blocks 1..11; block 0 stays free
     tab = np.full((B, mb), -1, np.int32)
     tab[0, :3] = [5, 1, 8]
@@ -379,7 +378,7 @@ def test_paged_hot_path_has_no_full_view_gather(tiny):
     tab[1, :3] = [4, 5, 6]
     cache["block_table"] = jnp.asarray(tab)
     cache["pos"] = jnp.asarray([10, 7], jnp.int32)
-    pool_shape = (device_pool_rows(nb), bs, cfg.n_kv_heads, cfg.head_dim)
+    pool_shape = (device_pool_rows(nb), cfg.n_kv_heads, bs, cfg.head_dim)
     kernel_pol = DENSE.with_(use_pallas_kernels=True)
 
     toks = jnp.zeros((slots, 1), jnp.int32)

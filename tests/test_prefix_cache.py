@@ -135,13 +135,12 @@ def test_pool_evicts_lru_before_reporting_exhaustion():
 @pytest.mark.parametrize("attn_kernel", [False, True],
                          ids=["gather-oracle", "pallas-kernel"])
 def test_shared_system_prompt_skips_prefill_token_identical(
-        tiny, attn_kernel, monkeypatch):
+        tiny, attn_kernel):
     """Acceptance: a shared-system-prompt stream reuses ≥ 1 block per
     following request and skips ≥ 50% of their prompt rows, while greedy
     outputs stay token-identical to BOTH the one-shot engine and the same
     engine with caching off — on the jnp gather oracle AND the Pallas
-    block-walk kernel under REPRO_PALLAS_INTERPRET=1."""
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    block-walk kernel (interpreted on the CPU)."""
     cfg, model, params = tiny
     policy = DENSE.with_(use_pallas_kernels=True) if attn_kernel else DENSE
     sysp = _rand_tokens(cfg, 32, seed=70)
@@ -236,7 +235,7 @@ def test_sparse_policy_does_not_share_across_the_emitted_boundary(tiny):
     # pure-prompt blocks (16 tokens = 4 blocks) shared; emitted-region
     # blocks correctly missed under the dense-row salt
     assert req["cached_tokens"] == 16, req
-    assert res1["outputs"][0] == _oracle(model, params, policy, p1, 6)
+    assert res1["outputs"][0] == _oracle(model, sparams, policy, p1, 6)
 
 
 def test_prefix_cache_auto_disabled_for_recurrent_archs():

@@ -26,6 +26,24 @@ def test_robust_norm_scale_clips_outliers(rng):
     assert ratio_robust < ratio_plain / 100  # outlier influence crushed
 
 
+@pytest.mark.parametrize("shape,dtype", [((64, 48), jnp.bfloat16),
+                                         ((257, 33), jnp.float32),
+                                         ((1000, 7), jnp.float32),
+                                         ((1, 1), jnp.float32)])
+def test_quantile_matches_jnp_quantile(shape, dtype):
+    """The counting-pass quantile gives jnp.quantile's value (bf16-cast
+    weights are full of ties; signed zeros compare equal)."""
+    w = (jax.random.normal(jax.random.PRNGKey(shape[0]), shape) * 0.02)
+    w = w.astype(dtype).astype(jnp.float32).at[:3].set(0.0).at[3:5].set(-0.0)
+    for q in (0.0, 0.005, 0.25, 0.5, 0.995, 1.0):
+        want = jax.jit(lambda x: jnp.quantile(x, q))(w)
+        got = jax.jit(lambda x: scoring.quantile(x, q))(w)
+        assert got == want, (q, got, want)
+    srt = np.sort(np.asarray(w).ravel())
+    for k in (0, w.size // 2, w.size - 1):
+        assert float(scoring.kth_smallest(w, k)) == srt[k]
+
+
 def test_score_activations_naive_vs_scaled(rng):
     x = jax.random.normal(rng, (8, 32))
     s_naive = scoring.score_activations(x, None)

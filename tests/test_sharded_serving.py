@@ -345,8 +345,8 @@ check("w8a8_matmul",
 
 B, Hq, Hkv, D, bs, nb, T = 2, 4, 2, 8, 4, 16, 12
 q = jnp.asarray(rng.standard_normal((B, T, Hq, D)), jnp.float32)
-kp = jnp.asarray(rng.standard_normal((nb, bs, Hkv, D)), jnp.float32)
-vp = jnp.asarray(rng.standard_normal((nb, bs, Hkv, D)), jnp.float32)
+kp = jnp.asarray(rng.standard_normal((nb, Hkv, bs, D)), jnp.float32)
+vp = jnp.asarray(rng.standard_normal((nb, Hkv, bs, D)), jnp.float32)
 bt = jnp.asarray(np.arange(2 * 8).reshape(2, 8), jnp.int32)
 qo = jnp.zeros((B,), jnp.int32)
 kvl = jnp.full((B,), T, jnp.int32)
@@ -374,7 +374,6 @@ def test_tp_kernel_parity_vs_single_device_oracle():
     env = {**os.environ,
            "PYTHONPATH": _SRC,
            "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-           "REPRO_PALLAS_INTERPRET": "1",
            "JAX_PLATFORMS": "cpu"}
     proc = subprocess.run([sys.executable, "-c", _TP_PARITY], env=env,
                           capture_output=True, text=True, timeout=900)

@@ -8,14 +8,14 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ARCH_IDS, get_config, get_smoke_config
 from repro.distributed import sharding as shd
-from repro.launch.mesh import abstract_mesh, make_mesh_auto
+from repro.launch.mesh import make_mesh_auto
 from repro.models import build_model
 
 
 def _mesh(shape=(2, 4), axes=("data", "model")):
     # tests run on 1 device; abstract mesh via make_mesh requires devices —
     # use the AbstractMesh to validate specs without hardware
-    return abstract_mesh(shape, axes)
+    return jax.sharding.AbstractMesh(shape, axes)
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -25,7 +25,7 @@ def test_param_specs_divisible(arch):
     cfg = get_config(arch)
     model = build_model(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     specs = shd.param_specs(params, mesh, cfg.n_experts)
 
     def check(path, leaf, spec):
@@ -42,7 +42,7 @@ def test_param_specs_divisible(arch):
 
 
 def test_known_rules():
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("qwen2_5_32b")
     model = build_model(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -62,7 +62,7 @@ def test_known_rules():
 
 def test_whisper_odd_vocab_replicates():
     """vocab 51865 is not divisible by 16 → embedding must not shard it."""
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("whisper_medium")
     model = build_model(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -74,7 +74,7 @@ def test_whisper_odd_vocab_replicates():
 
 
 def test_batch_and_cache_specs():
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert shd.data_axes(mesh) == ("pod", "data")
     assert tuple(shd.batch_spec(mesh))[0] == ("pod", "data")
 
@@ -92,7 +92,7 @@ def test_smoke_cell_lowers_on_multidevice_mesh():
     """End-to-end pjit lowering of a smoke config on an 8-way mesh shape
     (validates sharding rules agree with GSPMD propagation)."""
     if len(jax.devices()) < 2:
-        mesh = abstract_mesh((2, 4), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     from repro.launch.cells import build_cell
     mesh_c = make_mesh_auto((1, 1), ("data", "model"))
     cell = build_cell("llama31_8b", "train_4k", mesh_c,
