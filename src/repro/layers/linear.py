@@ -127,6 +127,14 @@ def sparse_linear(
 
 def _project(x: jax.Array, p: Dict[str, jax.Array], prune: bool,
              policy: SparsityPolicy, layer_flag=None) -> jax.Array:
+    # device name of the projection: inside a skip-list ``lax.cond`` each
+    # branch carries its own
+    with jax.named_scope("model.amber_proj" if prune else "model.dense_proj"):
+        return _project_ops(x, p, prune, policy, layer_flag)
+
+
+def _project_ops(x: jax.Array, p: Dict[str, jax.Array], prune: bool,
+                 policy: SparsityPolicy, layer_flag=None) -> jax.Array:
     if "wq" in p:  # Outstanding-sparse / plain W8A8
         return _quantized(x, p, prune, policy, layer_flag)
 

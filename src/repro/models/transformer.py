@@ -226,8 +226,26 @@ def _attn_block_apply(
     chunk_len=None,
     block_table=None,
 ) -> Tuple[jax.Array, Optional[Dict]]:
-    b, t, d = h.shape
     fl = flags or {}
+    with jax.named_scope("model.attention"):
+        h, new_cache = _self_attention(cfg, h, p, policy, phase, cache, pos,
+                                       positions, positions_3d, fl,
+                                       chunk_len, block_table)
+    with jax.named_scope("model.mlp"):
+        x2 = common.norm_apply(h, p["ln2"], cfg.norm)
+        if cfg.n_experts:
+            ff = moe(x2, p["moe"], policy, phase, cfg.top_k, cfg.act_fn,
+                     cfg.moe_impl, fl)
+        else:
+            ff = mlp(x2, p["mlp"], policy, phase, cfg.act_fn, None, fl)
+        return h + ff, new_cache
+
+
+def _self_attention(cfg, h, p, policy, phase, cache, pos, positions,
+                    positions_3d, fl, chunk_len, block_table):
+    """Norm, projections, attention and the residual add of one attention
+    block; returns (h, the block's new cache)."""
+    b, t, d = h.shape
     x = common.norm_apply(h, p["ln1"], cfg.norm)
     q = sparse_linear(x, p["q_proj"], "q_proj", policy, phase, None,
                       fl.get("q_proj"))
@@ -274,9 +292,10 @@ def _attn_block_apply(
         use_kernel = bool(policy.use_pallas_kernels)
         if t == 1:  # vector-pos decode: every row writes at its own depth
             posv = pos if jnp.ndim(pos) == 1 else jnp.broadcast_to(pos, (b,))
-            ck, cv = paged_kv_update(cache["k"], cache["v"], k, v,
-                                     block_table, posv,
-                                     use_kernel=use_kernel)
+            with jax.named_scope("model.kv_update"):
+                ck, cv = paged_kv_update(cache["k"], cache["v"], k, v,
+                                         block_table, posv,
+                                         use_kernel=use_kernel)
             o = paged_attention(q, ck, cv, block_table, causal=False,
                                 q_offset=posv,
                                 kv_len=jnp.minimum(posv + 1, mb * bs_),
@@ -293,9 +312,10 @@ def _attn_block_apply(
             assert b == 1, "paged chunked prefill is per-slot (batch 1)"
             cl = (chunk_len if chunk_len is not None
                   else jnp.asarray(t, jnp.int32))
-            ck, cv = paged_kv_update(cache["k"], cache["v"], k, v,
-                                     block_table, pos, cl,
-                                     use_kernel=use_kernel)
+            with jax.named_scope("model.kv_update"):
+                ck, cv = paged_kv_update(cache["k"], cache["v"], k, v,
+                                         block_table, pos, cl,
+                                         use_kernel=use_kernel)
             o = paged_attention(q, ck, cv, block_table, causal=True,
                                 q_offset=pos, kv_len=pos + cl,
                                 chunk=cfg.attn_chunk,
@@ -378,14 +398,7 @@ def _attn_block_apply(
     o = o.reshape(b, t, cfg.q_dim)
     o = sparse_linear(o, p["o_proj"], "o_proj", policy, phase, None,
                       fl.get("o_proj"))
-    h = h + o
-    x2 = common.norm_apply(h, p["ln2"], cfg.norm)
-    if cfg.n_experts:
-        ff = moe(x2, p["moe"], policy, phase, cfg.top_k, cfg.act_fn,
-                 cfg.moe_impl, fl)
-    else:
-        ff = mlp(x2, p["mlp"], policy, phase, cfg.act_fn, None, fl)
-    return h + ff, new_cache
+    return h + o, new_cache
 
 
 def _block_apply(cfg, kind, h, p, policy, phase, cache, pos, positions,
@@ -453,7 +466,8 @@ def _embed_inputs(cfg: ModelConfig, params, batch) -> jax.Array:
     from repro.distributed.sharding import maybe_shard
 
     tokens = batch["tokens"]
-    h = common.embed(tokens, params["embed"])
+    with jax.named_scope("model.embed"):
+        h = common.embed(tokens, params["embed"])
     h = maybe_shard(h, "dp", None, None)
     if cfg.vision_stub and "pixel_embeds" in batch:
         pe = batch["pixel_embeds"].astype(h.dtype)
@@ -571,7 +585,10 @@ def _run_blocks(cfg, params, h, policy, phase, cache, positions, positions_3d,
             idxs = jnp.arange(n_per)
             xs = (params["periods"], period_flags, idxs) \
                 if period_flags is not None else (params["periods"], idxs)
-            (h, cstack), _ = jax.lax.scan(body, (h, cstack), xs)
+            # the loop's own slices of each layer's weights and cache sit
+            # in this scope, the blocks' work in theirs below it
+            with jax.named_scope("model.layers"):
+                (h, cstack), _ = jax.lax.scan(body, (h, cstack), xs)
             new_cache["periods"] = cstack
 
     if tail:
@@ -592,11 +609,12 @@ def _run_blocks(cfg, params, h, policy, phase, cache, positions, positions_3d,
 def _lm_logits(cfg, params, h):
     from repro.distributed.sharding import maybe_shard
 
-    h = common.norm_apply(h, params["final_norm"], cfg.norm)
-    if cfg.tie_embeddings:
-        logits = h @ params["embed"]["w"].T
-    else:
-        logits = h @ params["lm_head"]["w"]
+    with jax.named_scope("model.lm_head"):
+        h = common.norm_apply(h, params["final_norm"], cfg.norm)
+        if cfg.tie_embeddings:
+            logits = h @ params["embed"]["w"].T
+        else:
+            logits = h @ params["lm_head"]["w"]
     # keep the vocab dim model-sharded: (B, T, V) or (B, V)
     if logits.ndim == 3:
         return maybe_shard(logits, "dp", None, "model")

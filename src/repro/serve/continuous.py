@@ -76,6 +76,7 @@ import numpy as np
 
 from repro.core.policy import DENSE, SparsityPolicy
 from repro.serve import faults as fault_mod
+from repro.serve import tracing
 from repro.serve.executor import Executor
 from repro.serve.faults import EngineCrash, FaultInjector
 from repro.serve.metrics import (LifecycleMetrics, MetricsSnapshot,
@@ -336,7 +337,7 @@ class ContinuousServingEngine:
         return float("nan") if kind == "nonfinite" else 0.0
 
     def _step_fused(self, params, extras: Dict[int, Dict], it: int,
-                    t0: float) -> bool:
+                    plan: StepPlan) -> bool:
         """One-dispatch iteration: the scheduler's fused plan runs as a
         SINGLE compiled step program (executor-side bucketing by static
         phase presence).  Returns whether any model work ran.
@@ -347,7 +348,6 @@ class ContinuousServingEngine:
         frozen before dispatch), where the legacy path recomputed the
         roster after prefill.  Greedy token streams are identical; see
         ``ContinuousConfig.fused_step`` for the temperature>0 caveat."""
-        plan = self.sched.plan_step()
         if not plan.has_work:
             return False
         self.exec.apply_effects(plan)
@@ -370,18 +370,18 @@ class ContinuousServingEngine:
         pw = plan.prefill
         ex = extras.get(pw.req.rid, {}) if pw is not None and pw.first else {}
         res = self.exec.step(params, plan, ex, pkey, dkey, fault)
-        if pw is not None:
-            self.sched.commit_chunk(pw.req, pw.chunk_len)
-            if self.sched.seq_complete(pw.req):   # seq ingested: sample
-                self.sched.emit_prefill_token(pw.req, res.prefill_token,
-                                              it, t0)
-        if plan.decode is not None:
-            self.sched.emit_decode_tokens(plan.decode, res.decode_tokens,
-                                          it, t0)
+        with tracing.span(tracing.COMMIT):
+            if pw is not None:
+                self.sched.commit_chunk(pw.req, pw.chunk_len)
+                if self.sched.seq_complete(pw.req):   # seq ingested: sample
+                    self.sched.emit_prefill_token(pw.req, res.prefill_token,
+                                                  it)
+            if plan.decode is not None:
+                self.sched.emit_decode_tokens(plan.decode,
+                                              res.decode_tokens, it)
         return True
 
-    def _step_prefill(self, params, extras: Dict[int, Dict], it: int,
-                      t0: float) -> bool:
+    def _step_prefill(self, params, extras: Dict[int, Dict], it: int) -> bool:
         """Legacy two-program split, phase 1: one chunk for the oldest
         prefilling request.  Returns whether the PREFILL roster was
         non-empty (the historical progress signal — a fully-ingested
@@ -390,7 +390,8 @@ class ContinuousServingEngine:
         if not any(r.state == PREFILL for r in self.sched.requests):
             return False
         self._key, sub = jax.random.split(self._key)
-        plan = self.sched.plan_prefill()
+        with tracing.span(tracing.SCHEDULE):
+            plan = self.sched.plan_prefill()
         pw = plan.prefill
         if pw is None:     # fully ingested, parked — nothing to run
             return True
@@ -401,21 +402,24 @@ class ContinuousServingEngine:
         self.sched.commit_chunk(pw.req, pw.chunk_len)
         if self.sched.seq_complete(pw.req):   # seq ingested: sample
             tok = self.exec.sample_token(logits, sub)
-            self.sched.emit_prefill_token(pw.req, tok, it, t0)
+            with tracing.span(tracing.COMMIT):
+                self.sched.emit_prefill_token(pw.req, tok, it)
         return True
 
-    def _step_decode(self, params, it: int, t0: float) -> bool:
+    def _step_decode(self, params, it: int) -> bool:
         """Legacy two-program split, phase 2: one slot-batched decode step
         (roster computed AFTER prefill, so a request finishing prefill
         this iteration decodes the same iteration)."""
-        plan = self.sched.plan_decode()
+        with tracing.span(tracing.SCHEDULE):
+            plan = self.sched.plan_decode()
         if plan.decode is None:
             return False
         self._key, sub = jax.random.split(self._key)
         self.exec.apply_effects(plan)
         fault = jnp.float32(self._crash_fire("decode", it))
         nxt = self.exec.decode(params, plan, sub, fault)
-        self.sched.emit_decode_tokens(plan.decode, nxt, it, t0)
+        with tracing.span(tracing.COMMIT):
+            self.sched.emit_decode_tokens(plan.decode, nxt, it)
         return True
 
     # ------------------------------------------------------------ main loop
@@ -425,8 +429,13 @@ class ContinuousServingEngine:
         ``extras`` maps request id → modality arrays sent with the first
         prefill chunk (``frame_embeds`` for encdec, ``pixel_embeds`` for
         VLM stubs).  Returns per-request outputs and aggregate metrics.
+        Under a ``jax.profiler`` session the loop records the spans of
+        :mod:`repro.serve.tracing`.
         """
-        extras = extras or {}
+        with tracing.span(tracing.RUN):
+            return self._run(params, extras or {})
+
+    def _run(self, params, extras: Dict[int, Dict]) -> Dict:
         sched, ex = self.sched, self.exec
         ex.init_cache(sched.pool.num_blocks if self.paged else None)
         sched.mark_extras(extras)
@@ -455,101 +464,114 @@ class ContinuousServingEngine:
                 if self.faults is not None:
                     self.faults.tick(it)
                 if self.iteration_hook is not None:
-                    self.iteration_hook(self, it)
-                if (self.cfg.snapshot_every
-                        and it % self.cfg.snapshot_every == 0):
-                    # iteration boundary = consistent state: a crash later
-                    # this iteration rewinds here via restore()
-                    self.last_snapshot = self.snapshot()
-                sched.stamp_arrivals(it, time.perf_counter())
-                reaped = sched.reap(it)
-                admitted = sched.admit(it)
-                if self.fused_step:
-                    # block grab moves BEFORE the dispatch: the fused
-                    # program reads the final roster/table, and a dry-pool
-                    # preemption can still unwind the prefilling request
-                    # ahead of its chunk
-                    if self.paged:
-                        sched.ensure_decode_blocks()
-                    worked = self._step_fused(params, extras, it, t0)
-                else:
-                    worked = self._step_prefill(params, extras, it, t0)
-                    if self.paged:
-                        sched.ensure_decode_blocks()
-                    worked = self._step_decode(params, it, t0) or worked
-                if worked:
-                    self.work_iterations += 1
-                if self.paged and self._validate:
-                    sched.audit_pool()
-                sched.observe_progress(it, bool(reaped or admitted
-                                                or worked))
-                sched.it += 1
+                    with tracing.span(tracing.HOOK):
+                        self.iteration_hook(self, it)
+                with tracing.iteration(it) as step_span:
+                    if (self.cfg.snapshot_every
+                            and it % self.cfg.snapshot_every == 0):
+                        # iteration boundary = consistent state: a crash
+                        # later this iteration rewinds here via restore()
+                        self.last_snapshot = self.snapshot()
+                    with tracing.span(tracing.SCHEDULE):
+                        sched.stamp_arrivals(it, time.perf_counter())
+                        reaped = sched.reap(it)
+                        admitted = sched.admit(it)
+                        if self.fused_step:
+                            # block grab moves BEFORE the dispatch: the
+                            # fused program reads the final roster/table,
+                            # and a dry-pool preemption can still unwind
+                            # the prefilling request ahead of its chunk
+                            if self.paged:
+                                sched.ensure_decode_blocks()
+                            plan = sched.plan_step()
+                    if self.fused_step:
+                        tracing.describe(step_span, plan)
+                        worked = self._step_fused(params, extras, it, plan)
+                    else:
+                        worked = self._step_prefill(params, extras, it)
+                        if self.paged:
+                            with tracing.span(tracing.SCHEDULE):
+                                sched.ensure_decode_blocks()
+                        worked = self._step_decode(params, it) or worked
+                    if worked:
+                        self.work_iterations += 1
+                    if self.paged and self._validate:
+                        sched.audit_pool()
+                    sched.observe_progress(it, bool(reaped or admitted
+                                                    or worked))
+                    sched.it += 1
         finally:
             fault_mod.deactivate()
-        it = sched.it - it0
-        wall = time.perf_counter() - t0
-        gen = sum(len(r.out) for r in sched.requests)
-        snap = MetricsSnapshot(
-            iterations=it,
-            wall_s=wall,
-            generated_tokens=gen,
-            tokens_per_s=gen / max(wall, 1e-9),
-            trace_counts=dict(ex.trace_counts),
-            # compiled-program launches per iteration that ran model work
-            # (oracle re-runs included) — 1.0 on a clean fused run, ~2 on
-            # the legacy two-program split when prefill+decode overlap
-            dispatches=ex.dispatches - disp0,
-            dispatches_per_iteration=(
-                (ex.dispatches - disp0)
-                / max(self.work_iterations - work0, 1)),
-            degraded_iterations=ex.degraded_iterations - degraded0,
-            lifecycle=LifecycleMetrics(
-                terminal_states={
-                    s: sum(1 for r in sched.requests if r.state == s)
-                    for s in TERMINAL},
-                admission_retries=sched.admission_retries - retries0,
-                watchdog_trips=sched.watchdog_trips - wdog0,
-                timeouts=sched.timeouts - timeout0,
-                cancellations=sched.cancellations - cancel0,
-                restores=self.restores,
-                faults_fired=(self.faults.total_fired
-                              if self.faults is not None else 0),
-            ),
-            paged=(PagedMetrics(
-                enabled=True,
-                block_size=sched.pool.block_size,
-                num_blocks=sched.pool.num_blocks,
-                peak_blocks_in_use=sched.pool.peak_in_use,
-                preemptions=sched.preemptions - preempt0,
-                rejections=sched.rejections - reject0,
-                attention_kernel=ex.paged_kernel,
-                prefix_cache=sched.prefix_cache,
-                prefix_hits=sched.prefix_hits - hits0,
-                blocks_reused=sched.blocks_reused - reused0,
-                tokens_skipped=sched.tokens_skipped - skipped0,
-                prefill_tokens=sched.prefill_demand - demand0,
-                cached_blocks=sched.pool.cached_blocks,
-                evictions=sched.pool.evictions - evict0,
-            ) if self.paged else PagedMetrics(enabled=False)),
-            requests=[RequestMetrics(
-                rid=r.rid,
-                prompt_len=int(len(r.tokens)),
-                arrival=r.arrival,
-                state=r.state,
-                admitted_iter=r.admitted_iter,
-                first_token_iter=r.first_token_iter,
-                done_iter=r.done_iter,
-                latency_iters=r.done_iter - r.arrival,
-                latency_s=r.done_time,
-                n_out=len(r.out),
-                preemptions=r.preempted,
-                cached_tokens=r.cached_tokens,
-                retries=r.retries,
-                deadline=r.deadline,
-            ) for r in sched.requests],
-        )
-        self.metrics_snapshot = snap
-        self.metrics = snap.to_dict()
+        with tracing.span(tracing.METRICS):
+            wall = time.perf_counter() - t0
+            gen = sum(len(r.out) for r in sched.requests)
+            snap = MetricsSnapshot(
+                iterations=sched.it - it0,
+                wall_s=wall,
+                generated_tokens=gen,
+                tokens_per_s=gen / max(wall, 1e-9),
+                trace_counts=dict(ex.trace_counts),
+                # compiled-program launches per iteration that ran model
+                # work (oracle re-runs included) — 1.0 on a clean fused
+                # run, ~2 on the legacy two-program split when
+                # prefill+decode overlap
+                dispatches=ex.dispatches - disp0,
+                dispatches_per_iteration=(
+                    (ex.dispatches - disp0)
+                    / max(self.work_iterations - work0, 1)),
+                degraded_iterations=ex.degraded_iterations - degraded0,
+                lifecycle=LifecycleMetrics(
+                    terminal_states={
+                        s: sum(1 for r in sched.requests if r.state == s)
+                        for s in TERMINAL},
+                    admission_retries=sched.admission_retries - retries0,
+                    watchdog_trips=sched.watchdog_trips - wdog0,
+                    timeouts=sched.timeouts - timeout0,
+                    cancellations=sched.cancellations - cancel0,
+                    restores=self.restores,
+                    faults_fired=(self.faults.total_fired
+                                  if self.faults is not None else 0),
+                ),
+                paged=(PagedMetrics(
+                    enabled=True,
+                    block_size=sched.pool.block_size,
+                    num_blocks=sched.pool.num_blocks,
+                    peak_blocks_in_use=sched.pool.peak_in_use,
+                    preemptions=sched.preemptions - preempt0,
+                    rejections=sched.rejections - reject0,
+                    attention_kernel=ex.paged_kernel,
+                    prefix_cache=sched.prefix_cache,
+                    prefix_hits=sched.prefix_hits - hits0,
+                    blocks_reused=sched.blocks_reused - reused0,
+                    tokens_skipped=sched.tokens_skipped - skipped0,
+                    prefill_tokens=sched.prefill_demand - demand0,
+                    cached_blocks=sched.pool.cached_blocks,
+                    evictions=sched.pool.evictions - evict0,
+                ) if self.paged else PagedMetrics(enabled=False)),
+                requests=[RequestMetrics(
+                    rid=r.rid,
+                    prompt_len=int(len(r.tokens)),
+                    arrival=r.arrival,
+                    state=r.state,
+                    admitted_iter=r.admitted_iter,
+                    first_token_iter=r.first_token_iter,
+                    done_iter=r.done_iter,
+                    latency_iters=r.done_iter - r.arrival,
+                    latency_s=(r.done_t - r.visible_t if r.done_t >= 0
+                               and r.visible_t >= 0 else 0.0),
+                    n_out=len(r.out),
+                    preemptions=r.preempted,
+                    cached_tokens=r.cached_tokens,
+                    retries=r.retries,
+                    visible_t=r.visible_t,
+                    admitted_t=r.admitted_t,
+                    first_chunk_t=r.first_chunk_t,
+                    first_token_t=r.first_token_t,
+                    done_t=r.done_t,
+                ) for r in sched.requests],
+            )
+            self.metrics_snapshot = snap
+            self.metrics = snap.to_dict()
         return {
             "outputs": {r.rid: list(r.out) for r in sched.requests},
             "metrics": self.metrics,

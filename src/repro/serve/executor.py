@@ -31,6 +31,7 @@ import numpy as np
 from repro.core.policy import DENSE, SparsityPolicy
 from repro.distributed import tp as tp_mod
 from repro.serve import slots as slot_ops
+from repro.serve import tracing
 from repro.serve.faults import KernelFault
 from repro.serve.paged import init_paged_cache
 from repro.serve.scheduler import StepPlan
@@ -155,14 +156,17 @@ class Executor:
                 # runs at trace time only
                 self.trace_counts[count_key] = \
                     self.trace_counts.get(count_key, 0) + 1
-                sub = slot_ops.slice_slot(cache, slot, self._spec)
+                with jax.named_scope("step.slot_io"):
+                    sub = slot_ops.slice_slot(cache, slot, self._spec)
                 batch = {"tokens": tokens, "chunk_len": chunk_len, **extras}
                 logits, sub = self.model.prefill_chunk(params, batch, sub,
                                                        policy=policy)
                 logits = logits[0] + fault
                 ok = jnp.all(jnp.isfinite(logits))
-                return logits, slot_ops.write_slot(cache, slot, sub,
-                                                   self._spec), ok
+                with jax.named_scope("step.slot_io"):
+                    cache = slot_ops.write_slot(cache, slot, sub, self._spec)
+                return logits, cache, ok
+            prefill_fn.__name__ = prefill_fn.__qualname__ = count_key
             return prefill_fn
 
         dense = DENSE.with_(use_pallas_kernels=policy.use_pallas_kernels)
@@ -174,8 +178,9 @@ class Executor:
                 logits, new_cache = self.model.decode_step(
                     params, tokens[:, None], cache, policy=policy)
                 logits = logits + fault
-                new_cache = slot_ops.where_active(active, new_cache, cache,
-                                                  self._spec)
+                with jax.named_scope("step.cache_select"):
+                    new_cache = slot_ops.where_active(active, new_cache,
+                                                      cache, self._spec)
                 nxt = self._sample(logits, key)
                 # inactive slots may legitimately hold junk logits — only
                 # active rows gate the degradation ladder
@@ -183,6 +188,7 @@ class Executor:
                              | ~active.reshape(active.shape[0],
                                                *([1] * (logits.ndim - 1))))
                 return jnp.where(active, nxt, tokens), new_cache, ok
+            decode_fn.__name__ = decode_fn.__qualname__ = count_key
             return decode_fn
 
         self._prefill_jit = jit(make_prefill_fn(policy, "prefill"))
@@ -230,23 +236,26 @@ class Executor:
                 ok = jnp.asarray(True)
                 ptok = jnp.asarray(0, jnp.int32)
                 if has_prefill:
-                    sub = slot_ops.slice_slot(cache, slot, self._spec)
+                    with jax.named_scope("step.slot_io"):
+                        sub = slot_ops.slice_slot(cache, slot, self._spec)
                     batch = {"tokens": tokens, "chunk_len": chunk_len,
                              **extras}
                     p_logits, sub = self.model.prefill_chunk(
                         params, batch, sub, policy=pf_policy)
                     p_logits = p_logits[0] + fault
                     ok = ok & jnp.all(jnp.isfinite(p_logits))
-                    cache = slot_ops.write_slot(cache, slot, sub,
-                                                self._spec)
+                    with jax.named_scope("step.slot_io"):
+                        cache = slot_ops.write_slot(cache, slot, sub,
+                                                    self._spec)
                     ptok = self._sample(p_logits, pkey)
                 nxt = toks
                 if has_decode:
                     d_logits, new_cache = self.model.decode_step(
                         params, toks[:, None], cache, policy=dec_policy)
                     d_logits = d_logits + fault
-                    cache = slot_ops.where_active(active, new_cache, cache,
-                                                  self._spec)
+                    with jax.named_scope("step.cache_select"):
+                        cache = slot_ops.where_active(active, new_cache,
+                                                      cache, self._spec)
                     # inactive slots may legitimately hold junk logits —
                     # only active rows gate the degradation ladder
                     ok = ok & jnp.all(
@@ -256,6 +265,9 @@ class Executor:
                     nxt = jnp.where(active, self._sample(d_logits, dkey),
                                     toks)
                 return ptok, nxt, cache, ok
+            # the bucket's name names the jitted program: XLA's module
+            # (jit_<bucket>) and JAX's compile events carry it
+            step_fn.__name__ = step_fn.__qualname__ = count_key
             return step_fn
 
         # raw (unjitted) step fns are kept for the jaxpr pins in tests and
@@ -276,10 +288,11 @@ class Executor:
 
     # ------------------------------------------------------------- sampling
     def _sample(self, logits, key):
-        if self.cfg.temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jax.random.categorical(
-            key, logits / self.cfg.temperature, axis=-1).astype(jnp.int32)
+        with jax.named_scope("step.sample"):
+            if self.cfg.temperature <= 0.0:
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jax.random.categorical(
+                key, logits / self.cfg.temperature, axis=-1).astype(jnp.int32)
 
     def sample_token(self, logits, key) -> int:
         return int(self._sample(logits, key))
@@ -323,11 +336,13 @@ class Executor:
         """Apply the plan's idempotent cache-side effects BEFORE the step:
         slot resets decided at admission and the host block table when the
         scheduler rewrote it."""
-        for slot, pos in plan.resets:
-            self.cache = slot_ops.reset_slot(self.cache, slot, self._spec,
-                                             pos=pos)
-        if plan.table is not None:
-            self.cache["block_table"] = self._on_mesh(jnp.asarray(plan.table))
+        with tracing.span(tracing.APPLY_EFFECTS):
+            for slot, pos in plan.resets:
+                self.cache = slot_ops.reset_slot(self.cache, slot,
+                                                 self._spec, pos=pos)
+            if plan.table is not None:
+                self.cache["block_table"] = self._on_mesh(
+                    jnp.asarray(plan.table))
 
     # ----------------------------------------------------------- dispatch
     def _tp_scope(self):
@@ -341,17 +356,19 @@ class Executor:
         the SAME operands on the bit-exact jnp oracle program."""
         self.dispatches += 1
         try:
-            with self._tp_scope():
+            with tracing.span(tracing.DISPATCH), self._tp_scope():
                 out = fn(*args, fault)
-            ok = bool(out[-1])
+            with tracing.span(tracing.WAIT):
+                ok = bool(out[-1])
         except KernelFault:
             ok = False
         if not ok:
             self.degraded_iterations += 1
             self.dispatches += 1
-            with self._tp_scope():
-                out = ofn(*args, jnp.float32(0.0))
-            assert bool(out[-1]), "oracle produced non-finite logits"
+            with tracing.span(tracing.ORACLE):
+                with self._tp_scope():
+                    out = ofn(*args, jnp.float32(0.0))
+                assert bool(out[-1]), "oracle produced non-finite logits"
         return out
 
     def step(self, params, plan: StepPlan, extras: Dict, pkey, dkey,
@@ -383,10 +400,11 @@ class Executor:
             self._step_jits[bucket], self._step_oracle_jits[bucket],
             args, fault)
         self.cache = new_cache
-        return StepResult(
-            prefill_token=int(ptok) if pw is not None else None,
-            decode_tokens=np.asarray(nxt) if dw is not None else None,
-            degraded=self.degraded_iterations > degraded0)
+        with tracing.span(tracing.WAIT):
+            return StepResult(
+                prefill_token=int(ptok) if pw is not None else None,
+                decode_tokens=np.asarray(nxt) if dw is not None else None,
+                degraded=self.degraded_iterations > degraded0)
 
     def prefill(self, params, plan: StepPlan, extras: Dict, fault):
         """Legacy two-program split, phase 1: run the chunk, return its

@@ -64,6 +64,12 @@ class PagedMetrics:
 
 @dataclasses.dataclass
 class RequestMetrics:
+    """One request.  The ``*_t`` fields are its timeline on
+    ``time.perf_counter()`` (-1 where the request never got there), each
+    stamped once where the scheduler decided it: visible (arrival
+    iteration reached), admitted to a slot, first prefill chunk handed
+    out, first token on the host, terminal.  ``latency_s`` is
+    ``done_t - visible_t``."""
     rid: int
     prompt_len: int
     arrival: int
@@ -77,7 +83,11 @@ class RequestMetrics:
     preemptions: int
     cached_tokens: int
     retries: int
-    deadline: Optional[int]
+    visible_t: float = -1.0
+    admitted_t: float = -1.0
+    first_chunk_t: float = -1.0
+    first_token_t: float = -1.0
+    done_t: float = -1.0
 
 
 @dataclasses.dataclass

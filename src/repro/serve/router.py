@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.policy import DENSE, SparsityPolicy
 from repro.distributed import tp as tp_mod
+from repro.serve import tracing
 from repro.serve.continuous import ContinuousConfig, ContinuousServingEngine
 from repro.serve.faults import EngineCrash, FaultInjector
 from repro.serve.metrics import MetricsSnapshot
@@ -209,16 +210,18 @@ class Router:
         # records relabeled to global rids.  Requests drained off a dead
         # replica are counted where they finished; a dead replica's own
         # partial run contributes no counters (its work was re-done).
-        back = {(rep, lrid): g for g, (rep, lrid) in self._rid_map.items()}
-        merged_parts = []
-        for i, p in sorted(parts.items()):
-            p = MetricsSnapshot.from_dict(p.to_dict())    # private copy
-            for r in p.requests:
-                r.rid = back.get((i, r.rid), r.rid)
-            merged_parts.append(p)
-        self.metrics_snapshot = MetricsSnapshot.merge(merged_parts,
-                                                      wall_s=wall)
-        self.metrics = self.metrics_snapshot.to_dict()
+        with tracing.span(tracing.METRICS):
+            back = {(rep, lrid): g
+                    for g, (rep, lrid) in self._rid_map.items()}
+            merged_parts = []
+            for i, p in sorted(parts.items()):
+                p = MetricsSnapshot.from_dict(p.to_dict())  # private copy
+                for r in p.requests:
+                    r.rid = back.get((i, r.rid), r.rid)
+                merged_parts.append(p)
+            self.metrics_snapshot = MetricsSnapshot.merge(merged_parts,
+                                                          wall_s=wall)
+            self.metrics = self.metrics_snapshot.to_dict()
         outputs = dict(self._outputs)
         for g, (rep, lrid) in self._rid_map.items():
             if g not in outputs:

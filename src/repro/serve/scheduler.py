@@ -71,8 +71,13 @@ class Request:
     admitted_iter: int = -1
     first_token_iter: int = -1
     done_iter: int = -1
-    arrival_time: float = -1.0         # wall clock when arrival was reached
-    done_time: float = 0.0             # wall-clock latency from arrival
+    # timeline on time.perf_counter(), each stamped once (-1 = not yet):
+    # a preempted or replayed request keeps its first stamps
+    visible_t: float = -1.0            # arrival iteration reached
+    admitted_t: float = -1.0           # first admission to a slot
+    first_chunk_t: float = -1.0        # first prefill chunk handed out
+    first_token_t: float = -1.0        # first sampled token on the host
+    done_t: float = -1.0               # terminal state reached
     # --- lifecycle hardening ---
     deadline: Optional[int] = None     # absolute iteration bound (TIMED_OUT)
     cancel_requested: bool = False     # processed at the next iteration start
@@ -267,11 +272,7 @@ class Scheduler:
                 req.slot = -1
         req.state = state
         req.done_iter = it
-        # terminal latency is still wall-clock since arrival — evicted
-        # requests (cancelled / timed out / rejected) otherwise report the
-        # -1.0 dataclass default as their latency_s
-        if req.arrival_time >= 0:
-            req.done_time = time.perf_counter() - req.arrival_time
+        req.done_t = time.perf_counter()
         req.filled = 0
         req.kv_len = 0
 
@@ -307,13 +308,13 @@ class Scheduler:
         return n
 
     def stamp_arrivals(self, it: int, now: float) -> None:
-        """Anchor wall-clock latency at arrival.  Stamped unconditionally
-        on visibility, NOT gated on WAITING: a request admitted the same
-        iteration it became visible would otherwise keep the -1.0 default
-        and report garbage latency."""
+        """Stamp ``visible_t`` on every request whose arrival iteration
+        has come.  Unconditional on visibility, NOT gated on WAITING: a
+        request admitted the same iteration it became visible must carry
+        the stamp too."""
         for r in self.requests:
-            if r.arrival <= it and r.arrival_time < 0:
-                r.arrival_time = now
+            if r.arrival <= it and r.visible_t < 0:
+                r.visible_t = now
 
     def _seq(self, req: Request) -> np.ndarray:
         """Tokens to prefill: the prompt, plus — after a preemption — the
@@ -443,6 +444,8 @@ class Scheduler:
             req.slot, req.state = slot, PREFILL
             req.filled = req.kv_len = skip
             req.admitted_iter = it
+            if req.admitted_t < 0:
+                req.admitted_t = time.perf_counter()
             self._slot_req[slot] = req
             moved += 1
         return moved
@@ -523,11 +526,10 @@ class Scheduler:
                                  key=lambda v: (v.admitted_iter, v.rid))
                     self.preempt(victim)
 
-    def finish(self, req: Request, it: int, t0: float) -> None:
+    def finish(self, req: Request, it: int) -> None:
         req.state = DONE
         req.done_iter = it
-        anchor = req.arrival_time if req.arrival_time >= 0 else t0
-        req.done_time = time.perf_counter() - anchor
+        req.done_t = time.perf_counter()
         if self.paged and req.blocks:
             self._register_blocks(req)
             self.pool.release(req.blocks[::-1])   # chain head → MRU end
@@ -597,6 +599,8 @@ class Scheduler:
         tokens, clen, first, replay = self.next_chunk(req)
         if tokens is None:     # fully ingested, parked — nothing to run
             return None
+        if req.first_chunk_t < 0:
+            req.first_chunk_t = time.perf_counter()
         return PrefillWork(req, tokens, clen, first, replay)
 
     def _decode_work(self) -> Optional[DecodeWork]:
@@ -646,27 +650,28 @@ class Scheduler:
     def seq_complete(self, req: Request) -> bool:
         return req.filled == len(self._seq(req))
 
-    def emit_prefill_token(self, req: Request, tok: int, it: int,
-                           t0: float) -> None:
+    def emit_prefill_token(self, req: Request, tok: int, it: int) -> None:
         """The chunk that completed the sequence sampled ``tok``: record
         it and transition to DECODE (or finish on eos/budget)."""
         req.out.append(tok)
         if req.first_token_iter < 0:
             req.first_token_iter = it
+        if req.first_token_t < 0:
+            req.first_token_t = time.perf_counter()
         if tok == self.cfg.eos_token or len(req.out) >= req.max_new_tokens:
-            self.finish(req, it, t0)
+            self.finish(req, it)
         else:
             req.state, req.cur = DECODE, tok
 
     def emit_decode_tokens(self, work: DecodeWork, nxt: np.ndarray,
-                           it: int, t0: float) -> None:
+                           it: int) -> None:
         for r in work.requests:
             r.kv_len += 1
             tok = int(nxt[r.slot])
             r.out.append(tok)
             r.cur = tok
             if tok == self.cfg.eos_token or len(r.out) >= r.max_new_tokens:
-                self.finish(r, it, t0)
+                self.finish(r, it)
 
     # ------------------------------------------------------------ watchdog
     def observe_progress(self, it: int, progressed: bool) -> None:

@@ -13,7 +13,7 @@ Covers the tentpole contract and its satellites:
     pool-shaped gathers or scatters outside a ``pallas_call`` (the KV
     scatter moved in-kernel; the jnp oracle keeps both, so the pin bites).
   * Chaos: seeds 0-2 stay green with the fused step enabled.
-  * Latency report (bugfix): ``arrival_time`` is stamped unconditionally,
+  * Latency report (bugfix): ``visible_t`` is stamped unconditionally,
     so no terminal request — finished, cancelled, or timed out — reports
     the garbage ``-1.0`` default through the ``--trace`` latency report.
 """
