@@ -110,8 +110,8 @@ def run() -> list[str]:
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
     nb_, bs_, mb_ = 24, 16, 16
     bp, tq, hq, hkv, hd = 2, 16, 4, 2, 64
-    kpool = jax.random.normal(kk, (nb_, bs_, hkv, hd), dtype=jnp.bfloat16)
-    vpool = jax.random.normal(kv, (nb_, bs_, hkv, hd), dtype=jnp.bfloat16)
+    kpool = jax.random.normal(kk, (nb_, hkv, bs_, hd), dtype=jnp.bfloat16)
+    vpool = jax.random.normal(kv, (nb_, hkv, bs_, hd), dtype=jnp.bfloat16)
     tab = np.full((bp, mb_), -1, np.int32)
     tab[0, :8] = np.arange(1, 9)           # 128 valid rows
     tab[1, :6] = np.arange(9, 15)          # 96 valid rows
@@ -133,10 +133,10 @@ def run() -> list[str]:
     view_rows = bp * mb_ * bs_
     # oracle: full-Hkv logical view written then re-read (2 passes), K and V
     est_o = (2 * 2 * view_rows * hkv * row_head) / HBM
-    # kernel: each allocated block streams once per (query head, q-tile) as
-    # a single-KV-head slice — the cost model in kernels/__init__.py
-    n_qt = tq // min(128, tq)
-    est_k = (2 * hq * n_qt * walked * row_head) / HBM
+    # kernel: each allocated block streams once per KV head (its group's
+    # query heads share the q tile; one q tile here) — the cost model in
+    # kernels/__init__.py
+    est_k = (2 * hkv * walked * row_head) / HBM
     rows.append(csv_row(
         f"kernel/paged_attention/{bp}x{tq}x{mb_ * bs_}", us_k,
         f"oracle_us={us_o:.1f};tpu_est_s={est_k:.3e};"

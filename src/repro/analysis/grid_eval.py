@@ -32,6 +32,13 @@ lengths) are known.  This module
   3. checks bounds / revisit-contiguity / aliased-RAW over the resulting
      per-step block-index sequences (:func:`check_grid`).
 
+An operand left in HBM (``pl.ANY``) has no index map: the kernel body
+copies from it with its own DMAs.  For such kernels the body itself is
+walked at every step with the same concrete scalars (program ids,
+scalar-prefetch loads, scalar arithmetic, ``pl.when`` branches and static
+loops), recording each ``dma_start``'s source window
+(:class:`DmaStart`), which the bounds check and the ``hbm`` rules read.
+
 Skipped-step index remaps (PR 4's refetch-elision trick) are covered by
 the same two write checks: a remap that parks on a block some other step
 writes shows up as a discontiguous revisit or an aliased refetch of a
@@ -224,6 +231,8 @@ class OperandGrid:
     block_bytes: int
     indices: Any                    # (steps, ndim) int ndarray
     scalar_dependent: bool
+    manual: bool = False            # left in HBM (pl.ANY): the kernel
+                                    # copies from it with its own DMAs
 
     @property
     def label(self) -> str:
@@ -243,6 +252,17 @@ class GridEval:
     inputs: List[OperandGrid]
     outputs: List[OperandGrid]
     aliases: List[Tuple[int, int]]   # (input idx, output idx), bm-relative
+    dmas: List["DmaStart"] = dataclasses.field(default_factory=list)
+
+
+@dataclasses.dataclass
+class DmaStart:
+    """One DMA a kernel body starts itself from an operand left in HBM."""
+    operand: int                     # input index, bm-relative
+    step: int                        # grid step (row-major)
+    index: Tuple[int, ...]           # first source element, per dim
+    shape: Tuple[int, ...]           # source window extents, per dim
+    nbytes: int
 
 
 def _block_dims(block_shape) -> Tuple[int, ...]:
@@ -334,7 +354,8 @@ def eval_pallas_eqn(eqn, invals: Sequence[Any]):
             block_bytes=(math.prod(bdims)
                          * np.dtype(arr.dtype).itemsize),
             indices=idx,
-            scalar_dependent=_scalar_dependent(cj, naxes))
+            scalar_dependent=_scalar_dependent(cj, naxes),
+            manual=_in_hbm(bm))
         (outputs if is_out else inputs).append(og)
 
     aliases: List[Tuple[int, int]] = []
@@ -342,8 +363,163 @@ def eval_pallas_eqn(eqn, invals: Sequence[Any]):
                                                 ()) or ()):
         aliases.append((int(op_idx) - n_idx, int(out_idx)))
 
+    dmas: List[DmaStart] = []
+    if any(og.manual for og in inputs):
+        body = eqn.params["jaxpr"]
+        refs = [np.asarray(x) for x in scalars] + [
+            _OperandRef(i) for i in range(len(body.invars) - n_idx)]
+        for step in range(steps):
+            pos = [int(x) for x in grid_idx[step]]
+            found: List[Tuple[int, Tuple[int, ...], Tuple[int, ...]]] = []
+            if _walk_body(body, [], refs, pos, grid, found) is None:
+                return (f"{kernel}: a DMA of the kernel body depends on "
+                        "values the scalar walk cannot resolve")
+            for op, start, shape in found:
+                if op >= len(inputs):      # a copy out of scratch
+                    continue
+                arr = gm.block_mappings[op].array_aval
+                dmas.append(DmaStart(
+                    op, step, start, shape,
+                    math.prod(shape) * np.dtype(arr.dtype).itemsize))
+
     return GridEval(kernel=kernel, grid=grid, steps=steps, inputs=inputs,
-                    outputs=outputs, aliases=aliases)
+                    outputs=outputs, aliases=aliases, dmas=dmas)
+
+
+def _in_hbm(bm) -> bool:
+    space = getattr(bm.transformed_block_aval, "memory_space", None)
+    return str(space).lower().endswith("any")
+
+
+class _OperandRef:
+    """A kernel operand ref in the body walk (its position after the
+    scalar-prefetch refs)."""
+
+    def __init__(self, pos: int):
+        self.pos = pos
+
+
+def _walk_body(jaxpr, consts, invals, pos, grid, found):
+    """Scalar walk of a kernel body for one grid step: program ids, loads
+    from the concrete scalar-prefetch refs and scalar arithmetic are
+    evaluated, ``pl.when`` branches followed, and every ``dma_start`` from
+    an operand records ``(operand, first source index, window shape)``
+    into ``found``.  Vector work stays unevaluated.  Returns the jaxpr's
+    output values, or None when a branch holding a DMA, or a DMA's source
+    window, cannot be resolved."""
+    import numpy as np
+    from jax import tree_util
+    from jax.extend import core as jax_core
+    from jax._src.pallas.mosaic.primitives import _dma_unflatten
+    from jax._src.state.indexing import NDIndexer, Slice
+
+    env: Dict[Any, Any] = {}
+
+    def read(v):
+        if isinstance(v, jax_core.Literal):
+            return v.val
+        return env.get(v, UNKNOWN)
+
+    def scalar(x):
+        return (not isinstance(x, (_Unknown, _OperandRef))
+                and getattr(x, "shape", ()) == ())
+
+    env.update(zip(jaxpr.constvars, consts))
+    env.update(zip(jaxpr.invars, invals))
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        vals = [read(v) for v in eqn.invars]
+        outs: Optional[List[Any]] = [UNKNOWN] * len(eqn.outvars)
+        if name == "program_id":
+            outs = [np.int32(pos[eqn.params["axis"]])]
+        elif name == "num_programs":
+            outs = [np.int32(grid[eqn.params["axis"]])]
+        elif name == "get" and isinstance(vals[0], np.ndarray):
+            (idx,) = tree_util.tree_unflatten(eqn.params["tree"], vals[1:])
+            if all(scalar(i) for i in idx.indices):
+                outs = [vals[0][tuple(int(i) for i in idx.indices)]]
+        elif name == "cond" and scalar(vals[0]):
+            j, c = _closed(eqn.params["branches"][int(vals[0])])
+            outs = _walk_body(j, c, vals[1:], pos, grid, found)
+        elif name == "cond" and _has_dma(eqn):
+            return None
+        elif name in ("pjit", "jit", "closed_call"):
+            j, c = _closed(eqn.params.get("jaxpr")
+                           or eqn.params.get("call_jaxpr"))
+            outs = _walk_body(j, c, vals, pos, grid, found)
+        elif name == "scan" and not eqn.params["reverse"]:
+            # a fori_loop with static bounds: walk every iteration
+            j, c = _closed(eqn.params["jaxpr"])
+            nc, ncar = eqn.params["num_consts"], eqn.params["num_carry"]
+            carry = vals[nc:nc + ncar]
+            xs = [UNKNOWN] * (len(vals) - nc - ncar)
+            for _ in range(eqn.params["length"]):
+                res = _walk_body(j, c, vals[:nc] + carry + xs, pos, grid,
+                                 found)
+                if res is None:
+                    return None
+                carry = res[:ncar]
+            outs = carry + [UNKNOWN] * (len(eqn.outvars) - ncar)
+        elif name in ("scan", "while") and _has_dma(eqn):
+            return None
+        elif name == "dma_start":
+            src, src_tr = _dma_unflatten(eqn.params["tree"], vals)[:2]
+            if isinstance(src, _OperandRef):
+                window = _dma_window(src_tr, eqn.invars[0].aval.shape,
+                                     scalar, NDIndexer, Slice)
+                if window is None:
+                    return None
+                found.append((src.pos,) + window)
+        elif all(scalar(x) for x in vals) and all(
+                getattr(v.aval, "shape", None) == () for v in eqn.outvars):
+            try:
+                res = eqn.primitive.bind(*vals, **eqn.params)
+                res = res if eqn.primitive.multiple_results else [res]
+                outs = [np.asarray(o) for o in res]
+            except Exception:  # noqa: BLE001 — stays unevaluated
+                pass
+        if outs is None:
+            return None
+        env.update(zip(eqn.outvars, outs))
+    return [read(v) for v in jaxpr.outvars]
+
+
+def _dma_window(transforms, shape, scalar, NDIndexer, Slice):
+    """``(first index, extents)`` per dim of a ref of ``shape`` that a
+    chain of ``.at[...]`` indexers selects (extent 1 where an integer
+    index drops the dim); None if unresolved."""
+    start, ext = [0] * len(shape), [int(d) for d in shape]
+    dims = list(range(len(shape)))          # dims the next indexer sees
+    for tr in transforms:
+        if not isinstance(tr, NDIndexer):
+            return None
+        kept = []
+        for d, ix in zip(dims, tr.indices):
+            if isinstance(ix, Slice):
+                if not scalar(ix.start) or ix.stride != 1:
+                    return None
+                start[d] += int(ix.start)
+                ext[d] = int(ix.size)
+                kept.append(d)
+            elif scalar(ix):
+                start[d] += int(ix)
+                ext[d] = 1
+            else:
+                return None
+        dims = kept + dims[len(tr.indices):]
+    return tuple(start), tuple(ext)
+
+
+def _has_dma(eqn) -> bool:
+    from jax.extend import core as jax_core
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(j, (jax_core.Jaxpr, jax_core.ClosedJaxpr)):
+                jj, _ = _closed(j)
+                if any(e.primitive.name == "dma_start" or _has_dma(e)
+                       for e in jj.eqns):
+                    return True
+    return False
 
 
 def _runs(indices) -> List[Tuple[Tuple[int, ...], int, int]]:
@@ -395,6 +571,19 @@ def check_grid(ge: GridEval) -> List[Dict[str, Any]]:
                 "kind": "oob", "operand": og.label, "kernel": ge.kernel,
                 "count": len(bad), "nblocks": list(nblk),
                 "first": {"step": bad[0][0], "block": list(bad[0][1])}})
+
+    # a body-issued DMA must read inside its HBM operand, too
+    for og in ge.inputs:
+        if not og.manual:
+            continue
+        bad = [d for d in ge.dmas if d.operand == og.idx and any(
+            i < 0 or i + n > a
+            for i, n, a in zip(d.index, d.shape, og.array_shape))]
+        if bad:
+            issues.append({
+                "kind": "dma-oob", "operand": og.label, "kernel": ge.kernel,
+                "count": len(bad),
+                "first": {"step": bad[0].step, "index": list(bad[0].index)}})
 
     # (a) non-aliased outputs: a block revisited in >1 run is written
     # back through a stale VMEM copy (WAW) under Mosaic pipelining

@@ -7,12 +7,15 @@ contract by MEASURING the same quantity from the kernels' real grids:
 
     measured = Σ_inputs  (fetch runs  × block bytes)
              + Σ_outputs (write runs × block bytes)
+             + Σ_DMAs    (bytes a kernel body copies itself)
 
 where "runs" is the maximal-constant-run compression of each operand's
 per-grid-step block-index sequence, obtained by concretely evaluating
 every BlockSpec index map over the enumerated grid
 (:mod:`repro.analysis.grid_eval`) — i.e. exactly the refetch/write-back
-elision Mosaic's pipeline performs.  The formulas are an independent
+elision Mosaic's pipeline performs.  An operand left in HBM (``pl.ANY``)
+has no pipeline; its traffic is the DMAs the body starts, found by the
+same walk evaluating the body's scalar control flow at every step.  The formulas are an independent
 re-derivation from the documented contract, so >10% divergence means a
 kernel's grid/BlockSpecs changed without the cost model (or the model
 was wrong all along) — either way, CI fails until they agree.
@@ -47,11 +50,17 @@ def measured_call_bytes(ge: GridEval) -> Tuple[int, Dict[str, Any]]:
     total = 0
     detail: Dict[str, Any] = {}
     for og in ge.inputs + ge.outputs:
-        runs = len(_runs(og.indices))
-        byts = runs * og.block_bytes
+        if og.manual:
+            dmas = [d for d in ge.dmas if d.operand == og.idx]
+            byts = sum(d.nbytes for d in dmas)
+            detail[og.label] = {"dmas": len(dmas), "bytes": byts}
+        else:
+            runs = len(_runs(og.indices))
+            byts = runs * og.block_bytes
+            detail[og.label] = {"runs": runs,
+                                "block_bytes": og.block_bytes,
+                                "bytes": byts}
         total += byts
-        detail[og.label] = {"runs": runs, "block_bytes": og.block_bytes,
-                            "bytes": byts}
     return total, detail
 
 

@@ -12,6 +12,9 @@ computes a worst-case footprint:
            + vmem scratch bytes                # single-buffered
     smem  =  scalar-prefetch operands + smem scratch
 
+Operands left in HBM (``pl.ANY``) take no VMEM: the kernel copies what
+it needs into its own scratch buffers, which the scratch term counts.
+
 The x2 models Mosaic's pipelined double buffering of every streamed
 block (see pallas guide "Patterns: Double Buffering"); scratch buffers
 persist across grid steps and are not double-buffered.  Grids with a
@@ -81,19 +84,24 @@ def _block_numel(block_shape) -> int:
     return math.prod(_block_dims(block_shape))
 
 
+
 def _ref_space_and_bytes(aval) -> Tuple[str, int]:
-    """(memory space, bytes) of a kernel ref aval (AbstractMemoryRef)."""
+    """(memory space, bytes) of a kernel ref aval (AbstractMemoryRef);
+    DMA semaphores take no VMEM or SMEM bytes."""
     inner = getattr(aval, "inner_aval", aval)
     shape = getattr(inner, "shape", getattr(aval, "shape", ()))
     dtype = getattr(inner, "dtype", getattr(aval, "dtype", None))
     space = getattr(aval, "memory_space", None)
     space = str(space).lower() if space is not None else "vmem"
+    if "semaphore" in space:
+        return "semaphore", 0
     nbytes = math.prod(int(d) for d in shape) * _itemsize(dtype)
     return ("smem" if "smem" in space else "vmem"), nbytes
 
 
 def estimate_jaxpr(jaxpr) -> List[PallasFootprint]:
     """Footprints for every ``pallas_call`` in a (Closed)Jaxpr."""
+    from repro.analysis.grid_eval import _in_hbm as in_hbm
     out: List[PallasFootprint] = []
     for eqn in pallas_call_eqns(jaxpr):
         gm = eqn.params["grid_mapping"]
@@ -103,6 +111,8 @@ def estimate_jaxpr(jaxpr) -> List[PallasFootprint]:
 
         block_bytes = 0
         for bm in gm.block_mappings:
+            if in_hbm(bm):            # stays in HBM; the body copies tiles
+                continue
             arr = bm.array_aval
             block_bytes += _block_numel(bm.block_shape) * _itemsize(arr.dtype)
 
@@ -121,7 +131,7 @@ def estimate_jaxpr(jaxpr) -> List[PallasFootprint]:
                 space, nb = _ref_space_and_bytes(v.aval)
                 if space == "smem":
                     smem_bytes += nb
-                else:
+                elif space == "vmem":
                     vmem_scratch += nb
 
         out.append(PallasFootprint(
@@ -275,7 +285,8 @@ def grid_zoo_entries(cfg) -> List[GridZooEntry]:
     from repro.kernels import ops
     from repro.kernels.flash_attention import flash_attention_pallas
     from repro.kernels.paged_attention import (paged_attention_pallas,
-                                               paged_kv_scatter_pallas)
+                                               paged_kv_scatter_pallas,
+                                               paged_tile)
     from repro.serve.paged import device_pool_rows
 
     # projections: two 128-lane blocks per axis (the wrappers pad smaller
@@ -348,27 +359,35 @@ def grid_zoo_entries(cfg) -> List[GridZooEntry]:
         dict(b=1, h=hq, hkv=hkv, t=t_attn, s_kv=t_attn, bq=ba, bk=ba,
              hd=hd)))
 
-    # paged pool: 2 rows, 16 allocatable blocks + the trailing sentinel
-    # row (never in any table).  Row 0 is a from-zero prefill (kv_len =
-    # its chunk); row 1 sits mid-sequence at pos 12/16.
-    bs, mb, nb = 8, 8, 16
+    # paged pool: 2 rows, 120 allocatable blocks + the trailing sentinel
+    # row (never in any table).  Row 0 is a from-zero prefill whose chunk
+    # (600) ends mid-tile; row 1 sits mid-sequence at pos 200.  A
+    # 1024-token call splits into two q tiles (g * t > the q-tile rows),
+    # and 80 table entries into three KV tiles of 32 pages, the last one
+    # partial.
+    bs, mb, nb = 16, 80, 120
     rows = device_pool_rows(nb)
     pool = jnp.zeros((rows, hkv, bs, hd), jnp.float32)
     atab = np.full((2, mb), -1, np.int32)
-    atab[0, :4] = [1, 2, 3, 4]
-    atab[1, :6] = [5, 6, 7, 8, 9, 10]
-    tq = 32
-    qoff = np.asarray([0, 16], np.int32)
-    kvl = np.asarray([tq, 16 + tq], np.int32)
+    atab[0, :38] = np.arange(1, 39)
+    atab[1, :77] = np.arange(39, 116)
+    tq = 1024
+    qoff = np.asarray([0, 200], np.int32)
+    kvl = np.asarray([600, 200 + tq], np.int32)
+    bq, pages = paged_tile(hq // hkv, tq, bs, mb, jnp.float32)
     entries.append(GridZooEntry(
         "paged_attention",
-        functools.partial(paged_attention_pallas, causal=True, block_q=16,
+        functools.partial(paged_attention_pallas, causal=True,
                           interpret=True),
         (jnp.zeros((2, tq, hq, hd), jnp.float32), pool, pool,
          jnp.asarray(atab), jnp.asarray(qoff), jnp.asarray(kvl)),
-        dict(b=2, h=hq, hkv=hkv, t=tq, bq=16, bs=bs, mb=mb, rows=rows,
-             hd=hd, tab=atab, qoff=qoff, kvl=kvl)))
+        dict(b=2, h=hq, hkv=hkv, t=tq, bq=bq, pages=pages, bs=bs, mb=mb,
+             rows=rows, hd=hd, tab=atab, qoff=qoff, kvl=kvl)))
 
+    # scatter: 16 allocatable blocks of 8 rows + the sentinel row
+    bs, mb, nb = 8, 8, 16
+    rows = device_pool_rows(nb)
+    pool = jnp.zeros((rows, hkv, bs, hd), jnp.float32)
     stab = np.full((2, mb), -1, np.int32)
     stab[0, :2] = [1, 2]
     stab[1, 1:4] = [5, 6, 7]

@@ -14,12 +14,14 @@ VMEM/registers, never HBM):
   flash_attention  — causal online-softmax attention, VMEM score tiles
                      (full and sliding-window self-attention; off-band KV
                      blocks are skipped at block granularity)
-  paged_attention  — flash attention over the paged KV pool: the KV grid
-                     axis walks the per-row block table (scalar prefetch,
-                     ``pltpu.PrefetchScalarGridSpec``), streaming each
-                     physical block through VMEM and skipping ``-1`` /
-                     ≥ ``kv_len`` / off-band blocks before their matmuls
-                     issue — chunked prefill at cache offsets and
+  paged_attention  — flash attention over the paged KV pool: one grid
+                     step takes a KV head's group of query heads
+                     against a tile of ~512 positions of pages, each
+                     page resolved through the scalar-prefetched block
+                     table (``pltpu.PrefetchScalarGridSpec``) and
+                     streamed through VMEM; tiles past ``kv_len`` or
+                     off-band skip their matmuls and DMAs, ``-1`` pages
+                     are masked — chunked prefill at cache offsets and
                      vector-position decode share one kernel
   paged_kv_scatter — the write side of the paged pool (same module): per
                      logical block the scalar-prefetched table picks the
@@ -68,7 +70,7 @@ overlaps.
   nm_spmm              s*(J*T*D + I*D*N + I*J*D + T*N)
   osparse_matmul       s*(2*J*T*D + 4*I*J*D + 2*I*N + T*N) + 2*I*D*N
   osparse_w8a8_decode  s*(J*T*D + 2*I*J*D + 2*I*N + T*N + 1) + I*D*N
-  paged_attention      s*(2*B*H*T*hd + runs(kv walk)*2*bs*hd)
+  paged_attention      s*(2*B*H*T*hd + 2*P*bs*hd*tiles(visible walk))
   paged_kv_scatter     s*(2*B*T*r + runs(pool walk)*4*bs*r)
   w8a8_matmul          J*T*D + I*D*N + s*(I*N + 1 + T*N)
 --- end generated table ---
@@ -76,11 +78,13 @@ overlaps.
 Symbols: T tokens, D in-features, N out-features, s dtype bytes (f32:
 4); grid extents I = T/bt, J = N/bo, K = D/bk (K-refetch of X/W blocks
 is why J·T·D and I·D·N appear, not T·D and D·N); attention B, H, hd,
-query tile bq, KV length S; paged r = Hkv·hd.  ``runs(·)`` counts
-maximal constant runs of the scalar-prefetched block walk — the paged
-formulas replay the documented table/visibility contract over the real
-block table (invisible steps park on the row-0/sentinel block, so
-consecutive skips fetch nothing).
+query tile bq, KV length S; paged r = Hkv·hd, P pages of bs rows per
+KV tile, T padded to whole q tiles.  The paged formulas replay the
+documented table/visibility contract over the real block table:
+``tiles(·)`` counts the KV tiles paged attention copies page by page
+itself (visible ones only), ``runs(·)`` the maximal constant runs of the
+scatter's scalar-prefetched block walk (skipped steps park on the
+sentinel block, so consecutive skips fetch nothing).
 
 Dispatch for the paged pool (``models/attention.paged_attention`` reads,
 ``models/attention.paged_kv_update`` writes) runs the same ladder as the
@@ -105,7 +109,7 @@ scalar-prefetched block tables:
   nm_spmm               2.75 MiB     0 B   llama31_8b        (1,56,8)
   osparse_matmul        2.01 MiB     0 B   llama31_8b        (1,56,16)
   osparse_w8a8_decode   0.32 MiB     0 B   llama31_8b        (1,56,8)
-  paged_attention       0.69 MiB  8256 B   recurrentgemma_2b (8,10,2,256)
+  paged_attention       5.13 MiB  8512 B   recurrentgemma_2b (8,1,4,8)
   paged_kv_scatter     10.00 MiB  8256 B   rwkv6_7b          (8,9)
   w8a8_matmul           1.25 MiB     0 B   llama31_8b        (1,56,8)
 
@@ -226,30 +230,29 @@ def _flash_attention_bytes(d):
 
 
 def _paged_attention_bytes(d):
-    # replay the documented block walk: grid (B, H, T/bq, mb), mb
-    # innermost; invisible steps (unallocated / beyond kv_len / causally
-    # future) remap to the row's FIRST block so consecutive skips elide
-    # their fetch.  GQA: query head h reads KV head h // (H/Hkv).
+    # replay the documented walk: grid (B, Hkv, ceil(T/bq), ceil(mb/P)),
+    # KV tiles innermost.  A (b, KV head, q tile) reads its g*bq-row q
+    # tile once and writes its output tile once (T padded to whole q
+    # tiles).  K and V stay in HBM: the body copies the P pages of each
+    # VISIBLE tile once, page by page (a tile in [0, last], ``last`` the
+    # last tile below kv_len — capped at the row's last allocated page —
+    # and, causally, at or before the q tile's last query); skipped tiles
+    # copy nothing.
     s = d.get("s", 4)
-    b, h, hkv, t = d["b"], d["h"], d["hkv"], d["t"]
-    bq, bs, mb, hd = d["bq"], d["bs"], d["mb"], d["hd"]
-    tab, qoff, kvl = d["tab"], d["qoff"], d["kvl"]
-    g = h // hkv
-    walk = []
+    b, h, hkv, t, hd = d["b"], d["h"], d["hkv"], d["t"], d["hd"]
+    bq, pages, bs, mb = d["bq"], d["pages"], d["bs"], d["mb"]
+    tk = pages * bs
+    nq = -(-t // bq)
+    tiles = 0
     for bi in range(b):
-        for hh in range(h):
-            for qi in range(t // bq):
-                for ki in range(mb):
-                    pb = int(tab[bi][ki])
-                    k_lo = ki * bs
-                    q_lo = int(qoff[bi]) + qi * bq
-                    vis = (pb >= 0 and k_lo < int(kvl[bi])
-                           and k_lo <= q_lo + bq - 1)       # causal
-                    if not vis:
-                        pb = int(tab[bi][0])
-                    walk.append((max(pb, 0), hh // g))
-    q_out = 2 * b * h * t * hd * s
-    return q_out + 2 * _run_count(walk) * bs * hd * s       # k and v
+        row = [int(x) for x in d["tab"][bi]]
+        n_alloc = max([i + 1 for i, pb in enumerate(row) if pb >= 0],
+                      default=0)
+        kv = min(int(d["kvl"][bi]), n_alloc * bs)
+        for qi in range(nq):
+            q_hi = int(d["qoff"][bi]) + qi * bq + bq - 1
+            tiles += hkv * max(0, min(-(-kv // tk), q_hi // tk + 1))
+    return s * (2 * b * h * nq * bq * hd + 2 * pages * bs * hd * tiles)
 
 
 def _paged_kv_scatter_bytes(d):
@@ -298,7 +301,7 @@ COST_MODEL = {
         "formula": "s*(2*B*H*T*hd + 2*B*H*(T/bq)*S*hd)",
         "bytes": _flash_attention_bytes},
     "paged_attention": {
-        "formula": "s*(2*B*H*T*hd + runs(kv walk)*2*bs*hd)",
+        "formula": "s*(2*B*H*T*hd + 2*P*bs*hd*tiles(visible walk))",
         "bytes": _paged_attention_bytes},
     "paged_kv_scatter": {
         "formula": "s*(2*B*T*r + runs(pool walk)*4*bs*r)",

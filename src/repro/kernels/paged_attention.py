@@ -1,46 +1,58 @@
-"""Pallas TPU kernel: paged flash attention — the KV grid axis walks the
-per-row block table in-kernel.
+"""Pallas TPU kernel: paged flash attention — each grid step takes one KV
+head's group of query heads against a tile of KV pages that the kernel
+resolves through the per-row block table.
 
 Why it exists here: PR 3's paged KV cache made allocation block-granular,
 but the serving engine's attention still gathered a dense
 ``(B, max_blocks * block_size, Hkv, hd)`` logical view into HBM on every
 chunked-prefill and decode call (``models/attention.gather_kv_blocks``) —
-a full write + re-read of the logical cache view per call, which the
-roofline pass shows is the dominant HBM term of paged serving.  This
-kernel deletes that view: the innermost grid axis iterates **logical**
-block indices, each step resolves ``block_table[b, ki]`` from a
-scalar-prefetch argument (``pltpu.PrefetchScalarGridSpec``) and streams
-that *physical* block of the shared pool straight through VMEM.  Blocks
-that are unallocated (``-1``) or entirely outside the row's valid
-``kv_len`` (and, with causal/window masking, outside the query band) are
-skipped before their matmuls issue — decode attention is O(pos) per row,
-not O(max_blocks * block_size).
+a full write + re-read of the logical cache view per call.  This kernel
+deletes that view: the pools stay in HBM (``pl.ANY``), the scalar-
+prefetched block table (``pltpu.PrefetchScalarGridSpec``) maps each
+logical page of a KV tile to its *physical* block of the shared pool, and
+the body copies those blocks into a double-buffered VMEM tile itself, one
+DMA per page, fetching the next visible tile while it computes this one.
 
-Layout: q ``(B, Tq, Hq, hd)`` (viewed head-major inside the wrapper);
+Grid ``(B, Hkv, ceil(Tq/bq), ceil(max_blocks/P))``, the KV-tile axis
+innermost:
+
+* **one step per KV head**, not per query head: the ``g = Hq / Hkv``
+  query heads of a GQA group (query head ``h`` reads KV head ``h // g``,
+  so a group's heads are contiguous) share one ``(g*bq, hd)`` q tile, so
+  a group fetches its K/V pages once instead of ``g`` times.  Row ``r``
+  of the tile is query head ``r // bq`` at position
+  ``q_offset[b] + qi*bq + r % bq``; decode (``Tq == 1``) is a ``(g, hd)``
+  tile.
+* **one step per tile of P pages** (about 512 positions): the q·k tile is
+  ``(g*bq, P*block_size)``, wide enough to keep the MXU busy and to hide
+  the page DMAs behind it.
+
+Tile sizes come from the shapes alone (:func:`paged_tile`).  The visible
+KV tiles of each ``(row, q tile)`` form one span (:func:`_tile_span`): a
+tile wholly past ``kv_len`` (or past the row's last allocated page), or
+wholly outside the causal/window band, copies nothing and skips its
+matmuls; a skipped step costs the grid step alone.  (One BlockSpec per
+page would pay every page operand's pipeline bookkeeping at every step,
+skipped or not: a 512-token chunk at offset 0 took 1.1 ms that way on a
+TPU v5e, and 0.16 ms with the body's own copies.)  Inside a visible tile,
+pages that are unallocated (``-1``) or past ``kv_len`` are masked and
+their V rows zeroed.  Scratch (m, l, acc) carries the online-softmax
+state across tiles, in float32; finalization divides on the last tile.
+
+Layout: q ``(B, Tq, Hq, hd)``, viewed group-major inside the wrapper;
 pools are head-major ``(num_blocks, Hkv, block_size, hd)`` shared by every
-row, so one KV head of one physical block is a ``(block_size, hd)`` tile —
-the last two block dims Mosaic tiles on.  GQA is resolved in the index
-map: query head ``h`` reads KV head ``h // (Hq // Hkv)`` — the pool is
-never head-repeated in HBM.  Grid ``(B, Hq, Tq/bq, max_blocks)`` with the
-block axis innermost;
-scratch (m, l, acc) carries the online-softmax state across blocks;
-finalization divides on the last block.  Masking is by **absolute**
-positions: query row r sits at ``q_offset[b] + qi*bq + r`` and block
-``ki`` covers positions ``[ki*bs, (ki+1)*bs)``, so chunked prefill at a
-cache offset (scalar ``q_offset`` broadcast per row) and vector-position
-decode (per-row ``q_offset``) lower to the same kernel.
-
+row, so one KV head of one physical block is a ``(block_size, hd)`` tile.
 Mosaic tiling: ``block_size`` must be a multiple of the sublane tile of
-the pool dtype (16 for bf16) and ``hd`` a multiple of 128; ``bq`` is 128
-or the whole query count (decode runs bq=1).
+the pool dtype (16 for bf16) and ``hd`` a multiple of 128.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -49,7 +61,14 @@ from repro.kernels.flash_attention import (_NEG, softmax_finalize,
 from repro.kernels.platform import contract_precision, resolve_interpret
 
 __all__ = ["paged_attention_pallas", "paged_kv_scatter_pallas",
-           "paged_kernel_covers"]
+           "paged_kernel_covers", "paged_tile", "paged_tile_walk"]
+
+# Tile targets: rows of a q tile (a group's query heads x positions) and
+# KV positions of a KV tile.  A (1024 x 512) float32 score tile is 2 MiB
+# of VMEM; a large GQA group (g = 48) shrinks the positions per q tile
+# instead of growing the tile.
+_Q_ROWS = 1024
+_KV_TOKENS = 512
 
 
 def paged_kernel_covers(t: int) -> bool:
@@ -61,79 +80,178 @@ def paged_kernel_covers(t: int) -> bool:
     return t % min(128, t) == 0
 
 
-def _block_visible(tab_ref, qoff_ref, kvlen_ref, bi, qi, ki, *,
-                   bq: int, bs: int, causal: bool, window: int):
-    """(physical block id, contributes-anything?) for one grid step.
+def paged_tile(g: int, t: int, block_size: int, max_blocks: int,
+               dtype) -> Tuple[int, int]:
+    """``(bq, pages)``: query positions per q tile and KV pages per KV tile
+    for a ``t``-query call with GQA group ``g``.  The q tile holds
+    ``g * bq`` rows: the whole call when that fits ``_Q_ROWS``, else a
+    power of two of positions, at least the dtype's sublane tile (8 rows
+    of float32, 16 of bfloat16).  A KV tile holds about ``_KV_TOKENS``
+    positions, and never more pages than the table has."""
+    sub = 32 // jnp.dtype(dtype).itemsize
+    if g * t <= _Q_ROWS:
+        bq = t
+    else:
+        bq = min(t, max(sub, 1 << ((_Q_ROWS // g).bit_length() - 1)))
+    return bq, max(1, min(max_blocks, _KV_TOKENS // block_size))
 
-    The SINGLE definition of the block-level walk: an unallocated (-1)
-    table entry, a block entirely past the row's kv_len, or a block
-    entirely outside the causal/window band of this q tile contributes
-    nothing.  Both the kernel body (to skip the matmuls — on TPU Mosaic
-    this prunes the MXU work; decode touches O(pos) rows) and the
-    BlockSpec index map (to skip the DMA) consume this predicate; if they
-    ever disagreed, the body would accumulate a block the pipeline never
-    fetched.
+
+def _tile_span(xp, kv_len, q_lo, *, bq: int, tk: int, causal: bool,
+               window: int):
+    """``(first, last)`` KV tile visible to the q tile whose first query
+    sits at ``q_lo``; ``last < first`` when none is.
+
+    The SINGLE definition of the tile walk: a tile entirely past
+    ``kv_len``, or entirely outside the causal/window band of the q tile,
+    contributes nothing.  The wrapper evaluates it (``xp = jnp``) into the
+    scalar-prefetched span tables the kernel body reads to skip both the
+    page copies and the matmuls, and the serving engine's tile counters
+    replay it on the host (``xp = np``).
     """
-    pb = tab_ref[bi, ki]
-    k_lo = ki * bs
-    vis = (pb >= 0) & (k_lo < kvlen_ref[bi])
-    q_lo = qoff_ref[bi] + qi * bq
+    last = (kv_len + tk - 1) // tk - 1
     if causal:
-        vis = vis & (k_lo <= q_lo + bq - 1)
+        last = xp.minimum(last, (q_lo + bq - 1) // tk)
+    first = 0 * last
     if window > 0:
-        vis = vis & (k_lo + bs - 1 > q_lo - window)
-    return pb, vis
+        first = xp.maximum(q_lo - window + 1, 0) // tk
+    return first, last
 
 
-def _kernel(tab_ref, qoff_ref, kvlen_ref,      # scalar prefetch
-            q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            mb: int, bq: int, bs: int, causal: bool, window: int,
-            scale: float):
+def paged_tile_walk(kv_len: int, q_offset: int, *, t: int, bq: int,
+                    pages: int, block_size: int, max_blocks: int,
+                    causal: bool, window: int = 0) -> Tuple[int, int]:
+    """``(tiles walked, tiles visible)`` of one batch row and one KV head:
+    the kernel's KV-tile walk replayed in plain integers.  ``kv_len`` is
+    the row's effective length, capped at its last allocated page as the
+    wrapper caps it."""
+    tk = pages * block_size
+    nq, nk = -(-t // bq), -(-max_blocks // pages)
+    visible = 0
+    for qi in range(nq):
+        first, last = _tile_span(np, kv_len, q_offset + qi * bq, bq=bq,
+                                 tk=tk, causal=causal, window=window)
+        visible += max(0, int(last) - int(first) + 1)
+    return nq * nk, visible
+
+
+def _page(tab_ref, b, idx, mb: int, pages: int):
+    """Physical block of logical page ``idx`` of row ``b``; -1 past the
+    table (the last tile of a table that is not a whole number of
+    tiles)."""
+    if mb % pages == 0:
+        return tab_ref[b, idx]
+    return jnp.where(idx < mb, tab_ref[b, jnp.minimum(idx, mb - 1)], -1)
+
+
+def _kernel(tab_ref, lo_ref, hi_ref, qoff_ref, kvlen_ref,  # scalar prefetch
+            q_ref, k_hbm, v_hbm,                            # pools in HBM
+            o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, sems, *,
+            pages: int, bq: int, bs: int, mb: int, causal: bool,
+            window: int, scale: float):
     b = pl.program_id(0)
+    h = pl.program_id(1)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    tk = pages * bs
+    lo, hi = lo_ref[b, qi], hi_ref[b, qi]
+    visible = (ki >= lo) & (ki <= hi)
+    slot = ki % 2
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+
+    def page(p):
+        return _page(tab_ref, b, ki * pages + p, mb, pages)
+
+    def fetch(kt, into):
+        """Start the page DMAs of KV tile ``kt`` into buffer ``into``:
+        one per page and pool, an unallocated page reading block 0."""
+        def one(p, carry):
+            pb = jnp.maximum(_page(tab_ref, b, kt * pages + p, mb, pages), 0)
+            for j, (hbm, buf) in enumerate(pools):
+                pltpu.make_async_copy(hbm.at[pb, h], buf.at[into, p],
+                                      sems.at[j, into]).start()
+            return carry
+        jax.lax.fori_loop(0, pages, one, 0)
 
     @pl.when(ki == 0)
     def _init():
         softmax_init(m_ref, l_ref, acc_ref)
 
-    kvl = kvlen_ref[b]
-    q_lo = qoff_ref[b] + qi * bq
-    k_lo = ki * bs
-    _, visible = _block_visible(tab_ref, qoff_ref, kvlen_ref, b, qi, ki,
-                                bq=bq, bs=bs, causal=causal, window=window)
+    # the first visible tile of a walk fetches itself; every visible tile
+    # fetches its successor into the other buffer before it computes
+    @pl.when(visible & (ki == lo))
+    def _first():
+        fetch(ki, slot)
+
+    @pl.when(visible & (ki < hi))
+    def _next():
+        fetch(ki + 1, 1 - slot)
 
     @pl.when(visible)
-    def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32) * scale            # (bq, hd)
-        k = k_ref[0, 0].astype(jnp.float32)                    # (bs, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+    def _visible():
+        def wait(p, carry):
+            for j, (hbm, buf) in enumerate(pools):
+                pltpu.make_async_copy(hbm.at[0, 0], buf.at[slot, p],
+                                      sems.at[j, slot]).wait()
+            return carry
+        jax.lax.fori_loop(0, pages, wait, 0)
 
-        prec = contract_precision(q_ref.dtype, k_ref.dtype)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                    precision=prec)                        # (bq, bs)
-        q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 0)
-        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
-        valid = k_pos < kvl
-        if causal:
-            valid = valid & (k_pos <= q_pos)
-        if window > 0:
-            valid = valid & (k_pos > q_pos - window)
+        kvl = kvlen_ref[b]
+        k_lo = ki * tk
+        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+        # an unallocated page below kv_len is a hole in the table (serving
+        # tables are allocated prefixes, so tiles skip the masking below)
+        holes = jax.lax.fori_loop(0, pages, lambda p, n: n | (
+            (page(p) < 0) & (k_lo + p * bs < kvl)), False)
+
+        def mask_holes(ok):
+            def one(p, ok):
+                @pl.when(page(p) < 0)
+                def _zero_v():      # the rows of block 0, where holes read
+                    v_buf[slot, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+                p_lo = k_lo + p * bs
+                return jnp.where((page(p) < 0) & (k_pos >= p_lo)
+                                 & (k_pos < p_lo + bs), 0, ok)
+            return jax.lax.fori_loop(0, pages, one, ok)
+
+        ok = jax.lax.cond(holes, mask_holes, lambda ok: ok,
+                          jnp.ones((1, tk), jnp.int32))
+        valid = (k_pos < kvl) & (ok > 0)
+
+        q = q_ref[0, 0]                                         # (rows, hd)
+        k = k_buf[slot].reshape(tk, -1)                         # (tk, hd)
+        v = v_buf[slot].reshape(tk, -1).astype(jnp.float32)
+        # bf16 x bf16 products are exact in f32: q.k takes the stored
+        # operands; a float32 operand contracts at full precision
+        prec = contract_precision(q.dtype, k.dtype)
+        if prec is not None:
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=prec) * scale      # (rows, tk)
+        if causal or window > 0:
+            row = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
+            row = row & (bq - 1) if bq & (bq - 1) == 0 else row % bq
+            q_pos = qoff_ref[b] + qi * bq + row                 # (rows, 1)
+            if causal:
+                valid = valid & (k_pos <= q_pos)
+            if window > 0:
+                valid = valid & (k_pos > q_pos - window)
         s = jnp.where(valid, s, _NEG)
         # rows of a partially-filled physical block past kv_len are
         # unwritten pool memory; zero them so a 0-probability column can
         # never propagate NaN/garbage through the p @ v contraction
-        col = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
-        v = jnp.where(k_lo + col < kvl, v, 0.0)
+        col = k_lo + jax.lax.broadcasted_iota(jnp.int32, (tk, 1), 0)
+        v = jnp.where(col < kvl, v, 0.0)
 
-        softmax_update(s, v, m_ref, l_ref, acc_ref, prec)
+        softmax_update(s, v, m_ref, l_ref, acc_ref,
+                       contract_precision(q_ref.dtype, k_buf.dtype))
 
-    @pl.when(ki == mb - 1)
+    @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
         o_ref[0, 0] = softmax_finalize(l_ref, acc_ref, o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
+@functools.partial(jax.jit, static_argnames=("causal", "window",
                                              "interpret"))
 def paged_attention_pallas(
     q: jax.Array,             # (B, Tq, Hq, hd)
@@ -144,61 +262,69 @@ def paged_attention_pallas(
     kv_len: jax.Array,        # (B,) int32 valid KV rows per table row
     causal: bool = True,
     window: int = 0,          # >0 → sliding-window band by absolute pos
-    block_q: int = 128,
     interpret: Optional[bool] = None,  # None → chosen by platform
 ) -> jax.Array:
     b, t, hq, hd = q.shape
     nb, hkv, bs = k_pool.shape[:3]
     mb = block_table.shape[1]
+    # non-divisible heads would make a group read a clamped out-of-range
+    # KV head — plausible wrong outputs, so fail fast
+    assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
-    bq = min(block_q, t)
-    # non-divisible heads would make the index map read a clamped
-    # out-of-range KV head — plausible wrong outputs, so fail fast
-    assert hq % hkv == 0 and t % bq == 0, (hq, hkv, t, bq)
-    scale = hd**-0.5
+    bq, pages = paged_tile(g, t, bs, mb, q.dtype)
+    nq, nk = -(-t // bq), -(-mb // pages)
+    rows = g * bq
 
     tab = block_table.astype(jnp.int32)
     qoff = q_offset.astype(jnp.int32)
-    kvl = kv_len.astype(jnp.int32)
+    # pages past the row's last allocated one hold nothing: capping
+    # kv_len there keeps a free slot's walk (stale position, all -1
+    # table) from visiting any tile
+    n_alloc = jnp.max(jnp.where(tab >= 0, jnp.arange(1, mb + 1), 0), axis=1)
+    kvl = jnp.minimum(kv_len.astype(jnp.int32), n_alloc * bs)
+    lo, hi = _tile_span(jnp, kvl[:, None],
+                        qoff[:, None] + bq * jnp.arange(nq)[None, :],
+                        bq=bq, tk=pages * bs, causal=causal, window=window)
+    lo = jnp.broadcast_to(lo, (b, nq)).astype(jnp.int32)
+    hi = jnp.broadcast_to(hi, (b, nq)).astype(jnp.int32)
 
-    def k_index(bi, h, qi, ki, tab_ref, qoff_ref, kvlen_ref):
-        # physical block for this logical step.  Steps the kernel body will
-        # skip (same ``_block_visible`` predicate) resolve to the row's
-        # FIRST block instead of their own: consecutive skipped steps then
-        # map to an unchanged index, so the pipeline's refetch elision
-        # issues no DMA for them and attention traffic is O(kv_len) rows,
-        # not O(allocated blocks) (clipped to 0 for fully-empty rows).
-        pb, vis = _block_visible(tab_ref, qoff_ref, kvlen_ref, bi, qi, ki,
-                                 bq=bq, bs=bs, causal=causal, window=window)
-        pb = jnp.where(vis, pb, tab_ref[bi, 0])
-        return (jnp.maximum(pb, 0), h // g, 0, 0)
+    # group-major q: row r of tile (bi, h, qi) is query head h*g + r // bq
+    # at position qi*bq + r % bq
+    if nq * bq != t:
+        q = jnp.pad(q, ((0, 0), (0, nq * bq - t), (0, 0), (0, 0)))
+    qg = (q.reshape(b, nq, bq, hkv, g, hd).transpose(0, 3, 1, 4, 2, 5)
+          .reshape(b, hkv, nq * rows, hd))
 
     def q_index(bi, h, qi, ki, *_):
         return (bi, h, qi, 0)
 
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, hq, t // bq, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, hd), q_index),
-            pl.BlockSpec((1, 1, bs, hd), k_index),
-            pl.BlockSpec((1, 1, bs, hd), k_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd), q_index),
+        num_scalar_prefetch=5,
+        grid=(b, hkv, nq, nk),
+        in_specs=[pl.BlockSpec((1, 1, rows, hd), q_index),
+                  pool_spec, pool_spec],
+        out_specs=pl.BlockSpec((1, 1, rows, hd), q_index),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
+            pltpu.VMEM((rows,), jnp.float32),
+            pltpu.VMEM((rows,), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32),
+            pltpu.VMEM((2, pages, bs, hd), k_pool.dtype),
+            pltpu.VMEM((2, pages, bs, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, mb=mb, bq=bq, bs=bs, causal=causal,
-                          window=window, scale=scale),
+        functools.partial(_kernel, pages=pages, bq=bq, bs=bs, mb=mb,
+                          causal=causal, window=window, scale=hd**-0.5),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, t, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, nq * rows, hd), q.dtype),
         interpret=resolve_interpret(interpret),
-    )(tab, qoff, kvl, q.transpose(0, 2, 1, 3), k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3)
+        name="paged_attention_pallas",
+    )(tab, lo, hi, qoff, kvl, qg, k_pool, v_pool)
+    out = (out.reshape(b, hkv, nq, g, bq, hd).transpose(0, 2, 4, 1, 3, 5)
+           .reshape(b, nq * bq, hq, hd))
+    return out[:, :t]
 
 
 def _scatter_visible(tab_ref, pos_ref, len_ref, bi, ci, *, bs: int, mb: int):

@@ -393,7 +393,6 @@ def paged_attention(
             def _kern(q_, kp_, vp_, bt_, qo_, kvl_):
                 return paged_attention_pallas(q_, kp_, vp_, bt_, qo_, kvl_,
                                               causal=causal,
-                                              block_q=min(128, T),
                                               interpret=interpret)
 
             # tensor parallelism (distributed/tp.py): under an active TP
@@ -406,7 +405,6 @@ def paged_attention(
                 return out
             return paged_attention_pallas(q, k_pool, v_pool, block_table,
                                           qo, kvl, causal=causal,
-                                          block_q=min(128, T),
                                           interpret=interpret)
     k = gather_kv_blocks(k_pool, block_table)
     v = gather_kv_blocks(v_pool, block_table)

@@ -68,20 +68,22 @@ import dataclasses
 import os
 import time
 import warnings
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.policy import DENSE, SparsityPolicy
+from repro.kernels.paged_attention import paged_tile, paged_tile_walk
 from repro.serve import faults as fault_mod
 from repro.serve import tracing
 from repro.serve.executor import Executor
 from repro.serve.faults import EngineCrash, FaultInjector
 from repro.serve.metrics import (LifecycleMetrics, MetricsSnapshot,
                                  PagedMetrics, RequestMetrics)
-from repro.serve.paged import chain_block_hashes, chain_block_keys
+from repro.serve.paged import (chain_block_hashes, chain_block_keys,
+                               max_blocks_per_slot)
 from repro.serve.scheduler import (CANCELLED, DECODE, DONE, PREFILL,
                                    REJECTED, TERMINAL, TIMED_OUT, WAITING,
                                    Request, Scheduler, StepPlan,
@@ -199,6 +201,8 @@ class ContinuousServingEngine:
         self.fused_step = (env != "0") if env is not None else cfg.fused_step
         self.work_iterations = 0  # iterations that dispatched any program
         self.restores = 0         # times restore() rebuilt this engine
+        # paged-attention kernel tiles (walked, visible), per attention layer
+        self.attention_tiles = [0, 0]
         self.metrics: Dict[str, Any] = {}
         self.metrics_snapshot: Optional[MetricsSnapshot] = None
         self._key = None                       # sampling PRNG (run-owned)
@@ -336,6 +340,57 @@ class ContinuousServingEngine:
             raise EngineCrash(f"injected crash in {site} (it={it})")
         return float("nan") if kind == "nonfinite" else 0.0
 
+    def _attention_tile(self, t: int) -> Tuple[int, int]:
+        """The paged-attention kernel's ``(bq, pages)`` for a ``t``-query
+        call of this engine's model and pool."""
+        mcfg, bs = self.model.cfg, self.cfg.block_size
+        return paged_tile(mcfg.n_heads // mcfg.n_kv_heads, t, bs,
+                          max_blocks_per_slot(self.cfg.max_seq, bs),
+                          mcfg.dtype)
+
+    def _chunk_tile(self) -> Tuple[int, int]:
+        """``paged.attention_tile``: (q rows, pages) of the kernel's tile
+        for a full prefill chunk; (0, 0) with the kernel off."""
+        if not self.exec.paged_kernel:
+            return (0, 0)
+        mcfg = self.model.cfg
+        bq, pages = self._attention_tile(self.cfg.chunk_size)
+        return (mcfg.n_heads // mcfg.n_kv_heads * bq, pages)
+
+    def _count_attention_tiles(self, plan: StepPlan) -> None:
+        """Add one dispatch of ``plan`` to ``paged.attention_tiles_*``: the
+        kernel's KV-tile walk replayed from the plan's positions and block
+        counts (host integers, no device read).  Decode rows see a
+        prefill chunk of the same dispatch as already written."""
+        if not self.exec.paged_kernel:
+            return
+        bs = self.cfg.block_size
+        mb = max_blocks_per_slot(self.cfg.max_seq, bs)
+
+        def walk(t, causal, q_off, kv_len, n_blocks):
+            bq, pages = self._attention_tile(t)
+            return paged_tile_walk(min(kv_len, n_blocks * bs), q_off, t=t,
+                                   bq=bq, pages=pages, block_size=bs,
+                                   max_blocks=mb, causal=causal)
+
+        pw, rows = plan.prefill, []
+        if pw is not None:
+            r = pw.req
+            rows.append(walk(pw.tokens.shape[1], True, r.kv_len,
+                             r.kv_len + pw.chunk_len, len(r.blocks)))
+        if plan.decode is not None:
+            for r in self.sched.slot_requests:
+                if r is None:      # free slot: an all -1 table row
+                    rows.append(walk(1, False, 0, 0, 0))
+                    continue
+                pos = r.kv_len + (pw.chunk_len if pw is not None
+                                  and pw.req is r else 0)
+                rows.append(walk(1, False, pos, pos + 1, len(r.blocks)))
+        hkv = self.model.cfg.n_kv_heads
+        for walked, visible in rows:
+            self.attention_tiles[0] += walked * hkv
+            self.attention_tiles[1] += visible * hkv
+
     def _step_fused(self, params, extras: Dict[int, Dict], it: int,
                     plan: StepPlan) -> bool:
         """One-dispatch iteration: the scheduler's fused plan runs as a
@@ -370,6 +425,7 @@ class ContinuousServingEngine:
         pw = plan.prefill
         ex = extras.get(pw.req.rid, {}) if pw is not None and pw.first else {}
         res = self.exec.step(params, plan, ex, pkey, dkey, fault)
+        self._count_attention_tiles(plan)
         with tracing.span(tracing.COMMIT):
             if pw is not None:
                 self.sched.commit_chunk(pw.req, pw.chunk_len)
@@ -399,6 +455,7 @@ class ContinuousServingEngine:
         fault = jnp.float32(self._crash_fire("prefill", it))
         ex = extras.get(pw.req.rid, {}) if pw.first else {}
         logits = self.exec.prefill(params, plan, ex, fault)
+        self._count_attention_tiles(plan)
         self.sched.commit_chunk(pw.req, pw.chunk_len)
         if self.sched.seq_complete(pw.req):   # seq ingested: sample
             tok = self.exec.sample_token(logits, sub)
@@ -418,6 +475,7 @@ class ContinuousServingEngine:
         self.exec.apply_effects(plan)
         fault = jnp.float32(self._crash_fire("decode", it))
         nxt = self.exec.decode(params, plan, sub, fault)
+        self._count_attention_tiles(plan)
         with tracing.span(tracing.COMMIT):
             self.sched.emit_decode_tokens(plan.decode, nxt, it)
         return True
@@ -450,6 +508,7 @@ class ContinuousServingEngine:
         wdog0, timeout0 = sched.watchdog_trips, sched.timeouts
         cancel0 = sched.cancellations
         disp0, work0 = ex.dispatches, self.work_iterations
+        tiles0 = list(self.attention_tiles)
         if self.paged:
             sched.pool.peak_in_use = sched.pool.in_use   # per-run peak
             evict0 = sched.pool.evictions
@@ -547,6 +606,11 @@ class ContinuousServingEngine:
                     prefill_tokens=sched.prefill_demand - demand0,
                     cached_blocks=sched.pool.cached_blocks,
                     evictions=sched.pool.evictions - evict0,
+                    attention_tiles_walked=(self.attention_tiles[0]
+                                            - tiles0[0]),
+                    attention_tiles_visible=(self.attention_tiles[1]
+                                             - tiles0[1]),
+                    attention_tile=self._chunk_tile(),
                 ) if self.paged else PagedMetrics(enabled=False)),
                 requests=[RequestMetrics(
                     rid=r.rid,

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["SCHEMA_VERSION", "LifecycleMetrics", "PagedMetrics",
            "RequestMetrics", "MetricsSnapshot"]
@@ -53,6 +53,12 @@ class PagedMetrics:
     prefill_tokens: int = 0
     cached_blocks: int = 0
     evictions: int = 0
+    # the paged-attention kernel's KV-tile walk, per attention layer:
+    # grid steps walked, steps that ran (the rest skipped by the tile
+    # predicate), and the prefill chunk's (q rows, pages) tile
+    attention_tiles_walked: int = 0
+    attention_tiles_visible: int = 0
+    attention_tile: Tuple[int, int] = (0, 0)
 
     def to_dict(self) -> Dict[str, Any]:
         # legacy shape: a paging-disabled engine reported the bare
@@ -194,6 +200,11 @@ class MetricsSnapshot:
                 prefill_tokens=sum(p.prefill_tokens for p in paged_parts),
                 cached_blocks=sum(p.cached_blocks for p in paged_parts),
                 evictions=sum(p.evictions for p in paged_parts),
+                attention_tiles_walked=sum(p.attention_tiles_walked
+                                           for p in paged_parts),
+                attention_tiles_visible=sum(p.attention_tiles_visible
+                                            for p in paged_parts),
+                attention_tile=paged_parts[0].attention_tile,
             )
         else:
             paged = PagedMetrics(enabled=False)

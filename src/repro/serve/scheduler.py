@@ -613,6 +613,11 @@ class Scheduler:
             toks[r.slot], act[r.slot] = r.cur, True
         return DecodeWork(decoding, toks, act)
 
+    @property
+    def slot_requests(self) -> List[Optional[Request]]:
+        """The request holding each slot, None where the slot is free."""
+        return list(self._slot_req)
+
     def plan_step(self) -> StepPlan:
         """Fused-path plan: the active request's prefill chunk AND the
         frozen decode roster, as one step-program dispatch."""

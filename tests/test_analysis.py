@@ -189,6 +189,7 @@ def test_findings_json_schema():
     ("race_write_write", "aliased-raw"),
     ("race_oob_index", "oob"),
     ("race_discontiguous", "out-revisit"),
+    ("race_dma_oob", "dma-oob"),
 ])
 def test_cli_flags_seeded_grid_race(tmp_path, fixture, kind):
     """Each seeded racy grid yields EXACTLY ONE structured finding of

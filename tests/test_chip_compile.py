@@ -26,6 +26,8 @@ import chip_smoke  # noqa: E402
 T, D, N, HQ, HKV, HD, BS = 2048, 3584, 18944, 28, 4, 128, 16
 SLOTS, MAX_SEQ = 8, 8192
 NB, MB = SLOTS * MAX_SEQ // BS + 1, MAX_SEQ // BS
+CELL_SLOTS = 4
+CELL_NB = CELL_SLOTS * MB + 1
 
 
 @pytest.fixture(scope="module")
@@ -96,18 +98,44 @@ _CASES = {
         False),
     "paged_attention_prefill": (
         lambda q, kp, vp, tab, qo, kl: paged_attention_pallas(
-            q, kp, vp, tab, qo, kl, causal=True, block_q=128,
-            interpret=False),
+            q, kp, vp, tab, qo, kl, causal=True, interpret=False),
         [((1, 512, HQ, HD), bf), ((NB, HKV, BS, HD), bf),
          ((NB, HKV, BS, HD), bf), ((1, MB), i32), ((1,), i32), ((1,), i32)],
         False),
     "paged_attention_decode": (
         lambda q, kp, vp, tab, qo, kl: paged_attention_pallas(
-            q, kp, vp, tab, qo, kl, causal=False, block_q=1,
-            interpret=False),
+            q, kp, vp, tab, qo, kl, causal=False, interpret=False),
         [((SLOTS, 1, HQ, HD), bf), ((NB, HKV, BS, HD), bf),
          ((NB, HKV, BS, HD), bf), ((SLOTS, MB), i32), ((SLOTS,), i32),
          ((SLOTS,), i32)], False),
+    # the benchmark cell's engine: 4 slots of 8192 positions (2049 pool
+    # rows), a 512-token chunk and the 4-row decode
+    "paged_attention_prefill_cell": (
+        lambda q, kp, vp, tab, qo, kl: paged_attention_pallas(
+            q, kp, vp, tab, qo, kl, causal=True, interpret=False),
+        [((1, 512, HQ, HD), bf), ((CELL_NB, HKV, BS, HD), bf),
+         ((CELL_NB, HKV, BS, HD), bf), ((1, MB), i32), ((1,), i32),
+         ((1,), i32)], False),
+    "paged_attention_decode_cell": (
+        lambda q, kp, vp, tab, qo, kl: paged_attention_pallas(
+            q, kp, vp, tab, qo, kl, causal=False, interpret=False),
+        [((CELL_SLOTS, 1, HQ, HD), bf), ((CELL_NB, HKV, BS, HD), bf),
+         ((CELL_NB, HKV, BS, HD), bf), ((CELL_SLOTS, MB), i32),
+         ((CELL_SLOTS,), i32), ((CELL_SLOTS,), i32)], False),
+    # multi-query attention, one KV head for 48 query heads (granite_34b):
+    # the group shrinks the q tile's positions, not its rows
+    "paged_attention_prefill_g48": (
+        lambda q, kp, vp, tab, qo, kl: paged_attention_pallas(
+            q, kp, vp, tab, qo, kl, causal=True, interpret=False),
+        [((1, 512, 48, HD), bf), ((CELL_NB, 1, BS, HD), bf),
+         ((CELL_NB, 1, BS, HD), bf), ((1, MB), i32), ((1,), i32),
+         ((1,), i32)], False),
+    "paged_attention_decode_g48": (
+        lambda q, kp, vp, tab, qo, kl: paged_attention_pallas(
+            q, kp, vp, tab, qo, kl, causal=False, interpret=False),
+        [((CELL_SLOTS, 1, 48, HD), bf), ((CELL_NB, 1, BS, HD), bf),
+         ((CELL_NB, 1, BS, HD), bf), ((CELL_SLOTS, MB), i32),
+         ((CELL_SLOTS,), i32), ((CELL_SLOTS,), i32)], False),
     "paged_kv_scatter_prefill": (
         lambda kn, vn, kp, vp, tab, p, c: paged_kv_scatter_pallas(
             kn, vn, kp, vp, tab, p, c, interpret=False),
@@ -120,8 +148,7 @@ _CASES = {
         [((512, D), f32), ((D, D), f32), ((D,), f32)], True),
     "paged_attention_prefill_f32": (
         lambda q, kp, vp, tab, qo, kl: paged_attention_pallas(
-            q, kp, vp, tab, qo, kl, causal=True, block_q=128,
-            interpret=False),
+            q, kp, vp, tab, qo, kl, causal=True, interpret=False),
         [((1, 512, HQ, HD), f32), ((NB, HKV, BS, HD), f32),
          ((NB, HKV, BS, HD), f32), ((1, MB), i32), ((1,), i32), ((1,), i32)],
         False),

@@ -299,6 +299,32 @@ def test_submit_rejects_over_pool_capacity(tiny):
         eng.submit(np.arange(10, dtype=np.int32), max_new_tokens=10)
 
 
+# ----------------------------------------- paged-attention tile counters
+
+@pytest.mark.parametrize("attn_kernel", [False, True],
+                         ids=["gather-oracle", "pallas-kernel"])
+def test_attention_tile_counters(tiny, attn_kernel):
+    """``paged.attention_tiles_*`` replay the kernel's KV-tile walk per
+    attention layer: at max_seq 64 and block 8 a row is one tile of 8
+    pages, and the smoke model has 2 KV heads.  One 20-token request,
+    chunk 16, 3 tokens out, 2 slots: two prefill dispatches (one visible
+    tile each), then two decode dispatches walking both slots, of which
+    only the live one is visible.  Zero with the gather oracle."""
+    cfg, model, params = tiny
+    policy = DENSE.with_(use_pallas_kernels=True) if attn_kernel else DENSE
+    eng, res = _serve(model, params, policy, _prompts(cfg, [20]), [0], [3],
+                      num_slots=2, chunk_size=16, block_size=8)
+    pg = res["metrics"]["paged"]
+    hkv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    if attn_kernel:
+        assert (pg["attention_tiles_walked"],
+                pg["attention_tiles_visible"]) == (6 * hkv, 4 * hkv)
+        assert tuple(pg["attention_tile"]) == (g * 16, 8)
+    else:
+        assert (pg["attention_tiles_walked"], pg["attention_tiles_visible"],
+                tuple(pg["attention_tile"])) == (0, 0, (0, 0))
+
+
 # ------------------------------------------- unallocated-block fencing
 
 def test_unallocated_block_fence_survives_poison():
