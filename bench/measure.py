@@ -16,6 +16,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from bench import sizes
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
@@ -94,7 +96,7 @@ class Run:
         (``bench/work/paged_attention.py``)."""
         c = self.conf
         hq, hkv = c["num_attention_heads"], c["num_key_value_heads"]
-        hd = c["hidden_size"] // hq
+        hd = sizes.head_dim(c)
         block = self.mix["engine"]["block_size"]
         fn = work("paged_attention")
         per_layer = sum(self.min_time(*fn(item[1], item[2], hq, hkv, hd,

@@ -40,7 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench import weights
+from bench import sizes, weights
 
 ROW_BLOCK = 1024            # rows per block of the MLP and of attention
 
@@ -49,16 +49,15 @@ def layout(conf: dict) -> dict:
     """Path -> shape of every weight the reference reads, in the served
     program's parameter layout (stacked over layers)."""
     d, f, v = conf["hidden_size"], conf["intermediate_size"], conf["vocab_size"]
-    hq, hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd, n = d // hq, conf["num_hidden_layers"]
+    qd, kvd = sizes.q_dim(conf), sizes.kv_dim(conf)
+    n = conf["num_hidden_layers"]
     p = "periods/b0/"
     out = {"embed/w": (v, d), "final_norm/w": (d,), "lm_head/w": (d, v),
            p + "ln1/w": (n, d), p + "ln2/w": (n, d),
-           p + "o_proj/w": (n, hq * hd, d),
+           p + "o_proj/w": (n, qd, d),
            p + "mlp/gate_proj/w": (n, d, f), p + "mlp/up_proj/w": (n, d, f),
            p + "mlp/down_proj/w": (n, f, d)}
-    for name, width in (("q_proj", hq * hd), ("k_proj", hkv * hd),
-                        ("v_proj", hkv * hd)):
+    for name, width in (("q_proj", qd), ("k_proj", kvd), ("v_proj", kvd)):
         out[p + name + "/w"] = (n, d, width)
         out[p + name + "/b"] = (n, width)
     return out
@@ -119,13 +118,8 @@ def robust_scale(w) -> jax.Array:
 
 def pruned(conf: dict):
     """(layer, module) pairs Amber-P prunes in prefill."""
-    skip = set(conf["amber"]["qgate_skip_layers"])
-    out = []
-    for i in range(conf["num_hidden_layers"]):
-        if i not in skip:
-            out += [(i, "q_proj"), (i, "mlp/gate_proj")]
-        out.append((i, "mlp/down_proj"))
-    return out
+    return [(i, p.name) for i in range(conf["num_hidden_layers"])
+            for p in sizes.projections(conf, i) if p.pruned]
 
 
 def scales(conf: dict, w: dict) -> dict:
@@ -199,7 +193,7 @@ def _attention(q, k, v):
 
 def _layer_fn(conf: dict, control: bool):
     hq, hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd = conf["hidden_size"] // hq
+    hd = sizes.head_dim(conf)
     eps, theta = conf["rms_norm_eps"], conf["rope_theta"]
     n, m = conf["amber"]["n"], conf["amber"]["m"]
     store = _storer(conf)

@@ -35,10 +35,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 CACHE = os.path.join(HERE, ".out", "jax_cache")
 TRACE_DIR = os.path.join(HERE, ".out", "trace")
+# the checkout's root holds the harness, its src/ the program
+sys.path[:0] = [p for p in (ROOT, os.path.join(ROOT, "src"))
+                if p not in sys.path]
 
-
-class BenchError(RuntimeError):
-    """The run cannot produce a result."""
+from bench.errors import BenchError  # noqa: E402
 
 
 def _json(path: str) -> dict:
@@ -256,8 +257,6 @@ def main(argv=None) -> int:
                     help="also read the numbers of the int8 control on the "
                     "same served tokens (stderr; for setting limits)")
     args = ap.parse_args(argv)
-    sys.path.insert(0, ROOT)
-    sys.path.insert(1, os.path.join(ROOT, "src"))
     try:
         res = resolve(args.workload)
         jax = setup_jax()

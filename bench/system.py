@@ -1,26 +1,74 @@
 """The system under test, built from a configuration file: the program's
 model at the file's sizes, its Amber policy, the seeded weights with the
 program's own offline Amber scales, and a serving engine through
-``repro.serve.api`` shaped as the traffic mix says."""
+``repro.serve.api`` shaped as the traffic mix says.
+
+The configuration file is the one statement of the model's shape.  Every
+key it states is one of four kinds, and ``model_config`` raises
+``BenchError`` naming any key that is none of them, so a width the file
+states is never dropped for the program's default:
+
+* applied: set on the program's ``ModelConfig`` as stated (``APPLIED``);
+  the head width and the routed experts through ``bench/sizes.py``, so an
+  unstated head width is ``hidden_size // num_attention_heads`` and
+  unstated experts are none;
+* checked: the program has no setting for it, and the file's value must be
+  what the program does (``checked``): ``rms_norm_eps`` the program's
+  RMSNorm epsilon, ``norm_topk_prob`` true (the gates are a softmax over
+  the top-k logits), ``decoder_sparse_step`` 1 and ``mlp_only_layers`` []
+  (every layer of an expert model routes);
+* metadata, which the program never sees: ``source``, ``program_arch``,
+  ``reference``, ``reduced``, ``published_*``, ``amber`` (read by
+  ``policy``), ``deployment``, ``assumed``;
+* refused: any other key.
+
+What no key of a published config carries (the attention kind, norms
+inside attention, the RoPE variant) comes from the program's config for
+``program_arch``.
+"""
 from __future__ import annotations
 
 import dataclasses
 
 import jax
 
-from bench import weights
+from bench import sizes, weights
+from bench.errors import BenchError
 
 # configuration-file key -> the program's ModelConfig field
-_SIZES = {
+APPLIED = {
     "hidden_size": "d_model",
     "intermediate_size": "d_ff",
     "num_attention_heads": "n_heads",
     "num_key_value_heads": "n_kv_heads",
     "num_hidden_layers": "n_layers",
+    "head_dim": "head_dim",
     "vocab_size": "vocab_size",
     "rope_theta": "rope_theta",
     "attention_bias": "qkv_bias",
+    "num_experts": "n_experts",
+    "num_experts_per_tok": "top_k",
+    "moe_intermediate_size": "moe_d_ff",
+    "hidden_act": "act_fn",
+    "tie_word_embeddings": "tie_embeddings",
+    "torch_dtype": "dtype",
 }
+# published activation name -> the program's (its gelu is the tanh form)
+ACTIVATIONS = {"silu": "silu", "gelu_pytorch_tanh": "gelu"}
+METADATA = ("source", "program_arch", "reference", "reduced", "amber",
+            "deployment", "assumed")
+
+
+def checked(cfg) -> dict:
+    """Each key the program has no setting for -> the value that states
+    what the program does."""
+    import inspect
+
+    from repro.models.common import rms_norm
+    eps = inspect.signature(rms_norm).parameters["eps"].default
+    return {"rms_norm_eps": eps if cfg.norm == "rmsnorm" else None,
+            "norm_topk_prob": True, "decoder_sparse_step": 1,
+            "mlp_only_layers": []}
 
 
 def model_config(conf: dict):
@@ -28,11 +76,28 @@ def model_config(conf: dict):
     states; the file's values win over the program's."""
     from repro.configs.base import get_config
     cfg = get_config(conf["program_arch"])
-    sizes = {field: conf[key] for key, field in _SIZES.items()}
-    amber = conf["amber"]
+    does = checked(cfg)
+    for key, value in conf.items():
+        if key in does and value != does[key]:
+            raise BenchError(f"{key}: the file states {value!r}, the "
+                             f"program does {does[key]!r}")
+        if not (key in does or key in APPLIED or key in METADATA
+                or key.startswith("published_")):
+            raise BenchError(f"{key}: the benchmark neither applies nor "
+                             "checks this configuration key")
+    fields = {field: conf[key] for key, field in APPLIED.items()
+              if key in conf}
+    if "hidden_act" in conf:
+        if conf["hidden_act"] not in ACTIVATIONS:
+            raise BenchError(f"hidden_act: the program has no "
+                             f"{conf['hidden_act']!r}")
+        fields["act_fn"] = ACTIVATIONS[conf["hidden_act"]]
+    e = sizes.experts(conf) or sizes.Experts(0, 0, 0)
+    fields.update(head_dim=sizes.head_dim(conf), n_experts=e.count,
+                  top_k=e.top_k, moe_d_ff=e.width)
     return dataclasses.replace(
-        cfg, head_dim=0, dtype=conf["torch_dtype"],
-        qgate_skip_layers=tuple(amber["qgate_skip_layers"]), **sizes)
+        cfg, qgate_skip_layers=tuple(conf["amber"]["qgate_skip_layers"]),
+        **fields)
 
 
 def policy(conf: dict):

@@ -7,7 +7,8 @@ touching the program's arrays.
 
 Rule by path (the program's parameter layout, joined with ``/``):
 
-* ``.../ln1/w``, ``.../ln2/w``, ``final_norm/w``: ones;
+* ``.../ln1/w``, ``.../ln2/w`` and any ``.../<name>_norm/w`` (``final_norm``,
+  a per-head ``q_norm``): ones;
 * ``embed/w``: N(0, 0.02^2);
 * ``.../b``: N(0, 0.02^2), so the fused bias epilogues carry real values;
 * any other ``w``: N(0, 1/d_in), with ``d_in = shape[-2]``.
@@ -19,7 +20,7 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-NORMS = ("ln1", "ln2", "final_norm")
+NORMS = ("ln1", "ln2")
 
 
 def base_key(seed: int) -> jax.Array:
@@ -32,7 +33,8 @@ def base_key(seed: int) -> jax.Array:
 def leaf(key: jax.Array, path: str, shape, dtype) -> jax.Array:
     """The value of the leaf at ``path`` (traceable)."""
     parts = path.split("/")
-    if parts[-1] == "w" and len(parts) >= 2 and parts[-2] in NORMS:
+    if parts[-1] == "w" and len(parts) >= 2 and (
+            parts[-2] in NORMS or parts[-2].endswith("_norm")):
         return jnp.ones(shape, dtype)
     k = jax.random.fold_in(key, zlib.crc32(path.encode()))
     if path == "embed/w" or parts[-1] == "b":

@@ -6,35 +6,36 @@ offset, final)`` for a prompt chunk of ``q`` tokens at ``offset`` (``final``
 when it completes the prompt, whose last row alone needs the LM head), and
 ``("decode", 1, kv_len)`` for one decoded row attending ``kv_len``
 positions.  Prefill rows are pruned as the configuration's Amber policy
-says; decode rows run dense.
+says (``bench/sizes.py``); decode rows run dense.  A token goes through
+the router dense and through ``top_k`` routed experts.
 """
+from bench import sizes
 from bench.work import paged_attention
 
 
 def _linear(conf: dict, prefill: bool) -> float:
-    d, f = conf["hidden_size"], conf["intermediate_size"]
-    hq, hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd = d // hq
+    """Operations of every projection of every layer, for one row."""
     a = conf["amber"]
     credit = a["n"] / a["m"] if prefill else 1.0
+    e = sizes.experts(conf)
     total = 0.0
     for i in range(conf["num_hidden_layers"]):
-        qg = credit if i not in a["qgate_skip_layers"] else 1.0
-        total += 2 * d * hq * hd * qg + 2 * 2 * d * hkv * hd + 2 * hq * hd * d
-        total += 2 * d * f * qg + 2 * d * f + 2 * f * d * credit
+        for p in sizes.projections(conf, i):
+            rows = e.top_k if p.routed else 1
+            total += 2 * p.d_in * p.d_out * rows * (credit if p.pruned
+                                                    else 1.0)
     return total
 
 
 def work(conf: dict, items) -> float:
-    d, hq = conf["hidden_size"], conf["num_attention_heads"]
-    hd, n = d // hq, conf["num_hidden_layers"]
-    head = 2 * d * conf["vocab_size"]
+    attn = conf["num_hidden_layers"] * 4 * sizes.q_dim(conf)
+    head = 2 * conf["hidden_size"] * conf["vocab_size"]
     total = 0.0
     for item in items:
         q, off = item[1], item[2]
         prefill = item[0] == "prefill"
         total += q * _linear(conf, prefill)
-        total += n * 4 * hd * hq * paged_attention.visible(q, off)
+        total += attn * paged_attention.visible(q, off)
         if not prefill or item[3]:
             total += head
     return total
