@@ -216,6 +216,15 @@ def _zoo(cfg, chunk, decode_slots, max_seq, block_size):
             S((chunk, d), jnp.int8), wq, act, w_scale)),
     ]
 
+    # routed experts: a chunk's expert-sorted rows over the config's
+    # experts (8 groups of n_out for a dense model)
+    groups, width = cfg.n_experts or 8, cfg.moe_d_ff or n_out
+    rows = chunk * max(cfg.top_k, 1)
+    entries.append(("nm_grouped_matmul", lambda: estimate_call(
+        lambda x_, w_, g_: ops.nm_grouped_matmul(x_, w_, g_, n, m),
+        S((rows, d), jnp.float32), S((groups, d, width), jnp.float32),
+        S((groups,), jnp.int32))))
+
     # attention kernels: one batch row of a 1024-token self-attn tile is
     # representative — block sizes are clamped at 128 so longer sequences
     # only grow the grid, never the VMEM working set
@@ -347,6 +356,18 @@ def grid_zoo_entries(cfg) -> List[GridZooEntry]:
             (jnp.zeros((t, d), jnp.int8), wq, act, vec_o),
             dict(mm, bk=bk8)),
     ]
+
+    # routed experts: 48 rows in 3 row tiles over 9 groups (empty ones
+    # first, between and last; boundaries inside tiles)
+    sizes = [0, 5, 0, 12, 3, 0, 10, 18, 0]
+    entries.append(GridZooEntry(
+        "nm_grouped_matmul",
+        lambda x_, w_, g_: ops.nm_grouped_matmul(
+            x_, w_, g_, n, m, block_t=bt, block_o=bo, block_k=bk),
+        (jnp.zeros((48, d), jnp.float32),
+         jnp.zeros((len(sizes), d, n_out), jnp.float32),
+         jnp.asarray(sizes, jnp.int32)),
+        dict(mm, rows=48, sizes=sizes)))
 
     t_attn, ba = 64, 16
     q4 = jnp.zeros((1, hq, t_attn, hd), jnp.float32)

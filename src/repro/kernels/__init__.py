@@ -5,6 +5,14 @@ VMEM/registers, never HBM):
 
   nm_prune         — fused scoring + per-token N:M top-k + mask
   nm_prune_matmul  — fused prune + GEMM (the per-token projection itself)
+  nm_grouped_matmul — routed experts: grouped GEMM over expert-sorted
+                     rows with the per-token N:M prune (plain |x|) fused
+                     onto each row tile; scalar-prefetched group
+                     metadata picks each step's row tile and expert
+                     weight tile (megablox ``gmm``'s design), static
+                     ``prune`` (the dense up projection and decode run
+                     it too); reads one layer of a scan-stacked weight
+                     in place
   nm_spmm          — tile-consensus compacted matmul (TPU-native SpMM),
                      k-blocked over D with an f32 accumulator scratch
   osparse_matmul   — Outstanding-sparse chain: smooth-divide → prune →
@@ -65,6 +73,7 @@ overlaps.
 
 --- HBM bytes per call (generated from COST_MODEL; do not edit) ---
   flash_attention      s*(2*B*H*T*hd + 2*B*H*(T/bq)*S*hd)
+  nm_grouped_matmul    s*(bt*bk*runs(x) + bk*bo*runs(w) + bt*bo*runs(out))
   nm_prune             s*(2*T*D + I*D)
   nm_prune_matmul      s*(J*T*D + I*D*N + I*J*D + I*N + T*N)
   nm_spmm              s*(J*T*D + I*D*N + I*J*D + T*N)
@@ -77,7 +86,9 @@ overlaps.
 
 Symbols: T tokens, D in-features, N out-features, s dtype bytes (f32:
 4); grid extents I = T/bt, J = N/bo, K = D/bk (K-refetch of X/W blocks
-is why J·T·D and I·D·N appear, not T·D and D·N); attention B, H, hd,
+is why J·T·D and I·D·N appear, not T·D and D·N); grouped: bt/bk/bo
+its row, k and output tiles, ``runs(·)`` over its (group, row tile)
+walk; attention B, H, hd,
 query tile bq, KV length S; paged r = Hkv·hd, P pages of bs rows per
 KV tile, T padded to whole q tiles.  The paged formulas replay the
 documented table/visibility contract over the real block table:
@@ -104,6 +115,7 @@ scalar-prefetched block tables:
 
   kernel               VMEM       SMEM     worst config, grid
   flash_attention       1.13 MiB     0 B   recurrentgemma_2b (1,10,8,8)
+  nm_grouped_matmul    14.50 MiB   112 B   granite_34b       (12,9,12)
   nm_prune              2.00 MiB     0 B   llama31_8b        (1,8)
   nm_prune_matmul       2.76 MiB     0 B   llama31_8b        (1,56,8)
   nm_spmm               2.75 MiB     0 B   llama31_8b        (1,56,8)
@@ -124,6 +136,7 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import (paged_attention_pallas,
                                            paged_kv_scatter_pallas)
 from repro.kernels.ops import (
+    nm_grouped_matmul,
     nm_prune,
     nm_prune_matmul,
     nm_spmm,
@@ -132,6 +145,7 @@ from repro.kernels.ops import (
 )
 
 __all__ = [
+    "nm_grouped_matmul",
     "nm_prune",
     "nm_prune_matmul",
     "nm_spmm",
@@ -220,6 +234,38 @@ def _w8a8_matmul_bytes(d):
     return j * t * dd + i * dd * n + s * (i * n + 1 + t * n)
 
 
+def _grouped_steps(sizes, bt):
+    """(group, row tile) of each grid step that computes: every non-empty
+    group, in order, visits each row tile its rows overlap."""
+    steps, start = [], 0
+    for g, n in enumerate(sizes):
+        if n:
+            steps += [(g, t) for t in range(start // bt,
+                                            (start + n - 1) // bt + 1)]
+        start += n
+    return steps
+
+
+def _nm_grouped_matmul_bytes(d):
+    # grid (J, row tiles + G - 1, K); the steps past the computing ones
+    # hold the last one's blocks at its last k (nothing fetched or
+    # written).  X and W blocks are fetched per run of equal indices,
+    # each output tile written once per run of visits
+    s, bt, bk, bo = d.get("s", 4), d["bt"], d["bk"], d["bo"]
+    kk, jj = d["d"] // bk, d["n_out"] // bo
+    steps = _grouped_steps(d["sizes"], bt)
+    idle = d["rows"] // bt + len(d["sizes"]) - 1 - len(steps)
+    xs, ws, outs = [], [], []
+    for j in range(jj):
+        walk = [(g, t, k) for g, t in steps for k in range(kk)]
+        walk += [(steps[-1][0], steps[-1][1], kk - 1)] * idle
+        xs += [(t, k) for _, t, k in walk]
+        ws += [(g, k, j) for g, _, k in walk]
+        outs += [(t, j) for _, t, _ in walk]
+    return s * (bt * bk * _run_count(xs) + bk * bo * _run_count(ws)
+                + bt * bo * _run_count(outs))
+
+
 def _flash_attention_bytes(d):
     # q/out resident across the KV axis (1 run per (b,h,q-tile)); k/v
     # blocks are fetched every step — causal masking skips the COMPUTE
@@ -306,6 +352,9 @@ COST_MODEL = {
     "paged_kv_scatter": {
         "formula": "s*(2*B*T*r + runs(pool walk)*4*bs*r)",
         "bytes": _paged_kv_scatter_bytes},
+    "nm_grouped_matmul": {
+        "formula": "s*(bt*bk*runs(x) + bk*bo*runs(w) + bt*bo*runs(out))",
+        "bytes": _nm_grouped_matmul_bytes},
 }
 
 

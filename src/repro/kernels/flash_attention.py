@@ -53,7 +53,7 @@ def softmax_update(s, v, m_ref, l_ref, acc_ref, precision=None) -> None:
     ``_NEG``, which the ``s > _NEG / 2`` guard turns into exactly-zero
     probabilities (a fully-masked row would otherwise yield
     exp(_NEG − _NEG) = 1 per lane).  ``v`` is the (bk, d) value tile;
-    ``precision`` that of the ``p @ v`` contraction.
+    ``precision`` that of a float32 ``p @ v`` contraction (``_pv``).
     """
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -61,9 +61,24 @@ def softmax_update(s, v, m_ref, l_ref, acc_ref, precision=None) -> None:
     p = jnp.where(s > _NEG / 2, p, 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32, precision=precision)
+    acc_ref[...] = acc_ref[...] * alpha[:, None] + _pv(p, v, precision)
     m_ref[...] = m_new
+
+
+def _pv(p, v, precision):
+    """``p @ v``, accumulated in float32.  A bfloat16 ``v`` meets ``p``
+    split into two bfloat16 parts, so every product is exact and ``p``
+    keeps all but about 2^-17 of itself; one bfloat16 pass (Mosaic's
+    default contraction of a float32 operand) would keep 2^-9, an error as
+    large as the output itself holds where the weights are near uniform
+    and the values cancel."""
+    if v.dtype == jnp.bfloat16:
+        hi = p.astype(jnp.bfloat16)
+        lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        return (jnp.dot(hi, v, preferred_element_type=jnp.float32)
+                + jnp.dot(lo, v, preferred_element_type=jnp.float32))
+    return jnp.dot(p, v, preferred_element_type=jnp.float32,
+                   precision=precision)
 
 
 def softmax_finalize(l_ref, acc_ref, dtype):

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed import tp as tp_mod
+from repro.kernels.nm_grouped_matmul import nm_grouped_matmul_pallas
 from repro.kernels.nm_prune import nm_prune_pallas
 from repro.kernels.nm_prune_matmul import nm_prune_matmul_pallas
 from repro.kernels.nm_spmm import nm_spmm_pallas
@@ -27,6 +28,7 @@ from repro.kernels.osparse_matmul import osparse_matmul_pallas
 from repro.kernels.w8a8_matmul import w8a8_matmul_pallas
 
 __all__ = [
+    "nm_grouped_matmul",
     "nm_prune",
     "nm_prune_matmul",
     "nm_spmm",
@@ -163,6 +165,41 @@ def nm_prune_matmul(
     y = nm_prune_matmul_pallas(xf, w, scale, n, m, bias=bias, block_t=bt,
                                block_o=bo, block_k=bk, interpret=interpret)
     return y[:t, :n_out].reshape(*lead, n_out)
+
+
+def nm_grouped_matmul(
+    x: jax.Array,
+    w: jax.Array,
+    group_sizes: jax.Array,
+    n: int,
+    m: int,
+    prune: bool = True,
+    block_t: int = 128,
+    block_o: int = 2048,
+    block_k: int = 512,
+    interpret: Optional[bool] = None,
+    layer: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Routed experts' projection over expert-sorted rows ``x`` (R, D):
+    rows of group ``e`` (``group_sizes[e]`` of them, in order) times
+    ``w[e]`` (``w``: (G, D, N_out)), each row's input N:M-pruned on plain
+    ``|x|`` when ``prune``; rows past the last group come back zero.
+    With ``layer``, ``w`` is the scan-stacked (L, G, D, N_out) and the
+    kernel reads ``w[layer]`` in place."""
+    t, d = x.shape
+    n_out = w.shape[-1]
+    if prune:
+        _check_groups(d, m)
+    bt, tp = _rows(t, block_t)
+    bo, op = _lanes(n_out, block_o)
+    bk, dp = _lanes(d, block_k, m if prune else 1)
+    xf = _pad_to(_pad_to(x, 0, tp), 1, dp)
+    w = _pad_to(_pad_to(w, -2, dp), -1, op)
+    y = nm_grouped_matmul_pallas(xf, w, group_sizes, n, m, prune=prune,
+                                 block_t=bt, block_o=bo, block_k=bk,
+                                 interpret=interpret, layer=layer)
+    filled = jnp.arange(t) < jnp.sum(group_sizes)
+    return jnp.where(filled[:, None], y[:t, :n_out], 0)
 
 
 def nm_spmm(

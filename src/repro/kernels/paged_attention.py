@@ -219,7 +219,7 @@ def _kernel(tab_ref, lo_ref, hi_ref, qoff_ref, kvlen_ref,  # scalar prefetch
 
         q = q_ref[0, 0]                                         # (rows, hd)
         k = k_buf[slot].reshape(tk, -1)                         # (tk, hd)
-        v = v_buf[slot].reshape(tk, -1).astype(jnp.float32)
+        v = v_buf[slot].reshape(tk, -1)       # p @ v: softmax_update
         # bf16 x bf16 products are exact in f32: q.k takes the stored
         # operands; a float32 operand contracts at full precision
         prec = contract_precision(q.dtype, k.dtype)

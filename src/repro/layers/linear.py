@@ -50,10 +50,12 @@ def init_linear(
 
 
 def dense_linear(x: jax.Array, p: Dict[str, jax.Array]) -> jax.Array:
-    y = x @ p["w"]
+    """``x @ w (+ b)``, accumulated in float32 and stored once, as the
+    fused kernels' epilogues do."""
+    y = jnp.matmul(x, p["w"], preferred_element_type=jnp.float32)
     if "b" in p:
-        y = y + p["b"]
-    return y
+        y = y + p["b"].astype(jnp.float32)
+    return y.astype(jnp.result_type(x, p["w"]))
 
 
 def _quantized(x: jax.Array, p: Dict[str, jax.Array], prune: bool,
@@ -156,7 +158,5 @@ def _project_ops(x: jax.Array, p: Dict[str, jax.Array], prune: bool,
         xp = pruner.prune_input(x, scale, policy)
         if layer_flag is not None:
             xp = jnp.where(layer_flag, xp, x)
-        y = xp @ p["w"]
-        if "b" in p:
-            y = y + p["b"]
+        y = dense_linear(xp, p)
     return y

@@ -111,8 +111,10 @@ def rope_freqs(head_dim: int, theta: float, rotary_dim: int | None = None):
 def _rotate(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """Rotate pairs laid out as [x0..x_{d/2-1} | x_{d/2}..] (neox style)."""
     d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2], x[..., d2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    xf, cf, sf = (a.astype(jnp.float32) for a in (x, cos, sin))
+    x1, x2 = xf[..., :d2], xf[..., d2:]
+    return jnp.concatenate([x1 * cf - x2 * sf, x2 * cf + x1 * sf],
+                           axis=-1).astype(x.dtype)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:

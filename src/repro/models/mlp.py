@@ -16,6 +16,13 @@ def _act(x: jax.Array, kind: str) -> jax.Array:
     return jax.nn.gelu(x) if kind == "gelu" else jax.nn.silu(x)
 
 
+def glu(g: jax.Array, u: jax.Array, kind: str) -> jax.Array:
+    """``act(g) * u``, computed in float32 and stored once in ``g``'s
+    dtype."""
+    return (_act(g.astype(jnp.float32), kind)
+            * u.astype(jnp.float32)).astype(g.dtype)
+
+
 def init_mlp(rng: jax.Array, d_model: int, d_ff: int, dtype=jnp.float32) -> Dict:
     r1, r2, r3 = jax.random.split(rng, 3)
     return {
@@ -44,6 +51,6 @@ def mlp(
                       layer_idx, fl.get("gate_proj"))
     u = sparse_linear(x, p["up_proj"], "up_proj", policy, phase,
                       layer_idx, fl.get("up_proj"))
-    h = _act(g, act_fn) * u
+    h = glu(g, u, act_fn)
     return sparse_linear(h, p["down_proj"], "down_proj", policy, phase,
                          layer_idx, fl.get("down_proj"))

@@ -9,7 +9,20 @@ Amber Pruner inside experts: the paper disables Robust-Norm scoring for MoE
 (tokens are dynamically routed → per-expert weight statistics are unstable),
 so expert-FFN inputs are pruned with plain |X| scores (``moe_plain_score``);
 the per-token N:M mode is used even under tile-consensus because expert
-groups don't align with token tiles.  Router projections stay dense (tiny).
+groups don't align with token tiles.  The layer's q/gate skip flag covers
+the routed experts' gate_proj as it covers a dense MLP's.  Router
+projections stay dense (tiny) and run in float32 at full precision.
+
+With ``policy.use_pallas_kernels``, each routed projection of the ragged
+path runs as one ``nm_grouped_matmul`` kernel (the N:M prune fused onto
+its row tiles in prefill; dense in decode); kernels-off keeps
+``ragged_dot``, with ``_maybe_prune`` as the jnp oracle of the fused
+prune.  Inside the serving layer scan ``p`` carries the whole stack of
+expert weights and the ``layer`` index (``transformer._run_blocks``): the
+kernel reads that layer's weights in place, where XLA copies a scan slice
+(all of the layer's experts) before handing it to a kernel or a
+``ragged_dot``.  A prefill chunk's padded tail (rows at or past
+``chunk_len``) is routed to no expert and its output is zero.
 """
 from __future__ import annotations
 
@@ -21,7 +34,7 @@ import jax.numpy as jnp
 from repro.core import pruner
 from repro.core.policy import SparsityPolicy
 from repro.layers.linear import init_linear
-from repro.models.mlp import _act, init_mlp, mlp
+from repro.models.mlp import _act, glu, init_mlp, mlp
 
 __all__ = ["init_moe", "moe"]
 
@@ -50,11 +63,52 @@ def init_moe(
     return p
 
 
+def _prunes(module: str, policy: SparsityPolicy, phase: str) -> bool:
+    """Amber prunes this routed projection's input in ``phase`` (before
+    the layer's skip flag)."""
+    return policy.active(phase) and policy.should_prune(module, None)
+
+
 def _maybe_prune(x: jax.Array, module: str, policy: SparsityPolicy,
-                 phase: str) -> jax.Array:
-    if policy.active(phase) and policy.should_prune(module, None):
-        return pruner.prune_input(x, None, policy)  # naive |X| inside experts
-    return x
+                 phase: str, flag: Optional[jax.Array] = None) -> jax.Array:
+    if not _prunes(module, policy, phase):
+        return x
+    xp = pruner.prune_input(x, None, policy)  # naive |X| inside experts
+    return xp if flag is None else jnp.where(flag, xp, x)
+
+
+def _layer_weights(p: Dict) -> Dict[str, jax.Array]:
+    """The routed experts' weights of this layer: slices of the stack
+    when ``p`` carries the whole scan stack and its ``layer`` index."""
+    ws = {k: p["experts"][k]["w"]
+          for k in ("gate_proj", "up_proj", "down_proj")}
+    if "layer" in p:
+        ws = {k: w[p["layer"]] for k, w in ws.items()}
+    return ws
+
+
+def _grouped(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+             module: str, policy: SparsityPolicy, phase: str,
+             flag: Optional[jax.Array] = None,
+             layer: Optional[jax.Array] = None) -> jax.Array:
+    """One routed projection over expert-sorted rows: the fused grouped
+    kernel with kernels on (pruning in prefill), else prune then
+    ``ragged_dot``.  With ``layer``, ``w`` is the layer stack."""
+    if not policy.use_pallas_kernels:
+        w = w if layer is None else w[layer]
+        return jax.lax.ragged_dot(
+            _maybe_prune(x, module, policy, phase, flag), w, group_sizes)
+    from repro.kernels import ops
+
+    def call(prune):
+        return ops.nm_grouped_matmul(x, w, group_sizes, policy.n, policy.m,
+                                     prune=prune, layer=layer)
+
+    prune = _prunes(module, policy, phase)
+    if prune and flag is not None:
+        # scan-stacked skip list: both branches compiled, one runs
+        return jax.lax.cond(flag, lambda: call(True), lambda: call(False))
+    return call(prune)
 
 
 def moe(
@@ -66,7 +120,10 @@ def moe(
     act_fn: str = "silu",
     impl: str = "ragged",
     flags: Optional[Dict[str, jax.Array]] = None,
+    chunk_len: Optional[jax.Array] = None,
 ) -> jax.Array:
+    """Routed experts of ``x``; ``chunk_len`` (prefill chunks) marks the
+    valid leading rows of each sequence."""
     # Under a multi-device mesh, GSPMD partitions ragged_dot by expanding
     # the expert dim into dense masked ops over the GLOBAL token axis
     # (O(E·T·d) buffers).  Dispatch must be token-local: shard_map keeps the
@@ -87,7 +144,8 @@ def moe(
         if x.shape[0] % dp_size == 0 and x.shape[0] >= dp_size:
             return _moe_shard_map(mesh, x, p, policy, phase, top_k, act_fn,
                                   flags)
-    return _moe_local(x, p, policy, phase, top_k, act_fn, impl, flags)
+    return _moe_local(x, p, policy, phase, top_k, act_fn, impl, flags,
+                      chunk_len)
 
 
 def _moe_local(
@@ -99,26 +157,28 @@ def _moe_local(
     act_fn: str = "silu",
     impl: str = "ragged",
     flags: Optional[Dict[str, jax.Array]] = None,
+    chunk_len: Optional[jax.Array] = None,
 ) -> jax.Array:
     orig_shape = x.shape
     d = orig_shape[-1]
     xt = x.reshape(-1, d)
     t = xt.shape[0]
     n_experts = p["router"]["w"].shape[-1]
+    gate_flag = (flags or {}).get("gate_proj")
 
-    logits = (xt.astype(jnp.float32) @ p["router"]["w"])        # (T, E)
-    gate_vals, expert_ids = jax.lax.top_k(logits, top_k)        # (T, k)
-    gates = jax.nn.softmax(gate_vals, axis=-1)                  # renorm over top-k
-
-    wg = p["experts"]["gate_proj"]["w"]
-    wu = p["experts"]["up_proj"]["w"]
-    wd = p["experts"]["down_proj"]["w"]
+    with jax.named_scope("model.moe.router"):
+        logits = jnp.dot(xt.astype(jnp.float32), p["router"]["w"],
+                         precision=jax.lax.Precision.HIGHEST)   # (T, E)
+        gate_vals, expert_ids = jax.lax.top_k(logits, top_k)    # (T, k)
+        gates = jax.nn.softmax(gate_vals, axis=-1)              # renorm over top-k
 
     if impl == "dense":
+        ws = _layer_weights(p)
+        wg, wu, wd = ws["gate_proj"], ws["up_proj"], ws["down_proj"]
         # weighted all-expert compute (compile-anywhere fallback)
         combine = jnp.zeros((t, n_experts), jnp.float32)
         combine = jax.vmap(lambda c, i, g: c.at[i].add(g))(combine, expert_ids, gates)
-        xin = _maybe_prune(xt, "gate_proj", policy, phase)
+        xin = _maybe_prune(xt, "gate_proj", policy, phase, gate_flag)
         xup = _maybe_prune(xt, "up_proj", policy, phase)
         h = _act(jnp.einsum("td,edf->tef", xin, wg), act_fn)
         h = h * jnp.einsum("td,edf->tef", xup, wu)
@@ -127,22 +187,37 @@ def _moe_local(
         y_e = jnp.einsum("tef,efd->ted", h, wd)
         y = jnp.einsum("ted,te->td", y_e, combine.astype(y_e.dtype))
     else:
-        flat_e = expert_ids.reshape(-1)                         # (T*k,)
-        flat_t = jnp.repeat(jnp.arange(t), top_k)               # (T*k,)
-        order = jnp.argsort(flat_e, stable=True)
-        inv = jnp.argsort(order)
-        xs = jnp.take(xt, jnp.take(flat_t, order), axis=0)      # (T*k, D)
-        group_sizes = jnp.bincount(flat_e, length=n_experts).astype(jnp.int32)
+        valid = None
+        if chunk_len is not None:                       # padded tail
+            seq = orig_shape[-2]
+            valid = (jnp.arange(t) % seq) < chunk_len
+        with jax.named_scope("model.moe.router"):
+            flat_e = expert_ids.reshape(-1)                     # (T*k,)
+            if valid is not None:   # padded rows sort last, in no group
+                flat_e = jnp.where(jnp.repeat(valid, top_k), flat_e,
+                                   n_experts)
+            flat_t = jnp.repeat(jnp.arange(t), top_k)           # (T*k,)
+            order = jnp.argsort(flat_e, stable=True)
+            inv = jnp.argsort(order)
+            xs = jnp.take(xt, jnp.take(flat_t, order), axis=0)  # (T*k, D)
+            group_sizes = jnp.bincount(flat_e, length=n_experts
+                                       ).astype(jnp.int32)
 
-        xg = _maybe_prune(xs, "gate_proj", policy, phase)
-        xu = _maybe_prune(xs, "up_proj", policy, phase)
-        hg = jax.lax.ragged_dot(xg, wg, group_sizes)
-        hu = jax.lax.ragged_dot(xu, wu, group_sizes)
-        h = _act(hg, act_fn) * hu
-        h = _maybe_prune(h, "down_proj", policy, phase)
-        ys = jax.lax.ragged_dot(h, wd, group_sizes)             # (T*k, D)
+        with jax.named_scope("model.moe.experts"):
+            e, layer = p["experts"], p.get("layer")
+            hg = _grouped(xs, e["gate_proj"]["w"], group_sizes, "gate_proj",
+                          policy, phase, gate_flag, layer)
+            hu = _grouped(xs, e["up_proj"]["w"], group_sizes, "up_proj",
+                          policy, phase, None, layer)
+            h = glu(hg, hu, act_fn)
+            ys = _grouped(h, e["down_proj"]["w"], group_sizes, "down_proj",
+                          policy, phase, None, layer)
         y_flat = jnp.take(ys, inv, axis=0).reshape(t, top_k, d)
-        y = jnp.einsum("tkd,tk->td", y_flat, gates.astype(y_flat.dtype))
+        # the k gated outputs summed in float32 and stored once
+        y = jnp.einsum("tkd,tk->td", y_flat, gates.astype(y_flat.dtype),
+                       preferred_element_type=jnp.float32)
+        if valid is not None:
+            y = jnp.where(valid[:, None], y, 0)
 
     y = y.astype(x.dtype)
     if "shared" in p:
@@ -177,11 +252,13 @@ def _moe_shard_map(
     from jax.sharding import PartitionSpec as P
 
     dp = data_axes_tuple(mesh)
-    wg, wu, wd = (p["experts"]["gate_proj"]["w"], p["experts"]["up_proj"]["w"],
-                  p["experts"]["down_proj"]["w"])
+    ws = _layer_weights(p)
+    wg, wu, wd = ws["gate_proj"], ws["up_proj"], ws["down_proj"]
     router = p["router"]["w"]
+    gate_flag = (flags or {}).get("gate_proj")
+    flag_in = () if gate_flag is None else (gate_flag,)
 
-    def body(xb, router_l, wg_l, wu_l, wd_l):
+    def body(xb, router_l, wg_l, wu_l, wd_l, *flag_l):
         b, t, d = xb.shape
         n_exp = router_l.shape[-1]
         xt = xb.reshape(b * t, d)
@@ -210,11 +287,12 @@ def _moe_shard_map(
         xe = jnp.take(xs, idx_c.reshape(-1), axis=0).reshape(
             n_exp, cap, d)                                   # (E, C, D)
 
-        xg = _maybe_prune(xe, "gate_proj", policy, phase)
+        xg = _maybe_prune(xe, "gate_proj", policy, phase,
+                          flag_l[0] if flag_l else None)
         xu = _maybe_prune(xe, "up_proj", policy, phase)
         hg = jnp.einsum("ecd,edf->ecf", xg, wg_l)
         hu = jnp.einsum("ecd,edf->ecf", xu, wu_l)
-        h = _act(hg, act_fn) * hu
+        h = glu(hg, hu, act_fn)
         h = _maybe_prune(h, "down_proj", policy, phase)
         ye = jnp.einsum("ecf,efd->ecd", h, wd_l)             # partial over F
         ye = ye * valid[..., None]
@@ -237,10 +315,10 @@ def _moe_shard_map(
             P(None, None, "model"),           # gate (E, D, F/model)
             P(None, None, "model"),           # up
             P(None, "model", None),           # down (E, F/model, D)
-        ),
+        ) + (P(),) * len(flag_in),            # the layer's gate skip flag
         out_specs=P(dp_entry, None, None),
         check_vma=False,
-    )(x, router, wg, wu, wd)
+    )(x, router, wg, wu, wd, *flag_in)
 
     if "shared" in p:
         y = y + mlp(x, p["shared"], policy, phase, act_fn, None, flags)

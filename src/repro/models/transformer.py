@@ -82,6 +82,10 @@ def _init_attn_block(cfg: ModelConfig, rng: jax.Array, dtype) -> Dict:
         "o_proj": init_linear(r[3], qd, d, dtype=dtype),
         "ln2": common.init_norm(d, cfg.norm, dtype),
     }
+    if getattr(cfg, "qk_norm", False):
+        # per-head RMSNorm of q and k (Qwen3), one weight of head_dim
+        p["q_norm"] = common.init_norm(cfg.head_dim, "rmsnorm", dtype)
+        p["k_norm"] = common.init_norm(cfg.head_dim, "rmsnorm", dtype)
     if cfg.n_experts:
         p["moe"] = init_moe(r[4], d, cfg.moe_d_ff, cfg.n_experts,
                             cfg.shared_expert, dtype)
@@ -235,7 +239,7 @@ def _attn_block_apply(
         x2 = common.norm_apply(h, p["ln2"], cfg.norm)
         if cfg.n_experts:
             ff = moe(x2, p["moe"], policy, phase, cfg.top_k, cfg.act_fn,
-                     cfg.moe_impl, fl)
+                     cfg.moe_impl, fl, chunk_len)
         else:
             ff = mlp(x2, p["mlp"], policy, phase, cfg.act_fn, None, fl)
         return h + ff, new_cache
@@ -256,6 +260,10 @@ def _self_attention(cfg, h, p, policy, phase, cache, pos, positions,
     q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    if getattr(cfg, "qk_norm", False):
+        # over each head's head_dim, after the projection, before RoPE
+        q = common.rms_norm(q, p["q_norm"])
+        k = common.rms_norm(k, p["k_norm"])
     q = _apply_rope(cfg, q, positions, positions_3d)
     k = _apply_rope(cfg, k, positions, positions_3d)
     # pin attention sharding: heads on "model" when divisible, otherwise
@@ -478,6 +486,29 @@ def _embed_inputs(cfg: ModelConfig, params, batch) -> jax.Array:
     return h
 
 
+def _split_experts(periods: Dict) -> Tuple[Dict, Dict]:
+    """(each block's stacked routed-expert weights, the period stack
+    without them).  The cached layer scan hands the experts their whole
+    stack and the layer index instead of a scan slice: XLA copies a
+    slice (every expert weight of the layer, each step) before a kernel
+    or a ``ragged_dot`` reads it, and the grouped kernel indexes the
+    stack in place."""
+    experts, rest = {}, {}
+    for b, p in periods.items():
+        if "moe" in p:
+            experts[b] = p["moe"]["experts"]
+            p = dict(p, moe={k: v for k, v in p["moe"].items()
+                             if k != "experts"})
+        rest[b] = p
+    return experts, rest
+
+
+def _join_experts(pp: Dict, experts: Dict, idx: jax.Array) -> Dict:
+    """One period's params with its blocks' expert stacks and ``layer``."""
+    return {b: dict(p, moe=dict(p["moe"], experts=experts[b], layer=idx))
+            if b in experts else p for b, p in pp.items()}
+
+
 def _run_blocks(cfg, params, h, policy, phase, cache, positions, positions_3d,
                 chunk_len=None):
     n_per, tail = _n_periods(cfg)
@@ -566,6 +597,7 @@ def _run_blocks(cfg, params, h, policy, phase, cache, positions, positions_3d,
             # f32 copy of an xs cache out of the loop — as loop-varying
             # state it is sliced/updated in place per period
             cstack = cache["periods"]
+            experts, pstack = _split_experts(params["periods"])
 
             def body(carry, xs):
                 h_c, cs = carry
@@ -573,6 +605,7 @@ def _run_blocks(cfg, params, h, policy, phase, cache, positions, positions_3d,
                     pp, fl, idx = xs
                 else:
                     (pp, idx), fl = xs, None
+                pp = _join_experts(pp, experts, idx)
                 cc = jax.tree_util.tree_map(
                     lambda c: jax.lax.dynamic_index_in_dim(
                         c, idx, 0, keepdims=False), cs)
@@ -583,8 +616,8 @@ def _run_blocks(cfg, params, h, policy, phase, cache, positions, positions_3d,
                 return (hh, cs), None
 
             idxs = jnp.arange(n_per)
-            xs = (params["periods"], period_flags, idxs) \
-                if period_flags is not None else (params["periods"], idxs)
+            xs = (pstack, period_flags, idxs) \
+                if period_flags is not None else (pstack, idxs)
             # the loop's own slices of each layer's weights and cache sit
             # in this scope, the blocks' work in theirs below it
             with jax.named_scope("model.layers"):
@@ -611,10 +644,11 @@ def _lm_logits(cfg, params, h):
 
     with jax.named_scope("model.lm_head"):
         h = common.norm_apply(h, params["final_norm"], cfg.norm)
-        if cfg.tie_embeddings:
-            logits = h @ params["embed"]["w"].T
-        else:
-            logits = h @ params["lm_head"]["w"]
+        w = params["embed"]["w"].T if cfg.tie_embeddings \
+            else params["lm_head"]["w"]
+        # float32 logits, accumulated in float32: a bfloat16 logit is
+        # a step of 1/64 at |logit| 4, coarse enough to reorder the top two
+        logits = jnp.matmul(h, w, preferred_element_type=jnp.float32)
     # keep the vocab dim model-sharded: (B, T, V) or (B, V)
     if logits.ndim == 3:
         return maybe_shard(logits, "dp", None, "model")

@@ -81,7 +81,8 @@ from repro.serve import tracing
 from repro.serve.executor import Executor
 from repro.serve.faults import EngineCrash, FaultInjector
 from repro.serve.metrics import (LifecycleMetrics, MetricsSnapshot,
-                                 PagedMetrics, RequestMetrics)
+                                 MoEMetrics, PagedMetrics,
+                                 RequestMetrics)
 from repro.serve.paged import (chain_block_hashes, chain_block_keys,
                                max_blocks_per_slot)
 from repro.serve.scheduler import (CANCELLED, DECODE, DONE, PREFILL,
@@ -203,6 +204,8 @@ class ContinuousServingEngine:
         self.restores = 0         # times restore() rebuilt this engine
         # paged-attention kernel tiles (walked, visible), per attention layer
         self.attention_tiles = [0, 0]
+        # routed-expert rows (valid tokens x top_k) per phase
+        self.routed_rows = {"prefill": 0, "decode": 0}
         self.metrics: Dict[str, Any] = {}
         self.metrics_snapshot: Optional[MetricsSnapshot] = None
         self._key = None                       # sampling PRNG (run-owned)
@@ -391,6 +394,16 @@ class ContinuousServingEngine:
             self.attention_tiles[0] += walked * hkv
             self.attention_tiles[1] += visible * hkv
 
+    def _count_routed_rows(self, plan: StepPlan) -> None:
+        """Add one dispatch of ``plan`` to ``moe.routed_rows.*``: the
+        prefill chunk's valid tokens and the active decode rows, each
+        times top_k (host integers, no device read)."""
+        k = self.model.cfg.top_k if self.model.cfg.n_experts else 0
+        if plan.prefill is not None:
+            self.routed_rows["prefill"] += plan.prefill.chunk_len * k
+        if plan.decode is not None:
+            self.routed_rows["decode"] += int(plan.decode.active.sum()) * k
+
     def _step_fused(self, params, extras: Dict[int, Dict], it: int,
                     plan: StepPlan) -> bool:
         """One-dispatch iteration: the scheduler's fused plan runs as a
@@ -426,6 +439,7 @@ class ContinuousServingEngine:
         ex = extras.get(pw.req.rid, {}) if pw is not None and pw.first else {}
         res = self.exec.step(params, plan, ex, pkey, dkey, fault)
         self._count_attention_tiles(plan)
+        self._count_routed_rows(plan)
         with tracing.span(tracing.COMMIT):
             if pw is not None:
                 self.sched.commit_chunk(pw.req, pw.chunk_len)
@@ -456,6 +470,7 @@ class ContinuousServingEngine:
         ex = extras.get(pw.req.rid, {}) if pw.first else {}
         logits = self.exec.prefill(params, plan, ex, fault)
         self._count_attention_tiles(plan)
+        self._count_routed_rows(plan)
         self.sched.commit_chunk(pw.req, pw.chunk_len)
         if self.sched.seq_complete(pw.req):   # seq ingested: sample
             tok = self.exec.sample_token(logits, sub)
@@ -476,6 +491,7 @@ class ContinuousServingEngine:
         fault = jnp.float32(self._crash_fire("decode", it))
         nxt = self.exec.decode(params, plan, sub, fault)
         self._count_attention_tiles(plan)
+        self._count_routed_rows(plan)
         with tracing.span(tracing.COMMIT):
             self.sched.emit_decode_tokens(plan.decode, nxt, it)
         return True
@@ -509,6 +525,7 @@ class ContinuousServingEngine:
         cancel0 = sched.cancellations
         disp0, work0 = ex.dispatches, self.work_iterations
         tiles0 = list(self.attention_tiles)
+        routed0 = dict(self.routed_rows)
         if self.paged:
             sched.pool.peak_in_use = sched.pool.in_use   # per-run peak
             evict0 = sched.pool.evictions
@@ -612,6 +629,9 @@ class ContinuousServingEngine:
                                              - tiles0[1]),
                     attention_tile=self._chunk_tile(),
                 ) if self.paged else PagedMetrics(enabled=False)),
+                moe=MoEMetrics(routed_rows={
+                    ph: n - routed0[ph]
+                    for ph, n in self.routed_rows.items()}),
                 requests=[RequestMetrics(
                     rid=r.rid,
                     prompt_len=int(len(r.tokens)),

@@ -52,6 +52,10 @@ STEP_BUCKETS: Dict[Tuple[bool, bool, bool], str] = {
     (True, True, True): "step_replay_decode",
 }
 
+# XLA options of every serving program: a narrow (bfloat16) value is
+# rounded wherever the program stores one, never carried wider
+EXACT_STORES = {"xla_allow_excess_precision": False}
+
 # legacy two-program split (still served by ``prefill()``/``decode()``)
 _LEGACY_TRACE_KEYS = ("prefill", "prefill_replay", "decode")
 
@@ -130,13 +134,17 @@ class Executor:
                 f"{cfg.chunk_size} (see kernels.paged_attention"
                 ".paged_kernel_covers); use a power-of-two chunk_size or "
                 "drop use_pallas_kernels")
+        # every value the program stores in a narrow dtype is rounded
+        # where the program stores it: XLA otherwise keeps a fused chain
+        # in float32 past a store (a residual add read by the next norm),
+        # so a routed layer's top-k would hang on fusion choices
+        jit = functools.partial(jax.jit, compiler_options=EXACT_STORES)
         # a TP mesh returns every output replicated over it — the layout
         # the cache and params go in with, so a step never retraces on a
         # changed input sharding
-        jit = jax.jit
         if mesh is not None and mesh.size > 1:
             from jax.sharding import NamedSharding, PartitionSpec
-            jit = functools.partial(jax.jit, out_shardings=NamedSharding(
+            jit = functools.partial(jit, out_shardings=NamedSharding(
                 mesh, PartitionSpec()))
         self.cache = None               # built lazily per params
         self._placed = None             # (params, params on self.mesh)

@@ -21,7 +21,7 @@ import json
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["SCHEMA_VERSION", "LifecycleMetrics", "PagedMetrics",
-           "RequestMetrics", "MetricsSnapshot"]
+           "MoEMetrics", "RequestMetrics", "MetricsSnapshot"]
 
 SCHEMA_VERSION = 1
 
@@ -69,6 +69,16 @@ class PagedMetrics:
 
 
 @dataclasses.dataclass
+class MoEMetrics:
+    """Routed-expert work, counted on the host from each step's plan (no
+    device read): ``routed_rows[phase]`` sums valid tokens x top_k, the
+    rows the routed experts contract (``moe.routed_rows.prefill`` and
+    ``moe.routed_rows.decode``; decode counts active slots)."""
+    routed_rows: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: {"prefill": 0, "decode": 0})
+
+
+@dataclasses.dataclass
 class RequestMetrics:
     """One request.  The ``*_t`` fields are its timeline on
     ``time.perf_counter()`` (-1 where the request never got there), each
@@ -113,6 +123,7 @@ class MetricsSnapshot:
     lifecycle: LifecycleMetrics = dataclasses.field(
         default_factory=lambda: LifecycleMetrics(terminal_states={}))
     paged: PagedMetrics = dataclasses.field(default_factory=PagedMetrics)
+    moe: MoEMetrics = dataclasses.field(default_factory=MoEMetrics)
     requests: List[RequestMetrics] = dataclasses.field(default_factory=list)
     replicas: Optional[List["MetricsSnapshot"]] = None
     schema_version: int = SCHEMA_VERSION
@@ -130,6 +141,7 @@ class MetricsSnapshot:
             "degraded_iterations": self.degraded_iterations,
             "lifecycle": dataclasses.asdict(self.lifecycle),
             "paged": self.paged.to_dict(),
+            "moe": dataclasses.asdict(self.moe),
             "requests": [dataclasses.asdict(r) for r in self.requests],
         }
         if self.replicas is not None:
@@ -156,6 +168,7 @@ class MetricsSnapshot:
             lifecycle=LifecycleMetrics(**d.get(
                 "lifecycle", {"terminal_states": {}})),
             paged=paged,
+            moe=MoEMetrics(**d.get("moe", {})),
             requests=[RequestMetrics(**r) for r in d.get("requests", [])],
             replicas=([cls.from_dict(r) for r in d["replicas"]]
                       if d.get("replicas") is not None else None),
@@ -230,6 +243,9 @@ class MetricsSnapshot:
                 faults_fired=max(p.lifecycle.faults_fired for p in parts),
             ),
             paged=paged,
+            moe=MoEMetrics(routed_rows={
+                ph: sum(p.moe.routed_rows[ph] for p in parts)
+                for ph in ("prefill", "decode")}),
             requests=[r for p in parts for r in p.requests],
             replicas=list(parts),
         )

@@ -1,5 +1,6 @@
-"""Compile rehearsal: every kernel of the served path, at qwen2_7b widths,
-lowered by Mosaic for a described TPU v5e (no chip attached).
+"""Compile rehearsal: every kernel of the served path, at qwen2_7b widths
+and at qwen3_30b_a3b's where it differs (routed experts, q width, GQA
+group 8), lowered by Mosaic for a described TPU v5e (no chip attached).
 
 Interpret mode passes kernels that Mosaic refuses for tiling, layout or
 VMEM reasons; this file is where such a refusal shows up without a chip.
@@ -57,6 +58,10 @@ def spec(topo):
 
 
 bf, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+# qwen3_30b_a3b: a 512-token chunk's routed rows (x top-8), d_model,
+# expert width, routed experts, q width (32 heads x 128), GQA group 8
+Q3_ROWS, Q3_D, Q3_F, Q3_E, Q3_Q, Q3_HQ = 4096, 2048, 768, 128, 4096, 32
 
 # name → (fn, operand shapes, is a projection kernel)
 _CASES = {
@@ -158,6 +163,45 @@ _CASES = {
         [((1, 512, HKV, HD), f32), ((1, 512, HKV, HD), f32),
          ((NB, HKV, BS, HD), f32), ((NB, HKV, BS, HD), f32), ((1, MB), i32),
          ((1,), i32), ((1,), i32)], False),
+    # routed experts at Qwen3-30B-A3B widths: gate (pruned), up (dense)
+    # and down (pruned) over one chunk's expert-sorted rows
+    "nm_grouped_matmul_gate_q3": (
+        lambda x, w, g: ops.nm_grouped_matmul(x, w, g, 8, 16, prune=True,
+                                              interpret=False),
+        [((Q3_ROWS, Q3_D), bf), ((Q3_E, Q3_D, Q3_F), bf), ((Q3_E,), i32)],
+        False),
+    "nm_grouped_matmul_up_q3": (
+        lambda x, w, g: ops.nm_grouped_matmul(x, w, g, 8, 16, prune=False,
+                                              interpret=False),
+        [((Q3_ROWS, Q3_D), bf), ((Q3_E, Q3_D, Q3_F), bf), ((Q3_E,), i32)],
+        False),
+    "nm_grouped_matmul_down_q3": (
+        lambda x, w, g: ops.nm_grouped_matmul(x, w, g, 8, 16, prune=True,
+                                              interpret=False),
+        [((Q3_ROWS, Q3_F), bf), ((Q3_E, Q3_F, Q3_D), bf), ((Q3_E,), i32)],
+        False),
+    # as the serving layer scan calls it: the whole stack of 6 layers'
+    # experts, read in place at the layer's index
+    "nm_grouped_matmul_layer_stack_q3": (
+        lambda x, w, g, i: ops.nm_grouped_matmul(x, w, g, 8, 16, prune=True,
+                                                 interpret=False, layer=i),
+        [((Q3_ROWS, Q3_D), bf), ((6, Q3_E, Q3_D, Q3_F), bf), ((Q3_E,), i32),
+         ((), i32)], False),
+    # decode: the active slots' routed rows, dense
+    "nm_grouped_matmul_decode_q3": (
+        lambda x, w, g: ops.nm_grouped_matmul(x, w, g, 8, 16, prune=False,
+                                              interpret=False),
+        [((SLOTS * 8, Q3_D), bf), ((Q3_E, Q3_D, Q3_F), bf), ((Q3_E,), i32)],
+        False),
+    "nm_prune_matmul_q_proj_q3": (
+        lambda x, w, s: ops.nm_prune_matmul(x, w, s, 8, 16, interpret=False),
+        [((512, Q3_D), bf), ((Q3_D, Q3_Q), bf), ((Q3_D,), bf)], True),
+    "paged_attention_prefill_q3": (
+        lambda q, kp, vp, tab, qo, kl: paged_attention_pallas(
+            q, kp, vp, tab, qo, kl, causal=True, interpret=False),
+        [((1, 512, Q3_HQ, HD), bf), ((CELL_NB, HKV, BS, HD), bf),
+         ((CELL_NB, HKV, BS, HD), bf), ((1, MB), i32), ((1,), i32),
+         ((1,), i32)], False),
     "paged_kv_scatter_decode": (
         lambda kn, vn, kp, vp, tab, p, c: paged_kv_scatter_pallas(
             kn, vn, kp, vp, tab, p, c, interpret=False),
