@@ -75,7 +75,7 @@ overlaps.
   flash_attention      s*(2*B*H*T*hd + 2*B*H*(T/bq)*S*hd)
   nm_grouped_matmul    s*(bt*bk*runs(x) + bk*bo*runs(w) + bt*bo*runs(out))
   nm_prune             s*(2*T*D + I*D)
-  nm_prune_matmul      s*(J*T*D + I*D*N + I*J*D + I*N + T*N)
+  nm_prune_matmul      s*(T*D + I*D*N + I*D + I*N + T*N)
   nm_spmm              s*(J*T*D + I*D*N + I*J*D + T*N)
   osparse_matmul       s*(2*J*T*D + 4*I*J*D + 2*I*N + T*N) + 2*I*D*N
   osparse_w8a8_decode  s*(J*T*D + 2*I*J*D + 2*I*N + T*N + 1) + I*D*N
@@ -85,8 +85,10 @@ overlaps.
 --- end generated table ---
 
 Symbols: T tokens, D in-features, N out-features, s dtype bytes (f32:
-4); grid extents I = T/bt, J = N/bo, K = D/bk (K-refetch of X/W blocks
-is why J·T·D and I·D·N appear, not T·D and D·N); grouped: bt/bk/bo
+4); grid extents I = T/bt, J = N/bo, K = D/bk (a tiled matmul refetches
+X once per output tile and W once per token tile, hence J·T·D and I·D·N;
+``nm_prune_matmul`` keeps its selected X in VMEM across output tiles, so
+X streams once, T·D); grouped: bt/bk/bo
 its row, k and output tiles, ``runs(·)`` over its (group, row tile)
 walk; attention B, H, hd,
 query tile bq, KV length S; paged r = Hkv·hd, P pages of bs rows per
@@ -115,9 +117,9 @@ scalar-prefetched block tables:
 
   kernel               VMEM       SMEM     worst config, grid
   flash_attention       1.13 MiB     0 B   recurrentgemma_2b (1,10,8,8)
-  nm_grouped_matmul    14.50 MiB   112 B   granite_34b       (12,9,12)
+  nm_grouped_matmul    14.50 MiB   116 B   granite_34b       (12,9,12)
   nm_prune              2.00 MiB     0 B   llama31_8b        (1,8)
-  nm_prune_matmul       2.76 MiB     0 B   llama31_8b        (1,56,8)
+  nm_prune_matmul       8.76 MiB     0 B   recurrentgemma_2b (1,10,5)
   nm_spmm               2.75 MiB     0 B   llama31_8b        (1,56,8)
   osparse_matmul        2.01 MiB     0 B   llama31_8b        (1,56,16)
   osparse_w8a8_decode   0.32 MiB     0 B   llama31_8b        (1,56,8)
@@ -201,8 +203,12 @@ def _nm_prune_bytes(d):
 
 
 def _nm_prune_matmul_bytes(d):
+    # X and the channel scale stream once per token block (the X block
+    # parks on its last k block after the first output tile, whose
+    # selection the later tiles reuse from VMEM); weights once per token
+    # block, bias once per output run
     s, t, dd, n, i, j = _mm_dims(d)
-    return s * (j * t * dd + i * dd * n + i * j * dd + i * n + t * n)
+    return s * (t * dd + i * dd * n + i * dd + i * n + t * n)
 
 
 def _nm_spmm_bytes(d):
@@ -329,7 +335,7 @@ COST_MODEL = {
         "formula": "s*(2*T*D + I*D)",
         "bytes": _nm_prune_bytes},
     "nm_prune_matmul": {
-        "formula": "s*(J*T*D + I*D*N + I*J*D + I*N + T*N)",
+        "formula": "s*(T*D + I*D*N + I*D + I*N + T*N)",
         "bytes": _nm_prune_matmul_bytes},
     "nm_spmm": {
         "formula": "s*(J*T*D + I*D*N + I*J*D + T*N)",

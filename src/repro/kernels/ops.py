@@ -122,6 +122,55 @@ def nm_prune(
     return y[:t, :d].reshape(*lead, d)
 
 
+# The projection kernel's blocks follow the shape: the largest token
+# block whose pruned rows fit in VMEM (the whole chunk where they do, so
+# each weight matrix streams once), contraction blocks of at most 512
+# lanes, and the widest output tile that fits beside them.  The budget
+# leaves Mosaic's own temporaries room under the 16 MiB scoped limit:
+# about 3 MiB beside bf16 blocks, and 7 MiB beside float32 ones, whose
+# ``Precision.HIGHEST`` contraction runs as six bf16 passes over split
+# copies of the tiles (described-v5e compiles at q_proj's width).
+_MAX_BT, _MAX_BO, _MAX_BK = 512, 2048, 512
+_VMEM_BUDGET, _F32_CONTRACT_VMEM = 13 * 2**20, 4 * 2**20
+
+
+def nm_prune_matmul_vmem(bt: int, bo: int, bk: int, dp: int, op: int,
+                         itemsize: int) -> int:
+    """VMEM bytes of one ``nm_prune_matmul_pallas`` call: double-buffered
+    X, weight and output blocks and (at most float32) scale and bias
+    blocks, the float32 accumulator, and the pruned-X scratch (the token
+    block's whole pruned rows when there is more than one output tile,
+    else one k block)."""
+    blocks = (bt * bk + bk * bo + bt * bo) * itemsize + (bk + bo) * 4
+    scratch = bt * bo * 4 + bt * (dp if op > bo else bk) * itemsize
+    return 2 * blocks + scratch
+
+
+def _nm_prune_matmul_blocks(t: int, d: int, n_out: int, m: int,
+                            itemsize: int, block_t: Optional[int],
+                            block_o: Optional[int], block_k: Optional[int]):
+    """``((bt, tp), (bo, op), (bk, dp))`` for a (t, d) @ (d, n_out) call:
+    each block with its padded axis.  A block given is honoured as the
+    other projection kernels honour theirs; the token block is otherwise
+    the largest that fits (halving from 512), and the output tile the
+    widest exact lane divisor of ``n_out`` up to 2048 that fits with it."""
+    bk, dp = _lanes(d, block_k or _MAX_BK, m)
+    if block_t:
+        bts = [_rows(t, block_t)]
+    else:
+        bts = list(dict.fromkeys(_rows(t, _MAX_BT >> h) for h in range(7)))
+    bos = ([_lanes(n_out, block_o)] if block_o else
+           [(b, n_out) for b in range(min(n_out, _MAX_BO) // 128 * 128, 0,
+                                      -128) if n_out % b == 0]
+           or [_lanes(n_out, 256)])
+    budget = _VMEM_BUDGET - (_F32_CONTRACT_VMEM if itemsize == 4 else 0)
+    for bt, tp in bts:
+        for bo, op in bos:
+            if nm_prune_matmul_vmem(bt, bo, bk, dp, op, itemsize) <= budget:
+                return (bt, tp), (bo, op), (bk, dp)
+    return bts[-1], bos[-1], (bk, dp)
+
+
 def nm_prune_matmul(
     x: jax.Array,
     w: jax.Array,
@@ -129,15 +178,17 @@ def nm_prune_matmul(
     n: int,
     m: int,
     bias: Optional[jax.Array] = None,
-    block_t: int = 256,
-    block_o: int = 256,
-    block_k: int = 512,
+    block_t: Optional[int] = None,
+    block_o: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused per-token prune + GEMM over any (..., D) input (one X pass).
 
     ``bias`` (``(N_out,)``) is folded into the kernel epilogue — the add
-    happens on the hot f32 accumulator instead of a separate HBM pass."""
+    happens on the hot f32 accumulator instead of a separate HBM pass.
+    Blocks left ``None`` are chosen from the shape
+    (``_nm_prune_matmul_blocks``)."""
     # tensor parallelism (distributed/tp.py): under an active TP scope the
     # call re-enters itself column-parallel — each device runs this same
     # wrapper on its N_out slice (scope suspended inside the shard body),
@@ -153,9 +204,9 @@ def nm_prune_matmul(
     t, d = xf.shape
     n_out = w.shape[-1]
     _check_groups(d, m)
-    bt, tp = _rows(t, block_t)
-    bo, op = _lanes(n_out, block_o)
-    bk, dp = _lanes(d, block_k, m)
+    itemsize = jnp.dtype(jnp.result_type(x.dtype, w.dtype)).itemsize
+    (bt, tp), (bo, op), (bk, dp) = _nm_prune_matmul_blocks(
+        t, d, n_out, m, itemsize, block_t, block_o, block_k)
     xf = _pad_to(_pad_to(xf, 0, tp), 1, dp)
     w = _pad_to(_pad_to(w, 0, dp), 1, op)
     if scale is not None:

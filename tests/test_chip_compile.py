@@ -75,6 +75,29 @@ _CASES = {
     "nm_prune_matmul_down": (
         lambda x, w, s: ops.nm_prune_matmul(x, w, s, 8, 16, interpret=False),
         [((T, N), bf), ((N, D), bf), ((N,), bf)], True),
+    # the benchmark cell's 512-token chunk through q_proj (with its bias),
+    # gate_proj and down_proj: the blocks the wrapper picks for them
+    "nm_prune_matmul_q_proj_512": (
+        lambda x, w, s, b: ops.nm_prune_matmul(x, w, s, 8, 16, bias=b,
+                                               interpret=False),
+        [((512, D), bf), ((D, D), bf), ((D,), bf), ((D,), bf)], True),
+    "nm_prune_matmul_gate_512": (
+        lambda x, w, s: ops.nm_prune_matmul(x, w, s, 8, 16, interpret=False),
+        [((512, D), bf), ((D, N), bf), ((D,), bf)], True),
+    "nm_prune_matmul_down_512": (
+        lambda x, w, s: ops.nm_prune_matmul(x, w, s, 8, 16, interpret=False),
+        [((512, N), bf), ((N, D), bf), ((N,), bf)], True),
+    # a prompt's last chunk of 20 tokens: one unpadded token block
+    "nm_prune_matmul_tail_20": (
+        lambda x, w, s: ops.nm_prune_matmul(x, w, s, 8, 16, interpret=False),
+        [((20, D), bf), ((D, D), bf), ((D,), bf)], True),
+    # the policy's other patterns at q_proj's width
+    "nm_prune_matmul_2_4": (
+        lambda x, w, s: ops.nm_prune_matmul(x, w, s, 2, 4, interpret=False),
+        [((512, D), bf), ((D, D), bf), ((D,), bf)], True),
+    "nm_prune_matmul_4_8": (
+        lambda x, w, s: ops.nm_prune_matmul(x, w, s, 4, 8, interpret=False),
+        [((512, D), bf), ((D, D), bf), ((D,), bf)], True),
     "nm_spmm": (
         lambda x, w, s: ops.nm_spmm(x, w, s, 8, 16, interpret=False),
         [((T, D), bf), ((D, N), bf), ((D,), bf)], True),

@@ -21,8 +21,10 @@ from repro.layers.linear import sparse_linear
 
 PATTERNS = [(2, 4), (4, 8), (8, 16)]
 DTYPES = [jnp.float32, jnp.bfloat16]
-# (t, d, n_out): the last two force the token/odd-shape padding fallback
-SHAPES = [(32, 64, 48), (128, 256, 128), (97, 160, 100), (33, 96, 130)]
+# (t, d, n_out): the middle two force the token/odd-shape padding fallback;
+# the last is a chunk's 20-row tail over two k blocks
+SHAPES = [(32, 64, 48), (128, 256, 128), (97, 160, 100), (33, 96, 130),
+          (20, 1024, 256)]
 
 
 def _tol(dtype):
@@ -71,6 +73,92 @@ def test_nm_prune_matmul_kblock_invariance(rng):
     b = ops.nm_prune_matmul(x, w, None, 8, 16, block_k=512)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-6, atol=1e-4)
+
+
+def _prune_matmul_operands(rng, t, d, no, dtype, integer=False):
+    """x, w, scale, bias; ``integer`` makes x, w and bias small whole
+    numbers, whose products and sums are exact in float32 in any order
+    (and |x| ties often inside a group, so tie-breaking is exercised)."""
+    k1, k2, k3, k4 = jax.random.split(rng, 4)
+    scale = jax.random.uniform(k3, (d,)) + 0.5
+    if integer:
+        ints = lambda k, shape, hi: jax.random.randint(
+            k, shape, -hi, hi + 1).astype(dtype)
+        return (ints(k1, (t, d), 16), ints(k2, (d, no), 4), scale,
+                ints(k4, (no,), 8))
+    x = jax.random.normal(k1, (t, d), dtype=dtype)
+    w = (jax.random.normal(k2, (d, no)) * d**-0.5).astype(dtype)
+    bias = jax.random.normal(k4, (no,), dtype=dtype)
+    return x, w, scale, bias
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("blocks,other", [
+    # one output tile against four: later tiles reuse the first tile's
+    # selection from VMEM
+    (dict(block_t=512, block_o=512), dict(block_t=512, block_o=128)),
+    # one token block against four and two
+    (dict(block_t=512, block_o=256), dict(block_t=128, block_o=256)),
+    (dict(block_t=256, block_o=512), dict(block_t=512, block_o=512)),
+], ids=["output_tiles", "token_blocks_4", "token_blocks_2"])
+def test_nm_prune_matmul_block_invariance(blocks, other, dtype, rng):
+    """Blocks only tile the work: with scale and bias, every token block
+    and output tile choice keeps the same channels and gives the same
+    bits (two k blocks each; whole-number operands, so no summation order
+    can move a bit)."""
+    x, w, scale, bias = _prune_matmul_operands(rng, 512, 512, 512, dtype,
+                                               integer=True)
+    a = ops.nm_prune_matmul(x, w, scale, 8, 16, bias=bias, block_k=256,
+                            **blocks)
+    b = ops.nm_prune_matmul(x, w, scale, 8, 16, bias=bias, block_k=256,
+                            **other)
+    np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                  np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_nm_prune_matmul_chosen_blocks_parity(dtype, rng, monkeypatch):
+    """Under a small VMEM budget the shape-chosen blocks split the tokens,
+    the outputs and the contraction: the result still matches the
+    oracle."""
+    monkeypatch.setattr(ops, "_VMEM_BUDGET", 2**19)
+    monkeypatch.setattr(ops, "_F32_CONTRACT_VMEM", 0)
+    t, d, no = 256, 1024, 512
+    itemsize = jnp.dtype(dtype).itemsize
+    (bt, tp), (bo, op), (bk, dp) = ops._nm_prune_matmul_blocks(
+        t, d, no, 16, itemsize, None, None, None)
+    assert (tp, op, dp) == (t, no, d)
+    assert t // bt > 1 and no // bo > 1 and d // bk > 1, (bt, bo, bk)
+    x, w, scale, bias = _prune_matmul_operands(rng, t, d, no, dtype)
+    got = ops.nm_prune_matmul(x, w, scale, 8, 16, bias=bias)
+    want = (ref.nm_prune_matmul_ref(x, w, scale, 8, 16).astype(jnp.float32)
+            + bias.astype(jnp.float32))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), **_tol(dtype))
+
+
+# name -> (tokens, d_in, d_out) of the served models' pruned projections
+_SERVED = {"qwen2_7b_q": (512, 3584, 3584), "qwen2_7b_gate": (512, 3584, 18944),
+           "qwen2_7b_down": (512, 18944, 3584),
+           "qwen3_30b_a3b_q": (512, 2048, 4096),
+           "chunk_2048_gate": (2048, 3584, 18944),
+           "chunk_2048_down": (2048, 18944, 3584), "tail_20": (20, 3584, 3584)}
+
+
+@pytest.mark.parametrize("name", sorted(_SERVED))
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_nm_prune_matmul_blocks_follow_shape(name, itemsize):
+    """At the served widths the chosen blocks divide the weights (no padded
+    weight copy), keep to the VMEM budget, and take a whole 512-token chunk
+    as one token block wherever its pruned rows fit."""
+    t, d, no = _SERVED[name]
+    (bt, tp), (bo, op), (bk, dp) = ops._nm_prune_matmul_blocks(
+        t, d, no, 16, itemsize, None, None, None)
+    assert (op, dp) == (no, d) and tp % bt == 0 and bk % 128 == 0
+    assert ops.nm_prune_matmul_vmem(bt, bo, bk, dp, op,
+                                    itemsize) <= ops._VMEM_BUDGET
+    if itemsize == 2 and t % 128 == 0 and t <= 512 and t * d <= 2 * 2**20:
+        assert bt == t
 
 
 # ---------------------------------------------------------- osparse_matmul
