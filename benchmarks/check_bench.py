@@ -52,9 +52,9 @@ REQUIRED_KERNEL_ROWS = (
 # the derived column, which the FAIL scan below enforces
 REQUIRED_SERVING_ROWS = (
     "serving/prefix_reuse",
-    # fused one-dispatch step vs the legacy two-program split; derived
-    # embeds the token-identity verdict and dispatches_per_iteration
-    "serving/one_dispatch",
+    # the staggered stream through one replica: derived embeds the
+    # one-trace-per-bucket and one-dispatch-per-iteration verdicts
+    "serving/continuous",
     # dp=2 router-sharded serving: derived embeds per-replica dpi and the
     # token-identity-vs-dp1 verdict
     "serving/sharded_dp2",

@@ -2,7 +2,7 @@
 
 A mixed-length staggered request stream through the continuous scheduler
 (chunked Amber-sparse prefill + slot-batched dense decode) against the same
-requests served sequentially by the legacy one-shot engine.  Both rows are
+requests served sequentially by the one-shot reference engine.  Both rows are
 measured after a warmup pass so they time compute, not tracing.  The row's
 ``us_per_call`` is microseconds per generated token; the derived column
 carries tok/s, scheduler shape-bucket trace counts, and an ordering check —
@@ -58,43 +58,26 @@ def run() -> list[str]:
 
     # --- continuous scheduler over the staggered stream -------------------
     eng = ContinuousServingEngine(model, policy, ContinuousConfig(
-        max_seq=_MAX_SEQ, num_slots=3, chunk_size=16), _via_api=True)
+        max_seq=_MAX_SEQ, num_slots=3, chunk_size=16))
     res = warmed_run(eng)
     m = res["metrics"]
     cont_us = m["wall_s"] / max(m["generated_tokens"], 1) * 1e6
-    # fused one-dispatch default: one step program per phase-presence bucket
+    # one step program per phase-presence bucket, each traced once, at
+    # exactly one compiled dispatch per work iteration
     no_retrace = all(v == 1 for v in m["trace_counts"].values())
     traces = "+".join(str(v) for _, v in sorted(m["trace_counts"].items()))
+    one_dispatch = m["dispatches_per_iteration"] == 1.0
     rows.append(csv_row(
         "serving/continuous", cont_us,
         f"tok_s={m['tokens_per_s']:.1f};traces={traces};"
-        f"single_trace_per_bucket={'PASS' if no_retrace else 'FAIL'}"))
-
-    # --- one-dispatch iterations vs the legacy two-program split ----------
-    # same staggered stream through the legacy split (fused_step=False);
-    # the fused engine above must emit identical greedy tokens at exactly
-    # one compiled dispatch per work iteration
-    legacy = ContinuousServingEngine(model, policy, ContinuousConfig(
-        max_seq=_MAX_SEQ, num_slots=3, chunk_size=16, fused_step=False),
-        _via_api=True)
-    lres = warmed_run(legacy)
-    lm = lres["metrics"]
-    identical = lres["outputs"] == res["outputs"]
-    one_dispatch = m["dispatches_per_iteration"] == 1.0
-    rows.append(csv_row(
-        "serving/one_dispatch", cont_us,
-        f"fused_tok_s={m['tokens_per_s']:.1f};"
-        f"legacy_tok_s={lm['tokens_per_s']:.1f};"
-        f"dpi={m['dispatches_per_iteration']:.2f}"
-        f"_vs_{lm['dispatches_per_iteration']:.2f};"
-        f"one_dispatch={'PASS' if one_dispatch else 'FAIL'};"
-        f"token_identity={'PASS' if identical else 'FAIL'}"))
+        f"single_trace_per_bucket={'PASS' if no_retrace else 'FAIL'};"
+        f"one_dispatch={'PASS' if one_dispatch else 'FAIL'}"))
 
     # --- dp=2 sharded serving through the Router/api facade ---------------
     # the same staggered stream load-balanced across two host-level engine
     # replicas (independent schedulers + block pools).  Gates: outputs
     # token-identical to the single-replica run above, and each replica
-    # keeps the fused one-dispatch property (dpi ≤ the single-engine
+    # keeps the one-dispatch property (dpi ≤ the single-engine
     # baseline — sharding must not reintroduce extra dispatches)
     sharded = Engine.from_config(model, EngineConfig(
         dp=2, serving=ContinuousConfig(max_seq=_MAX_SEQ, num_slots=3,
@@ -122,7 +105,7 @@ def run() -> list[str]:
     half_pool = (3 * _MAX_SEQ) // (2 * bs)
     press = ContinuousServingEngine(model, policy, ContinuousConfig(
         max_seq=_MAX_SEQ, num_slots=3, chunk_size=16,
-        block_size=bs, num_blocks=half_pool), _via_api=True)
+        block_size=bs, num_blocks=half_pool))
     pres = warmed_run(press)
     pm = pres["metrics"]
     pg = pm["paged"]
@@ -159,7 +142,7 @@ def run() -> list[str]:
     def shared_run(prefix_cache):
         eng = ContinuousServingEngine(model, policy, ContinuousConfig(
             max_seq=_MAX_SEQ, num_slots=3, chunk_size=16, block_size=8,
-            prefix_cache=prefix_cache), _via_api=True)
+            prefix_cache=prefix_cache))
         for _ in range(2):              # warmup compiles AND warms the index
             eng.clear()
             for p, a in zip(shared_prompts, shared_arrivals):
@@ -192,9 +175,8 @@ def run() -> list[str]:
         rows.append(csv_row("serving/prefix_reuse", 0.0,
                             "paging auto-disabled for this arch;SKIP"))
 
-    # --- legacy one-shot engine, one request at a time --------------------
-    one = ServingEngine(model, policy, ServeConfig(max_seq=_MAX_SEQ),
-                        _via_api=True)
+    # --- one-shot reference engine, one request at a time ----------------
+    one = ServingEngine(model, policy, ServeConfig(max_seq=_MAX_SEQ))
 
     def oneshot_sweep():
         n = 0

@@ -163,8 +163,7 @@ def _step_fixture(ctx: Context):
     rows = device_pool_rows(nb)   # +1 sentinel row on device leaves
     pol = DENSE.with_(use_pallas_kernels=True)
     eng = ContinuousServingEngine(model, pol, ContinuousConfig(
-        max_seq=max_seq, num_slots=slots, chunk_size=8, block_size=bs),
-        _via_api=True)
+        max_seq=max_seq, num_slots=slots, chunk_size=8, block_size=bs))
     cache = init_paged_cache(model, slots, max_seq, bs, nb, eng._spec)
     tab = np.full((slots, mb), -1, np.int32)
     tab[0, :3], tab[1, :3] = [1, 2, 3], [4, 5, 6]

@@ -34,7 +34,7 @@ def _err(rule_name, obj, msg, **data):
 # ------------------------------------------------------ dry-run budget
 
 def _dry_run(ctx: Context):
-    """Serve a few tiny prompts through the fused engine and return it
+    """Serve a few tiny prompts through the serving engine and return it
     (cached — the jaxpr rules' fixture engine is separate on purpose:
     this one must actually execute)."""
     if "dry_engine" in ctx._cache:
@@ -49,8 +49,7 @@ def _dry_run(ctx: Context):
     cfg, model, params = ctx.smoke_model()
     pol = DENSE.with_(use_pallas_kernels=True)
     eng = ContinuousServingEngine(model, pol, ContinuousConfig(
-        max_seq=64, num_slots=2, chunk_size=8, block_size=8,
-        fused_step=True), _via_api=True)
+        max_seq=64, num_slots=2, chunk_size=8, block_size=8))
     # staggered arrivals so prefill-only, hybrid, and decode-only buckets
     # all occur; lengths force multi-chunk prefill
     prompts = [np.asarray(jax.random.randint(

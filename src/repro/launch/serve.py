@@ -1,6 +1,6 @@
 """Serving driver: Amber-sparse prefill, dense decode.
 
-One-shot batch mode (legacy):
+One-shot batch mode (the whole-batch reference engine):
 
     PYTHONPATH=src python -m repro.launch.serve \
         --arch qwen2_7b --smoke --sparsity 8:16 --batch 4 --new-tokens 32
@@ -60,10 +60,6 @@ def main(argv=None):
     ap.add_argument("--ttl", type=int, default=None, metavar="ITERS",
                     help="per-request deadline in scheduler iterations "
                          "(--trace): requests exceeding it end TIMED_OUT")
-    ap.add_argument("--no-fused-step", action="store_true",
-                    help="legacy two-program iterations (separate prefill "
-                         "and decode dispatches) instead of the fused "
-                         "one-dispatch step program")
     ap.add_argument("--dp", type=int, default=1,
                     help="data-parallel engine replicas for --trace "
                          "(host-level: independent schedulers + block "
@@ -104,10 +100,9 @@ def main(argv=None):
 
     scfg = ServeConfig(max_seq=args.prompt_len + args.new_tokens + 8,
                        temperature=args.temperature)
-    # the one-shot batch path stays on the legacy engine (it is the
-    # monolithic-prefill oracle); _via_api marks first-party use
-    engine = ServingEngine(model, policy, scfg, _via_api=True)
-    dense_engine = ServingEngine(model, DENSE, scfg, _via_api=True)
+    # the one-shot batch path runs the monolithic-prefill reference engine
+    engine = ServingEngine(model, policy, scfg)
+    dense_engine = ServingEngine(model, DENSE, scfg)
 
     batch = {
         "tokens": jax.random.randint(
@@ -163,7 +158,7 @@ def _trace_mode(args, cfg, model, params, policy):
             paged=not args.no_paged, block_size=args.block_size,
             num_blocks=args.num_blocks,
             prefix_cache=not args.no_prefix_cache,
-            ttl_default=args.ttl, fused_step=not args.no_fused_step)),
+            ttl_default=args.ttl)),
         policy=policy)
     sysp = np.asarray(jax.random.randint(
         jax.random.PRNGKey(99), (args.shared_prefix,), 0, cfg.vocab_size))
@@ -227,8 +222,7 @@ def _trace_mode(args, cfg, model, params, policy):
           f"decode batch={args.slots})")
     print(f"# dispatches: {m.dispatches} programs / "
           f"{m.iterations} iterations = "
-          f"{m.dispatches_per_iteration:.2f} per work iteration "
-          f"({'fused one-dispatch step' if not args.no_fused_step else 'legacy two-program split'})")
+          f"{m.dispatches_per_iteration:.2f} per work iteration")
     if m.replicas is not None:
         per = ", ".join(
             f"r{i}: {p.generated_tokens} tok / {p.iterations} iters / "

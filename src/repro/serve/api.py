@@ -20,11 +20,12 @@ on a backend with a device per replica, builds a ``(data, model)`` mesh
 via ``launch.mesh.make_serving_mesh``: replica *i* holds its params and
 cache on mesh row *i*.
 
-The legacy entry points survive as thin adapters over this stack:
-``ContinuousServingEngine`` is exactly a dp=1 router replica and
-``ServingEngine.generate`` (one-shot) is "submit the whole batch, close
-admission, run" — both now raise ``DeprecationWarning`` on direct
-construction.  serve/README.md has the migration table.
+Below the facade, ``ContinuousServingEngine`` is one router replica
+(the benchmark reads it through ``Engine.replicas``; tools may build
+and drive it directly), and
+``ServingEngine.generate`` is the one-shot whole-batch reference the
+chunked path is tested against; ``Engine.generate`` gives the same
+one-shot call over this stack.  serve/README.md has the migration table.
 """
 from __future__ import annotations
 

@@ -4,7 +4,9 @@ interleaved with slot-batched dense decode over a **paged** KV cache.
 Requests arrive asynchronously (:meth:`ContinuousServingEngine.submit`) and
 are scheduled over a fixed pool of decode **slots** whose KV rows live in a
 global **block pool** (:mod:`repro.serve.paged`).  Each scheduler
-iteration:
+iteration plans the steps below on the host and runs its device work —
+the prefill chunk and the slot-batched decode — as ONE compiled step
+program:
 
   1. **admit** — waiting requests whose arrival time has passed claim free
      slots FCFS, gated by a block-budget check (the pool must cover the
@@ -14,46 +16,48 @@ iteration:
      request's own preemption replay) is acquired instead of recomputed:
      the shared block ids go straight into the table, the slot's ``pos``
      starts at the first non-cached token, and prefill begins mid-sequence;
-  2. **prefill** — the oldest admitted-but-unprefilled request advances by
-     one fixed-size token chunk through the Amber-sparse projection path
-     (``model.prefill_chunk``), scattering KV through its block table;
-  3. **ensure/preempt** — decoding slots crossing a block boundary grab a
+  2. **ensure/preempt** — decoding slots crossing a block boundary grab a
      fresh block; when the pool is dry the **youngest** active request is
      preempted (blocks released, request requeued; its emitted tokens are
      replayed through prefill on re-admission, so greedy output is
      unchanged);
-  4. **decode** — all slots holding decoding requests take one dense decode
-     step as a single padded batch (inactive slots are masked out of the
-     cache update).
+  3. **prefill** — the oldest admitted-but-unprefilled request advances by
+     one fixed-size token chunk through the Amber-sparse projection path
+     (``model.prefill_chunk``), scattering KV through its block table;
+  4. **decode** — all slots that were decoding when the iteration was
+     planned take one dense decode step as a single padded batch (inactive
+     slots are masked out of the cache update); it reads the KV the
+     prefill chunk just wrote.
 
-Since the scheduler/executor API split this class is a thin **driver**
-composing the two layers (serve/README.md "Architecture"):
+This class is a thin layer over two others (serve/README.md
+"Architecture"):
 
 * :class:`~repro.serve.scheduler.Scheduler` — every piece of host state
   (request lifecycles, slots, the block pool, prefix index, watchdog,
   counters); emits :class:`~repro.serve.scheduler.StepPlan`s and commits
   their results.  Never touches device arrays.
 * :class:`~repro.serve.executor.Executor` — the cache pytree, the jit'd
-  phase/step programs and their oracle twins, the fault/degradation
-  ladder, and (optionally) a TP mesh that shards the kernels.  Never
-  touches request state.
+  step programs and their oracle twins, the fault/degradation ladder, and
+  (optionally) a TP mesh that shards the kernels.  Never touches request
+  state.
 
 The driver owns only the run loop, the sampling PRNG, snapshot/restore
-composition, and metrics assembly.  New code should construct engines
-through :class:`repro.serve.api.Engine`; direct construction still works
-(every historical attribute delegates to the right layer) but warns.
+composition, and metrics assembly.  One instance is one serving replica:
+:class:`repro.serve.api.Engine` wraps it (through the
+:class:`~repro.serve.router.Router`), and tools may drive it directly.
 
-Shape buckets: prefill compiles once per chunk shape (a single
-``chunk_size`` bucket for attention archs; a dyadic ladder of at most
+Shape buckets: a step program compiles once per phase-presence bucket
+(``executor.STEP_BUCKETS``) and prefill chunk shape (a single
+``chunk_size`` for attention archs; a dyadic ladder of at most
 log2(chunk_size)+1 sizes for archs with recurrent blocks, whose scans
-cannot mask padded tokens), and decode compiles once for the padded
-``num_slots`` batch — arbitrary traffic never retraces, and block
-allocation/preemption only rewrites the small int32 block-table array, so
-paging does not add shape buckets.  The ``trace_counts`` attribute counts
-actual retraces per phase and is asserted in the test suite.
+cannot mask padded tokens); decode always runs the padded ``num_slots``
+batch — arbitrary traffic never retraces, and block allocation/preemption
+only rewrites the small int32 block-table array, so paging does not add
+shape buckets.  The ``trace_counts`` attribute counts actual retraces per
+bucket and is asserted in the test suite.
 
 Equivalence: with greedy decoding and **per-token** sparsity modes the
-per-request output stream is token-identical to the legacy one-shot
+per-request output stream is token-identical to the one-shot
 :class:`~repro.serve.engine.ServingEngine` — a token's N:M mask doesn't
 depend on which chunk carries it, chunked prefill attends over the cached
 prefix so logits match, decode rows are independent of batch composition,
@@ -67,7 +71,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
-import warnings
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -83,26 +86,10 @@ from repro.serve.faults import EngineCrash, FaultInjector
 from repro.serve.metrics import (LifecycleMetrics, MetricsSnapshot,
                                  MoEMetrics, PagedMetrics,
                                  RequestMetrics)
-from repro.serve.paged import (chain_block_hashes, chain_block_keys,
-                               max_blocks_per_slot)
-from repro.serve.scheduler import (CANCELLED, DECODE, DONE, PREFILL,
-                                   REJECTED, TERMINAL, TIMED_OUT, WAITING,
-                                   Request, Scheduler, StepPlan,
-                                   _dyadic_sizes)
+from repro.serve.paged import max_blocks_per_slot
+from repro.serve.scheduler import TERMINAL, Request, Scheduler, StepPlan
 
 __all__ = ["ContinuousConfig", "Request", "ContinuousServingEngine"]
-
-# historical module-level names: the lifecycle states and chunk ladder
-# lived here before the scheduler split, and tests/tools import them from
-# this module
-_TERMINAL = TERMINAL
-
-
-def _hash_blocks(*args, **kwargs):
-    # late-bound so the historical patch point keeps working: tests
-    # monkeypatch ``repro.serve.continuous.chain_block_hashes`` and the
-    # scheduler hashes through this shim
-    return chain_block_hashes(*args, **kwargs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,14 +101,6 @@ class ContinuousConfig:
     eos_token: int = -1       # -1 → never stop early
     seed: int = 0
     max_iters: int = 100_000  # scheduler-loop safety valve
-    fused_step: bool = True
-    # one-dispatch iterations: the active request's prefill chunk AND the
-    # slot-batched decode run as a SINGLE compiled step program per shape
-    # bucket (metrics["dispatches_per_iteration"] == 1 on clean runs).
-    # False restores the legacy two-program split (prefill then decode) —
-    # token-identical under greedy sampling; under temperature > 0 the
-    # sampling-key split order differs on same-iteration prefill→decode
-    # handoffs.  Overridable via REPRO_FUSED_STEP=0/1.
     # --- paged KV allocation (serve/paged.py) ---
     paged: bool = True        # auto-disabled where no full-attn KV exists
     block_size: int = 16      # KV rows per block
@@ -164,18 +143,15 @@ class ContinuousConfig:
 
 
 class ContinuousServingEngine:
-    """Scheduler + Executor driver over a paged slot cache."""
+    """One serving replica: runs a Scheduler and an Executor over a paged
+    slot cache.  :class:`repro.serve.api.Engine` builds one per dp
+    replica (the benchmark reads it through ``Engine.replicas``); tools
+    such as ``repro.analysis`` build and drive it directly."""
 
     def __init__(self, model, policy: SparsityPolicy = DENSE,
                  cfg: ContinuousConfig = ContinuousConfig(),
                  faults: Optional[FaultInjector] = None,
-                 mesh=None, _via_api: bool = False):
-        if not _via_api:
-            warnings.warn(
-                "constructing ContinuousServingEngine directly is "
-                "deprecated; use repro.serve.api.Engine.from_config "
-                "(serve/README.md has the migration table)",
-                DeprecationWarning, stacklevel=2)
+                 mesh=None):
         self.model = model
         self.policy = policy
         self.cfg = cfg
@@ -195,11 +171,7 @@ class ContinuousServingEngine:
             cfg, paged=self.exec.paged,
             exact_chunks=self.exec.exact_chunks,
             policy_enabled=policy.enabled, prefix_cache=cfg.prefix_cache,
-            faults=faults, validate=self._validate, hash_fn=_hash_blocks)
-        # one-dispatch iterations (cfg.fused_step, env-overridable so the
-        # chaos-smoke CI matrix can pin either path without code changes)
-        env = os.environ.get("REPRO_FUSED_STEP")
-        self.fused_step = (env != "0") if env is not None else cfg.fused_step
+            faults=faults, validate=self._validate)
         self.work_iterations = 0  # iterations that dispatched any program
         self.restores = 0         # times restore() rebuilt this engine
         # paged-attention kernel tiles (walked, visible), per attention layer
@@ -212,8 +184,8 @@ class ContinuousServingEngine:
         self.last_snapshot: Optional[Dict] = None
 
     # -------------------------------------------------- layer delegation
-    # the historical flat-engine attributes, routed to the owning layer so
-    # pre-split callers (tests, benchmarks, tools) keep working unchanged
+    # layer-owned state read through the replica by the benchmark, tools
+    # and tests
     @property
     def requests(self):
         return self.sched.requests
@@ -225,10 +197,6 @@ class ContinuousServingEngine:
     @property
     def paged(self) -> bool:
         return self.exec.paged
-
-    @property
-    def paged_kernel(self) -> bool:
-        return self.exec.paged_kernel
 
     @property
     def prefix_cache(self) -> bool:
@@ -245,54 +213,6 @@ class ContinuousServingEngine:
     @property
     def dispatches(self) -> int:
         return self.exec.dispatches
-
-    @property
-    def degraded_iterations(self) -> int:
-        return self.exec.degraded_iterations
-
-    @property
-    def preemptions(self) -> int:
-        return self.sched.preemptions
-
-    @property
-    def rejections(self) -> int:
-        return self.sched.rejections
-
-    @property
-    def admission_retries(self) -> int:
-        return self.sched.admission_retries
-
-    @property
-    def watchdog_trips(self) -> int:
-        return self.sched.watchdog_trips
-
-    @property
-    def timeouts(self) -> int:
-        return self.sched.timeouts
-
-    @property
-    def cancellations(self) -> int:
-        return self.sched.cancellations
-
-    @property
-    def prefix_hits(self) -> int:
-        return self.sched.prefix_hits
-
-    @property
-    def blocks_reused(self) -> int:
-        return self.sched.blocks_reused
-
-    @property
-    def tokens_skipped(self) -> int:
-        return self.sched.tokens_skipped
-
-    @property
-    def cache(self):
-        return self.exec.cache
-
-    @cache.setter
-    def cache(self, value):
-        self.exec.cache = value
 
     @property
     def _spec(self):
@@ -404,32 +324,29 @@ class ContinuousServingEngine:
         if plan.decode is not None:
             self.routed_rows["decode"] += int(plan.decode.active.sum()) * k
 
-    def _step_fused(self, params, extras: Dict[int, Dict], it: int,
-                    plan: StepPlan) -> bool:
-        """One-dispatch iteration: the scheduler's fused plan runs as a
-        SINGLE compiled step program (executor-side bucketing by static
-        phase presence).  Returns whether any model work ran.
+    def _step(self, params, extras: Dict[int, Dict], it: int,
+              plan: StepPlan) -> bool:
+        """One iteration: the scheduler's plan runs as a SINGLE compiled
+        step program (executor-side bucketing by static phase presence).
+        Returns whether any model work ran.
 
-        Identical host bookkeeping to the legacy prefill+decode pair, with
-        one scheduling difference: a request whose final chunk lands this
-        iteration starts decoding NEXT iteration (the decode roster is
-        frozen before dispatch), where the legacy path recomputed the
-        roster after prefill.  Greedy token streams are identical; see
-        ``ContinuousConfig.fused_step`` for the temperature>0 caveat."""
+        The decode roster is frozen before dispatch, so a request whose
+        final prefill chunk lands this iteration samples its first token
+        here and starts decoding NEXT iteration."""
         if not plan.has_work:
             return False
         self.exec.apply_effects(plan)
-        # both legacy fault sites still fire (chaos schedules target them
-        # by name); either hit folds into the step's shared fault operand,
-        # so a single fault degrades the WHOLE fused step to the oracle —
-        # exactly the blast radius of one compiled program
+        # both fault sites fire by name (chaos schedules target them);
+        # either hit folds into the step's shared fault operand, so a
+        # single fault degrades the WHOLE step to the oracle — exactly the
+        # blast radius of one compiled program
         fault_val = 0.0
         if plan.prefill is not None:
             fault_val += self._crash_fire("prefill", it)
         if plan.decode is not None:
             fault_val += self._crash_fire("decode", it)
         fault = jnp.float32(fault_val)
-        # key-split order matches the legacy path (prefill, then decode)
+        # sampling keys split prefill first, then decode
         pkey = dkey = jnp.zeros((2,), jnp.uint32)   # placeholder operands
         if plan.prefill is not None:
             self._key, pkey = jax.random.split(self._key)
@@ -449,51 +366,6 @@ class ContinuousServingEngine:
             if plan.decode is not None:
                 self.sched.emit_decode_tokens(plan.decode,
                                               res.decode_tokens, it)
-        return True
-
-    def _step_prefill(self, params, extras: Dict[int, Dict], it: int) -> bool:
-        """Legacy two-program split, phase 1: one chunk for the oldest
-        prefilling request.  Returns whether the PREFILL roster was
-        non-empty (the historical progress signal — a fully-ingested
-        request parked in PREFILL counts as work even though nothing
-        dispatches)."""
-        if not any(r.state == PREFILL for r in self.sched.requests):
-            return False
-        self._key, sub = jax.random.split(self._key)
-        with tracing.span(tracing.SCHEDULE):
-            plan = self.sched.plan_prefill()
-        pw = plan.prefill
-        if pw is None:     # fully ingested, parked — nothing to run
-            return True
-        self.exec.apply_effects(plan)
-        fault = jnp.float32(self._crash_fire("prefill", it))
-        ex = extras.get(pw.req.rid, {}) if pw.first else {}
-        logits = self.exec.prefill(params, plan, ex, fault)
-        self._count_attention_tiles(plan)
-        self._count_routed_rows(plan)
-        self.sched.commit_chunk(pw.req, pw.chunk_len)
-        if self.sched.seq_complete(pw.req):   # seq ingested: sample
-            tok = self.exec.sample_token(logits, sub)
-            with tracing.span(tracing.COMMIT):
-                self.sched.emit_prefill_token(pw.req, tok, it)
-        return True
-
-    def _step_decode(self, params, it: int) -> bool:
-        """Legacy two-program split, phase 2: one slot-batched decode step
-        (roster computed AFTER prefill, so a request finishing prefill
-        this iteration decodes the same iteration)."""
-        with tracing.span(tracing.SCHEDULE):
-            plan = self.sched.plan_decode()
-        if plan.decode is None:
-            return False
-        self._key, sub = jax.random.split(self._key)
-        self.exec.apply_effects(plan)
-        fault = jnp.float32(self._crash_fire("decode", it))
-        nxt = self.exec.decode(params, plan, sub, fault)
-        self._count_attention_tiles(plan)
-        self._count_routed_rows(plan)
-        with tracing.span(tracing.COMMIT):
-            self.sched.emit_decode_tokens(plan.decode, nxt, it)
         return True
 
     # ------------------------------------------------------------ main loop
@@ -552,23 +424,15 @@ class ContinuousServingEngine:
                         sched.stamp_arrivals(it, time.perf_counter())
                         reaped = sched.reap(it)
                         admitted = sched.admit(it)
-                        if self.fused_step:
-                            # block grab moves BEFORE the dispatch: the
-                            # fused program reads the final roster/table,
-                            # and a dry-pool preemption can still unwind
-                            # the prefilling request ahead of its chunk
-                            if self.paged:
-                                sched.ensure_decode_blocks()
-                            plan = sched.plan_step()
-                    if self.fused_step:
-                        tracing.describe(step_span, plan)
-                        worked = self._step_fused(params, extras, it, plan)
-                    else:
-                        worked = self._step_prefill(params, extras, it)
+                        # block grab BEFORE the dispatch: the step program
+                        # reads the final roster/table, and a dry-pool
+                        # preemption can still unwind the prefilling
+                        # request ahead of its chunk
                         if self.paged:
-                            with tracing.span(tracing.SCHEDULE):
-                                sched.ensure_decode_blocks()
-                        worked = self._step_decode(params, it) or worked
+                            sched.ensure_decode_blocks()
+                        plan = sched.plan_step()
+                    tracing.describe(step_span, plan)
+                    worked = self._step(params, extras, it, plan)
                     if worked:
                         self.work_iterations += 1
                     if self.paged and self._validate:
@@ -588,9 +452,7 @@ class ContinuousServingEngine:
                 tokens_per_s=gen / max(wall, 1e-9),
                 trace_counts=dict(ex.trace_counts),
                 # compiled-program launches per iteration that ran model
-                # work (oracle re-runs included) — 1.0 on a clean fused
-                # run, ~2 on the legacy two-program split when
-                # prefill+decode overlap
+                # work (oracle re-runs included) — 1.0 on a clean run
                 dispatches=ex.dispatches - disp0,
                 dispatches_per_iteration=(
                     (ex.dispatches - disp0)

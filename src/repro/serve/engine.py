@@ -8,19 +8,18 @@ explicit:
     engine = ServingEngine(model, policy)
     out = engine.generate(params, prompts, max_new_tokens=64)
 
-``generate`` is the legacy whole-batch path kept as a thin compatibility
-wrapper (and as the bit-exactness oracle for the scheduler tests): every
-request in the batch must arrive together, prefill runs as one monolithic
-jit, and decode runs as a ``lax.scan`` over steps.  Production traffic —
-asynchronous arrivals, mixed prompt lengths, slot reuse — goes through
+``generate`` is the one-shot whole-batch reference that the chunked
+serving path is tested against: every request in the batch arrives
+together, prefill runs as one monolithic jit, and decode runs as a
+``lax.scan`` over steps.  Served traffic — asynchronous arrivals, mixed
+prompt lengths, slot reuse — goes through
 :class:`repro.serve.continuous.ContinuousServingEngine`, which chunks the
-sparse prefill, interleaves it with slot-batched decode, and compiles each
-phase once per shape bucket (see ``serve/README.md``).
+sparse prefill and runs it with slot-batched decode as one step program
+per shape bucket (see ``serve/README.md``).
 """
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from functools import partial
 from typing import Any, Dict, Optional
 
@@ -41,14 +40,12 @@ class ServeConfig:
 
 
 class ServingEngine:
+    """The one-shot whole-batch reference: monolithic prefill, then a
+    scanned dense decode.  The chunked serving path is tested against
+    it; serving itself goes through :class:`repro.serve.api.Engine`."""
+
     def __init__(self, model, policy: SparsityPolicy = DENSE,
-                 cfg: ServeConfig = ServeConfig(), *, _via_api: bool = False):
-        if not _via_api:
-            warnings.warn(
-                "constructing ServingEngine directly is deprecated; use "
-                "repro.serve.api.Engine.from_config — Engine.generate is the "
-                "one-shot adapter (serve/README.md has the migration table)",
-                DeprecationWarning, stacklevel=2)
+                 cfg: ServeConfig = ServeConfig()):
         self.model = model
         self.policy = policy
         self.cfg = cfg

@@ -2,11 +2,12 @@
 layer — see serve/README.md "Architecture").
 
 The :class:`Executor` owns everything that touches jax: the cache
-pytree, the jit'd step-program buckets (PR 7's one-dispatch iterations
-plus the legacy two-program split), their bit-exact jnp oracle twins,
-and the fault/degradation ladder.  It consumes
-:class:`~repro.serve.scheduler.StepPlan`s — plain host data — and
-returns sampled tokens; it never reads or mutates request state.
+pytree, the jit'd step programs (one per :data:`STEP_BUCKETS` row, each
+running an iteration's prefill chunk and slot-batched decode in one
+dispatch), their bit-exact jnp oracle twins, and the fault/degradation
+ladder.  It consumes :class:`~repro.serve.scheduler.StepPlan`s — plain
+host data — and returns sampled tokens; it never reads or mutates
+request state.
 
 Every step program is a **pure function** of ``(params, cache, plan
 operands)``: the only Python-side reads inside a traced body are
@@ -38,7 +39,7 @@ from repro.serve.scheduler import StepPlan
 
 __all__ = ["Executor", "StepResult", "STEP_BUCKETS", "declared_trace_keys"]
 
-# The fused one-dispatch step buckets, keyed (replay, has_prefill,
+# The one-dispatch step buckets, keyed (replay, has_prefill,
 # has_decode) — static phase presence (PR 7).  This table is THE
 # enumeration: ``Executor.__init__`` builds one program (plus its jnp
 # oracle twin) per row, and ``repro.analysis`` sweeps its jaxpr/trace
@@ -56,16 +57,13 @@ STEP_BUCKETS: Dict[Tuple[bool, bool, bool], str] = {
 # rounded wherever the program stores one, never carried wider
 EXACT_STORES = {"xla_allow_excess_precision": False}
 
-# legacy two-program split (still served by ``prefill()``/``decode()``)
-_LEGACY_TRACE_KEYS = ("prefill", "prefill_replay", "decode")
-
 
 def declared_trace_keys() -> Tuple[str, ...]:
     """Every ``trace_counts`` key an :class:`Executor` may legitimately
-    record: the fused buckets, the legacy split, and the ``_oracle``
-    degradation twins of each.  The retrace rule treats any key outside
-    this set as an undeclared (hence unbounded) trace bucket."""
-    base = tuple(STEP_BUCKETS.values()) + _LEGACY_TRACE_KEYS
+    record: the step buckets and their ``_oracle`` degradation twins.
+    The retrace rule treats any key outside this set as an undeclared
+    (hence unbounded) trace bucket."""
+    base = tuple(STEP_BUCKETS.values())
     return base + tuple(k + "_oracle" for k in base)
 
 
@@ -78,7 +76,7 @@ class StepResult:
 
 
 class Executor:
-    """Owns the cache pytree + jit'd phase programs; executes plans.
+    """Owns the cache pytree + jit'd step programs; executes plans.
 
     May mutate: ``self.cache``, its own dispatch/degradation counters,
     ``trace_counts``.  May NOT touch: requests, slots bookkeeping, the
@@ -152,88 +150,20 @@ class Executor:
         self.dispatches = 0       # compiled-program launches (incl. oracle)
         self.degraded_iterations = 0  # iterations re-run on the jnp oracle
 
-        # every phase program takes a runtime ``fault`` operand added onto
-        # its logits (0.0 on clean runs, NaN when the injector fires a
-        # "nonfinite" fault — a runtime value, so injection never bakes
-        # into or retraces the compiled program) and returns an ``ok``
-        # finiteness verdict the degradation ladder checks host-side.
-        # ``ok`` also trips on GENUINE non-finite logits from a kernel bug.
-        def make_prefill_fn(policy, count_key):
-            def prefill_fn(params, cache, slot, tokens, chunk_len, extras,
-                           fault):
-                # runs at trace time only
-                self.trace_counts[count_key] = \
-                    self.trace_counts.get(count_key, 0) + 1
-                with jax.named_scope("step.slot_io"):
-                    sub = slot_ops.slice_slot(cache, slot, self._spec)
-                batch = {"tokens": tokens, "chunk_len": chunk_len, **extras}
-                logits, sub = self.model.prefill_chunk(params, batch, sub,
-                                                       policy=policy)
-                logits = logits[0] + fault
-                ok = jnp.all(jnp.isfinite(logits))
-                with jax.named_scope("step.slot_io"):
-                    cache = slot_ops.write_slot(cache, slot, sub, self._spec)
-                return logits, cache, ok
-            prefill_fn.__name__ = prefill_fn.__qualname__ = count_key
-            return prefill_fn
-
-        dense = DENSE.with_(use_pallas_kernels=policy.use_pallas_kernels)
-
-        def make_decode_fn(policy, count_key):
-            def decode_fn(params, cache, tokens, active, key, fault):
-                self.trace_counts[count_key] = \
-                    self.trace_counts.get(count_key, 0) + 1
-                logits, new_cache = self.model.decode_step(
-                    params, tokens[:, None], cache, policy=policy)
-                logits = logits + fault
-                with jax.named_scope("step.cache_select"):
-                    new_cache = slot_ops.where_active(active, new_cache,
-                                                      cache, self._spec)
-                nxt = self._sample(logits, key)
-                # inactive slots may legitimately hold junk logits — only
-                # active rows gate the degradation ladder
-                ok = jnp.all(jnp.isfinite(logits)
-                             | ~active.reshape(active.shape[0],
-                                               *([1] * (logits.ndim - 1))))
-                return jnp.where(active, nxt, tokens), new_cache, ok
-            decode_fn.__name__ = decode_fn.__qualname__ = count_key
-            return decode_fn
-
-        self._prefill_jit = jit(make_prefill_fn(policy, "prefill"))
-        # preemption replay re-ingests tokens the request already EMITTED;
-        # their KV was originally written by the dense decode step, so the
-        # replay must also run dense or sparse-prefill outputs would drift
-        # from the one-shot oracle.  Chunks never span the prompt/emitted
-        # boundary (see Scheduler.next_chunk); this program only ever
-        # traces (and the "prefill_replay" key only appears) if a
-        # preemption happens under a non-dense policy.
-        self._prefill_replay_jit = jit(
-            make_prefill_fn(dense, "prefill_replay"))
-        self._decode_jit = jit(make_decode_fn(dense, "decode"))
-        # graceful-degradation ladder: bit-exact jnp oracle twins of every
-        # phase program (kernel dispatch forced off).  jax.jit is lazy, so
-        # none of these trace — and no "*_oracle" trace-count key appears —
-        # unless an iteration actually degrades.
-        opolicy = policy.with_(use_pallas_kernels=False) \
-            if policy.use_pallas_kernels else policy
-        self._prefill_oracle_jit = jit(
-            make_prefill_fn(opolicy, "prefill_oracle"))
-        self._prefill_replay_oracle_jit = jit(
-            make_prefill_fn(DENSE, "prefill_replay_oracle"))
-        self._decode_oracle_jit = jit(
-            make_decode_fn(DENSE, "decode_oracle"))
-
-        # ---- one-dispatch iterations: a single hybrid step program per
-        # shape bucket runs the active request's prefill chunk AND the
+        # one-dispatch iterations: a single hybrid step program per shape
+        # bucket runs the active request's prefill chunk AND the
         # slot-batched decode in one compiled dispatch.  Buckets are keyed
         # (replay, has_prefill, has_decode) — static phase presence, so an
         # idle phase costs nothing in the lowered program.  The prefill
         # half writes its chunk KV first; the decode half then reads the
-        # already-updated cache, exactly like the legacy two-program order
-        # within an iteration.  Both halves share one ``fault`` operand
-        # and fold into one all-finite ``ok`` verdict (inactive decode
-        # rows masked), so the degradation ladder re-runs the WHOLE step
-        # on the oracle twin.
+        # already-updated cache.  Every program takes a runtime ``fault``
+        # operand added onto its logits (0.0 on clean runs, NaN when the
+        # injector fires a "nonfinite" fault — a runtime value, so
+        # injection never bakes into or retraces the compiled program).
+        # Both halves share it and fold into one all-finite ``ok`` verdict
+        # (inactive decode rows masked; it also trips on GENUINE
+        # non-finite logits from a kernel bug), so the degradation ladder
+        # re-runs the WHOLE step on the oracle twin.
         def make_step_fn(pf_policy, dec_policy, count_key,
                          has_prefill, has_decode):
             def step_fn(params, cache, slot, tokens, chunk_len, extras,
@@ -278,6 +208,19 @@ class Executor:
             step_fn.__name__ = step_fn.__qualname__ = count_key
             return step_fn
 
+        # decode runs dense projections, and so does a preemption replay:
+        # it re-ingests tokens the request already EMITTED, whose KV the
+        # dense decode originally wrote, so a sparse replay would drift
+        # from the one-shot oracle.  Chunks never span the prompt/emitted
+        # boundary (see Scheduler.next_chunk); the replay buckets only
+        # trace if a preemption happens under a non-dense policy.
+        dense = DENSE.with_(use_pallas_kernels=policy.use_pallas_kernels)
+        # graceful-degradation ladder: bit-exact jnp oracle twins of every
+        # step program (kernel dispatch forced off).  jax.jit is lazy, so
+        # none of these trace — and no "*_oracle" trace-count key appears —
+        # unless an iteration actually degrades.
+        opolicy = policy.with_(use_pallas_kernels=False) \
+            if policy.use_pallas_kernels else policy
         # raw (unjitted) step fns are kept for the jaxpr pins in tests and
         # repro.analysis — ``step_program(bucket)`` is the public accessor
         self._step_raw: Dict[tuple, Callable] = {}
@@ -301,9 +244,6 @@ class Executor:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return jax.random.categorical(
                 key, logits / self.cfg.temperature, axis=-1).astype(jnp.int32)
-
-    def sample_token(self, logits, key) -> int:
-        return int(self._sample(logits, key))
 
     # ------------------------------------------------------------ the cache
     def _on_mesh(self, tree):
@@ -381,7 +321,7 @@ class Executor:
 
     def step(self, params, plan: StepPlan, extras: Dict, pkey, dkey,
              fault) -> StepResult:
-        """Execute a fused one-dispatch plan.  ``extras`` are the modality
+        """Execute a one-dispatch plan.  ``extras`` are the modality
         arrays for the chunk (already resolved to {} by the driver when
         this is not the request's first chunk)."""
         degraded0 = self.degraded_iterations
@@ -413,34 +353,6 @@ class Executor:
                 prefill_token=int(ptok) if pw is not None else None,
                 decode_tokens=np.asarray(nxt) if dw is not None else None,
                 degraded=self.degraded_iterations > degraded0)
-
-    def prefill(self, params, plan: StepPlan, extras: Dict, fault):
-        """Legacy two-program split, phase 1: run the chunk, return its
-        final-position logits (the driver samples only when the chunk
-        completed the sequence — matching the historical dispatch
-        pattern)."""
-        pw = plan.prefill
-        fn = self._prefill_replay_jit if pw.replay else self._prefill_jit
-        ofn = (self._prefill_replay_oracle_jit if pw.replay
-               else self._prefill_oracle_jit)
-        args = (self.place(params), self.cache,
-                jnp.asarray(pw.req.slot, jnp.int32),
-                jnp.asarray(pw.tokens), jnp.asarray(pw.chunk_len, jnp.int32),
-                extras)
-        logits, new_cache, _ = self._run_ladder(fn, ofn, args, fault)
-        self.cache = new_cache
-        return logits
-
-    def decode(self, params, plan: StepPlan, key, fault) -> np.ndarray:
-        """Legacy two-program split, phase 2: one slot-batched decode
-        step; returns the (num_slots,) next-token array."""
-        dw = plan.decode
-        args = (self.place(params), self.cache, jnp.asarray(dw.toks),
-                jnp.asarray(dw.active), key)
-        nxt, new_cache, _ = self._run_ladder(
-            self._decode_jit, self._decode_oracle_jit, args, fault)
-        self.cache = new_cache
-        return np.asarray(nxt)
 
     # ----------------------------------------------------------- test hooks
     def step_program(self, bucket: Tuple[bool, bool, bool],

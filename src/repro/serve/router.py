@@ -71,7 +71,7 @@ class Router:
         # across the whole fleet, wherever the site happens to fire
         self.replicas: List[ContinuousServingEngine] = [
             ContinuousServingEngine(model, policy, cfg, faults=faults,
-                                    mesh=subs[i], _via_api=True)
+                                    mesh=subs[i])
             for i in range(dp)]
         self.alive = [True] * dp
         self.crashes = 0                  # replicas lost to EngineCrash

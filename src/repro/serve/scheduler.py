@@ -5,9 +5,9 @@ The :class:`Scheduler` owns every piece of *host* state — the
 :class:`Request` lifecycle machine, slot assignment, the paged
 :class:`~repro.serve.paged.BlockPool`, the prefix index bookkeeping, the
 watchdog, and all scheduling counters — and **never touches device
-arrays**.  Each iteration it emits a :class:`StepPlan` (or the legacy
-:class:`PrefillWork` / :class:`DecodeWork` pair): a plain-numpy
-description of the device work to run.  The
+arrays**.  Each iteration it emits a :class:`StepPlan` — its
+:class:`PrefillWork` chunk and :class:`DecodeWork` roster, run as one
+step program — a plain-numpy description of the device work.  The
 :class:`~repro.serve.executor.Executor` consumes plans and returns
 sampled tokens; the scheduler's ``commit_*`` methods fold them back into
 request state.  That contract is what makes the executor's step a pure
@@ -136,7 +136,7 @@ class StepPlan:
     """Device work for one scheduler iteration.  ``resets`` and ``table``
     are idempotent cache-side effects the Executor applies BEFORE the
     step dispatch (slot handoffs and block-table rewrites, both decided
-    host-side); ``prefill``/``decode`` describe the fused step program's
+    host-side); ``prefill``/``decode`` describe the step program's
     operands.  An all-``None`` plan is an idle iteration."""
     prefill: Optional[PrefillWork] = None
     decode: Optional[DecodeWork] = None
@@ -165,12 +165,10 @@ class Scheduler:
 
     def __init__(self, cfg, *, paged: bool, exact_chunks: bool,
                  policy_enabled: bool, prefix_cache: bool,
-                 faults=None, validate: bool = False,
-                 hash_fn=chain_block_hashes):
+                 faults=None, validate: bool = False):
         self.cfg = cfg
         self.faults = faults
         self._validate = validate
-        self._hash_fn = hash_fn
         self._exact_chunks = exact_chunks
         self._policy_enabled = policy_enabled
         self.paged = paged
@@ -333,7 +331,7 @@ class Scheduler:
         chain = req.hash_chain
         if n_full > len(chain):
             dense_from = (len(req.tokens) if self._policy_enabled else None)
-            chain.extend(self._hash_fn(
+            chain.extend(chain_block_hashes(
                 tokens, self.pool.block_size, n_full, dense_from,
                 start=len(chain), h0=chain[-1] if chain else None))
         return chain[:n_full]
@@ -619,26 +617,11 @@ class Scheduler:
         return list(self._slot_req)
 
     def plan_step(self) -> StepPlan:
-        """Fused-path plan: the active request's prefill chunk AND the
-        frozen decode roster, as one step-program dispatch."""
+        """The iteration's plan: the active request's prefill chunk AND
+        the decode roster frozen before dispatch, as one step-program
+        dispatch."""
         plan = StepPlan(prefill=self._prefill_work(),
                         decode=self._decode_work())
-        if plan.has_work:
-            self._drain_effects(plan)
-        return plan
-
-    def plan_prefill(self) -> StepPlan:
-        """Legacy two-program split, phase 1: just the prefill chunk."""
-        plan = StepPlan(prefill=self._prefill_work())
-        if plan.has_work:
-            self._drain_effects(plan)
-        return plan
-
-    def plan_decode(self) -> StepPlan:
-        """Legacy two-program split, phase 2: the decode roster computed
-        AFTER prefill (a request finishing prefill this iteration joins
-        decode the same iteration — the legacy scheduling difference)."""
-        plan = StepPlan(decode=self._decode_work())
         if plan.has_work:
             self._drain_effects(plan)
         return plan

@@ -162,12 +162,14 @@ def test_pool_eqn_count_and_pallas_walk():
 
 
 def test_declared_trace_keys_cover_buckets():
-    from repro.serve.executor import STEP_BUCKETS, declared_trace_keys
+    from repro.serve.executor import declared_trace_keys
     keys = declared_trace_keys()
-    for name in STEP_BUCKETS.values():
-        assert name in keys and name + "_oracle" in keys
-    for legacy in ("prefill", "decode", "prefill_replay"):
-        assert legacy in keys and legacy + "_oracle" in keys
+    assert len(keys) == 10
+    assert set(keys) == {"step_prefill", "step_prefill_decode",
+                         "step_decode", "step_replay", "step_replay_decode",
+                         "step_prefill_oracle", "step_prefill_decode_oracle",
+                         "step_decode_oracle", "step_replay_oracle",
+                         "step_replay_decode_oracle"}
 
 
 def test_findings_json_schema():

@@ -33,9 +33,9 @@ from repro.core.pruner import precompute_scales
 from repro.models import build_model
 from repro.serve import (ContinuousConfig, ContinuousServingEngine,
                          ServeConfig, ServingEngine)
-from repro.serve.continuous import (CANCELLED, DONE, REJECTED, TIMED_OUT,
-                                    _TERMINAL)
 from repro.serve.faults import EngineCrash, FaultInjector, FaultSpec
+from repro.serve.scheduler import (CANCELLED, DONE, REJECTED, TERMINAL,
+                                   TIMED_OUT)
 
 MAX_SEQ = 64
 CHAOS_SEED = int(os.environ.get("REPRO_CHAOS_SEED", "0"))
@@ -76,7 +76,7 @@ def _engine(model, policy=DENSE, faults=None, **kw):
 def _drained(eng):
     """Post-drain leak check: every request terminal and holding nothing,
     every block back in the free list or parked zero-ref in the LRU."""
-    assert all(r.state in _TERMINAL for r in eng.requests)
+    assert all(r.state in TERMINAL for r in eng.requests)
     assert all(not r.blocks and r.slot == -1 for r in eng.requests)
     if eng.paged:
         assert eng.pool.in_use == 0, "leaked live blocks after drain"

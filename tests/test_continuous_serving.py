@@ -130,22 +130,6 @@ def test_single_trace_per_bucket(tiny):
     assert all(len(res["outputs"][i]) == 5 for i in range(len(lens)))
 
 
-def test_single_trace_per_bucket_legacy(tiny):
-    """Same stream through the legacy two-program split (fused_step=False):
-    the original per-phase pins still hold."""
-    cfg, model, params = tiny
-    lens = [3, 9, 14, 23, 31, 6]
-    prompts = _prompts(cfg, lens, seed0=70)
-    eng = ContinuousServingEngine(model, DENSE, ContinuousConfig(
-        max_seq=MAX_SEQ, num_slots=3, chunk_size=16, fused_step=False))
-    for p, a in zip(prompts, [0, 0, 1, 2, 5, 9]):
-        eng.submit(p, max_new_tokens=5, arrival=a)
-    res = eng.run(params)
-    assert eng.trace_counts == {"prefill": 1, "decode": 1}, eng.trace_counts
-    assert res["metrics"]["dispatches_per_iteration"] > 1.0
-    assert all(len(res["outputs"][i]) == 5 for i in range(len(lens)))
-
-
 def test_recurrent_arch_dyadic_chunks():
     """rwkv6: recurrent state carries across exact dyadic chunks; outputs
     stay token-identical and the trace count is bounded by the ladder."""

@@ -5,20 +5,23 @@ Covers the tentpole contract and its satellites:
   * ``_dyadic_sizes`` properties — non-increasing powers of two ≤ cap that
     sum exactly to the requested length, and the empty ladder for a zero
     remainder (the infinite-loop / IndexError bugfix).
-  * Token-identity: the fused one-dispatch engine matches both the legacy
-    two-program split AND the one-shot oracle across staggered bucket
-    shapes, with ``dispatches_per_iteration == 1`` on clean fused runs.
+  * Token-identity: the one-dispatch engine matches the one-shot oracle
+    across staggered bucket shapes, with ``dispatches_per_iteration == 1``
+    on clean runs; with an Amber-sparse prefill the Pallas kernels match
+    the kernels-off policy token for token.
+  * Scheduling: the decode roster is frozen before dispatch, so a request
+    samples its first token in the iteration of its last prefill chunk
+    and decodes from the next one.
   * Compile discipline: exactly one step program per phase-presence
     bucket, and with kernels on the step program's jaxpr carries ZERO
     pool-shaped gathers or scatters outside a ``pallas_call`` (the KV
     scatter moved in-kernel; the jnp oracle keeps both, so the pin bites).
-  * Chaos: seeds 0-2 stay green with the fused step enabled.
+  * Chaos: seeds 0-2 stay green on the one step path.
   * Latency report (bugfix): ``visible_t`` is stamped unconditionally,
     so no terminal request — finished, cancelled, or timed out — reports
     the garbage ``-1.0`` default through the ``--trace`` latency report.
 """
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
@@ -32,8 +35,8 @@ from repro.core.pruner import precompute_scales
 from repro.models import build_model
 from repro.serve import (ContinuousConfig, ContinuousServingEngine,
                          ServeConfig, ServingEngine)
-from repro.serve.continuous import _TERMINAL, _dyadic_sizes
 from repro.serve.faults import FaultInjector, FaultSpec
+from repro.serve.scheduler import TERMINAL, _dyadic_sizes
 
 MAX_SEQ = 64
 
@@ -60,15 +63,14 @@ def _oracle(model, params, policy, prompt, max_new):
     return np.asarray(out["tokens"])[0].tolist()
 
 
-def _serve(model, policy, params, prompts, arrivals, max_new, *,
-           fused, **kw):
+def _serve(model, policy, params, prompts, arrivals, max_new, **kw):
     kw.setdefault("max_seq", MAX_SEQ)
     kw.setdefault("num_slots", 2)
     kw.setdefault("chunk_size", 8)
     kw.setdefault("block_size", 4)
     kw.setdefault("validate_pool", True)
     eng = ContinuousServingEngine(model, policy,
-                                  ContinuousConfig(fused_step=fused, **kw))
+                                  ContinuousConfig(**kw))
     for p, a, mn in zip(prompts, arrivals, max_new):
         eng.submit(p, max_new_tokens=mn, arrival=a)
     return eng, eng.run(params)
@@ -103,63 +105,72 @@ def test_dyadic_properties(length, cap):
     assert (sizes == []) == (length <= 0)
 
 
-# ------------------------------------- fused vs legacy vs one-shot oracle
+# ------------------------------------------ step path vs one-shot oracle
 
 def test_fused_token_identity_across_buckets(tiny):
     """Staggered mixed-length stream exercising every phase-presence
-    bucket (prefill-only, hybrid, decode-only): the fused one-dispatch
-    engine is token-identical to the legacy two-program split and to the
-    per-request one-shot oracle, at exactly one dispatch per iteration."""
+    bucket (prefill-only, hybrid, decode-only): the one-dispatch engine
+    is token-identical to the per-request one-shot oracle, at exactly one
+    dispatch per iteration."""
     cfg, model, params = tiny
     lens, arrivals = [9, 27, 14, 33, 21, 12], [0, 0, 2, 4, 5, 8]
     max_new = [12] * len(lens)
     prompts = _prompts(cfg, lens)
-    ef, rf = _serve(model, DENSE, params, prompts, arrivals, max_new,
-                    fused=True)
-    el, rl = _serve(model, DENSE, params, prompts, arrivals, max_new,
-                    fused=False)
-    assert rf["outputs"] == rl["outputs"]
+    eng, res = _serve(model, DENSE, params, prompts, arrivals, max_new)
     for i, p in enumerate(prompts):
-        assert rf["outputs"][i] == _oracle(model, params, DENSE, p,
-                                           max_new[i]), f"request {i}"
-    assert rf["metrics"]["dispatches_per_iteration"] == 1.0
-    assert rl["metrics"]["dispatches_per_iteration"] > 1.0
+        assert res["outputs"][i] == _oracle(model, params, DENSE, p,
+                                            max_new[i]), f"request {i}"
+    assert res["metrics"]["dispatches_per_iteration"] == 1.0
     # all three hybrid buckets actually ran, each compiled exactly once
-    assert ef.trace_counts == {"step_prefill": 1, "step_decode": 1,
-                               "step_prefill_decode": 1}, ef.trace_counts
-    assert el.trace_counts == {"prefill": 1, "decode": 1}, el.trace_counts
+    assert eng.trace_counts == {"step_prefill": 1, "step_decode": 1,
+                                "step_prefill_decode": 1}, eng.trace_counts
 
 
-def test_fused_token_identity_sparse_prefill_kernels(tiny):
-    """Same identity under an Amber-sparse prefill policy with the Pallas
-    dispatch ladder on (in-kernel KV scatter + fused projections): fused
-    matches the legacy split on the SAME backend."""
+@pytest.mark.parametrize("n,m", [(2, 4), (8, 16)])
+def test_fused_token_identity_sparse_prefill_kernels(tiny, n, m):
+    """Under an Amber-sparse prefill policy the Pallas dispatch ladder
+    (in-kernel KV scatter, fused projections, paged attention) serves
+    the same stream token for token as the kernels-off policy, at one
+    dispatch per iteration."""
     cfg, model, params = tiny
-    policy = paper_policy(2, 4, cfg.qgate_skip_layers,
+    policy = paper_policy(n, m, cfg.qgate_skip_layers,
                           use_pallas_kernels=True)
     params = precompute_scales(params, policy)
     lens, arrivals, max_new = [7, 17, 12], [0, 0, 2], [6, 8, 6]
     prompts = _prompts(cfg, lens, seed0=720)
-    _, rf = _serve(model, policy, params, prompts, arrivals, max_new,
-                   fused=True)
-    _, rl = _serve(model, policy, params, prompts, arrivals, max_new,
-                   fused=False)
-    assert rf["outputs"] == rl["outputs"]
-    assert rf["metrics"]["dispatches_per_iteration"] == 1.0
+    ek, rk = _serve(model, policy, params, prompts, arrivals, max_new)
+    eo, ro = _serve(model, policy.with_(use_pallas_kernels=False), params,
+                    prompts, arrivals, max_new)
+    assert ek.exec.paged_kernel and not eo.exec.paged_kernel
+    assert rk["outputs"] == ro["outputs"]
+    assert rk["metrics"]["dispatches_per_iteration"] == 1.0
+    assert rk["metrics"]["degraded_iterations"] == 0
 
 
-def test_env_override_forces_dispatch_mode(tiny, monkeypatch):
-    """REPRO_FUSED_STEP=0/1 overrides the config (the CI chaos matrix
-    pins either path without code changes)."""
+def test_final_chunk_decodes_next_iteration(tiny):
+    """The decode roster is frozen before dispatch: a request whose last
+    prefill chunk lands in iteration i samples its first token in i and
+    decodes from i + 1, so with max_new_tokens = k and no other traffic
+    it finishes in iteration i + k - 1."""
     cfg, model, params = tiny
-    monkeypatch.setenv("REPRO_FUSED_STEP", "0")
+    k = 5
+    prompt = _prompts(cfg, [19], seed0=780)[0]   # three chunks of 8
     eng = ContinuousServingEngine(model, DENSE, ContinuousConfig(
-        max_seq=MAX_SEQ, fused_step=True))
-    assert eng.fused_step is False
-    monkeypatch.setenv("REPRO_FUSED_STEP", "1")
-    eng = ContinuousServingEngine(model, DENSE, ContinuousConfig(
-        max_seq=MAX_SEQ, fused_step=False))
-    assert eng.fused_step is True
+        max_seq=MAX_SEQ, num_slots=2, chunk_size=8, block_size=4,
+        validate_pool=True))
+    eng.submit(prompt, max_new_tokens=k, arrival=0)
+    filled = {}     # prefill progress seen at the top of each iteration
+    eng.iteration_hook = lambda e, it: filled.setdefault(
+        it, e.requests[0].filled)
+    res = eng.run(params)
+    # the chunk that completed the prompt ran one iteration before the
+    # first iteration to see it whole
+    i = min(it for it, f in filled.items() if f == len(prompt)) - 1
+    assert filled[i] < len(prompt)
+    (r,) = res["metrics"]["requests"]
+    assert r["first_token_iter"] == i
+    assert r["done_iter"] == i + k - 1
+    assert res["outputs"][0] == _oracle(model, params, DENSE, prompt, k)
 
 
 # --------------------------------------------------- jaxpr dispatch pins
@@ -224,7 +235,7 @@ def test_chaos_seeds_green_fused(tiny, seed):
     def serve(faults):
         eng = ContinuousServingEngine(model, DENSE, ContinuousConfig(
             max_seq=MAX_SEQ, num_slots=2, chunk_size=8, block_size=4,
-            validate_pool=True, fused_step=True), faults=faults)
+            validate_pool=True), faults=faults)
         for p, a, mn in zip(prompts, arrivals, max_new):
             eng.submit(p, max_new_tokens=mn, arrival=a)
         return eng, eng.run(params)
@@ -238,7 +249,7 @@ def test_chaos_seeds_green_fused(tiny, seed):
     eng, res = serve(inj)
     assert res["outputs"] == base["outputs"], \
         f"seed {seed}: faults changed tokens"
-    assert all(r.state in _TERMINAL for r in eng.requests)
+    assert all(r.state in TERMINAL for r in eng.requests)
     assert all(not r.blocks and r.slot == -1 for r in eng.requests)
     assert eng.pool.in_use == 0
     deg = res["metrics"]["degraded_iterations"]

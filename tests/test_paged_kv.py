@@ -243,7 +243,7 @@ def test_unservable_request_rejected_not_livelocked(tiny):
     waited on forever — strict FCFS would otherwise starve every request
     behind it.  ``submit`` guards the normal path, so craft the oversized
     request directly (as a preemption-grown replay would look)."""
-    from repro.serve.continuous import REJECTED, Request
+    from repro.serve.scheduler import REJECTED, Request
     cfg, model, params = tiny
     eng = ContinuousServingEngine(model, DENSE, ContinuousConfig(
         max_seq=MAX_SEQ, num_slots=2, chunk_size=8, block_size=8,
@@ -275,7 +275,7 @@ def test_preempt_prefill_victim_interleaving(tiny):
     Engineered deterministically: req0 decodes and crosses a block
     boundary exactly while req1 (40-token prompt, 5 chunks) is mid-prefill
     with the pool fully committed."""
-    from repro.serve.continuous import PREFILL
+    from repro.serve.scheduler import PREFILL
     cfg, model, params = tiny
     lens, arrivals, max_new = [8, 40], [0, 2], [24, 8]
     prompts = _prompts(cfg, lens, seed0=85)

@@ -367,7 +367,7 @@ def test_engine_survives_universal_hash_collisions(tiny, monkeypatch):
     oracle-identical and the collisions are metered."""
     cfg, model, params = tiny
     monkeypatch.setattr(
-        "repro.serve.continuous.chain_block_hashes",
+        "repro.serve.scheduler.chain_block_hashes",
         lambda tokens, bs, n_blocks=None, dense_from=None, start=0, h0=None:
             list(range(start, n_blocks)))
     prompts = [_rand_tokens(cfg, 14, seed=160 + i) for i in range(3)]

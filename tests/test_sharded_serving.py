@@ -124,8 +124,7 @@ def test_executor_step_program_is_pure(tiny):
     cfg, model, params = tiny
     slots, bs = 2, 8
     mb = max_blocks_per_slot(MAX_SEQ, bs)
-    eng = ContinuousServingEngine(model, DENSE, _serve_cfg(block_size=bs),
-                                  _via_api=True)
+    eng = ContinuousServingEngine(model, DENSE, _serve_cfg(block_size=bs))
     cache = init_paged_cache(model, slots, MAX_SEQ, bs, slots * mb,
                              eng._spec)
     tab = np.full((slots, mb), -1, np.int32)
@@ -228,17 +227,28 @@ def test_dp1_crash_propagates(tiny):
 
 # ----------------------------------------------------------- api adapters
 
-def test_direct_engine_construction_warns(tiny):
-    cfg, model, params = tiny
-    with pytest.warns(DeprecationWarning, match="Engine.from_config"):
-        ContinuousServingEngine(model, DENSE, _serve_cfg())
-    from repro.serve.engine import ServeConfig, ServingEngine
-    with pytest.warns(DeprecationWarning, match="Engine.from_config"):
-        ServingEngine(model, DENSE, ServeConfig(max_seq=MAX_SEQ))
+def test_direct_replica_matches_engine(tiny):
+    """A ``ContinuousServingEngine`` built directly is the replica
+    ``Engine`` wraps: the same staggered stream gives identical outputs
+    and trace counts through either, and neither construction warns."""
     import warnings as w
+    cfg, model, params = tiny
+    prompts = _prompts(cfg, (9, 17, 12, 21), seed0=85)
+    arrivals = (0, 0, 2, 3)
     with w.catch_warnings():
         w.simplefilter("error", DeprecationWarning)
-        Engine.from_config(model, EngineConfig(serving=_serve_cfg()))
+        rep = ContinuousServingEngine(model, DENSE, _serve_cfg())
+        eng = Engine.from_config(model, EngineConfig(serving=_serve_cfg()),
+                                 policy=DENSE)
+        rids = [rep.submit(p, 5, arrival=a)
+                for p, a in zip(prompts, arrivals)]
+        gids = [eng.submit(p, 5, arrival=a)
+                for p, a in zip(prompts, arrivals)]
+        direct = rep.run(params)["outputs"]
+        wrapped = eng.run(params)["outputs"]
+    assert [direct[r] for r in rids] == [wrapped[g] for g in gids]
+    assert rep.trace_counts == eng.replicas[0].trace_counts
+    assert rep.trace_counts
 
 
 def test_engine_generate_oneshot_adapter(tiny):

@@ -119,7 +119,7 @@ def test_request_timeline_is_ordered(tiny):
     prompts = [_tokens(cfg, 8, 95), _tokens(cfg, 40, 96)]
     eng = ContinuousServingEngine(model, DENSE, ContinuousConfig(
         max_seq=MAX_SEQ, num_slots=2, chunk_size=8, block_size=4,
-        num_blocks=13, validate_pool=True), _via_api=True)
+        num_blocks=13, validate_pool=True))
     for p, a, mn in zip(prompts, (0, 2), (24, 8)):
         eng.submit(p, max_new_tokens=mn, arrival=a)
     tops = {}
@@ -150,8 +150,7 @@ def test_profiler_session_changes_no_program(tiny, tmp_path):
 
     def serve(traced):
         eng = ContinuousServingEngine(model, policy, ContinuousConfig(
-            max_seq=MAX_SEQ, num_slots=2, chunk_size=8, block_size=4),
-            _via_api=True)
+            max_seq=MAX_SEQ, num_slots=2, chunk_size=8, block_size=4))
         for i, n in enumerate((11, 6, 17)):
             eng.submit(_tokens(cfg, n, 930 + i), max_new_tokens=3,
                        arrival=i)
@@ -171,8 +170,7 @@ def test_step_programs_carry_their_names(tiny):
                           use_pallas_kernels=True)
     sparams = precompute_scales(params, policy)
     eng = ContinuousServingEngine(model, policy, ContinuousConfig(
-        max_seq=MAX_SEQ, num_slots=2, chunk_size=8, block_size=4),
-        _via_api=True)
+        max_seq=MAX_SEQ, num_slots=2, chunk_size=8, block_size=4))
     ex = eng.exec
     ex.init_cache(eng.pool.num_blocks)
     zero = np.zeros((2,), np.uint32)
